@@ -59,8 +59,7 @@ class TraceGuardViolation(AssertionError):
 
 
 def _install() -> None:
-    """Register the process-global monitoring listener (idempotent).
-    jax.monitoring has no unregister API in 0.4.x, so the listener is
+    """Register the process-global monitoring listener (idempotent). It is
     installed once and counts forever; guards diff the counter."""
     global _installed
     if _installed:

@@ -286,8 +286,8 @@ class TableConfig:
     # TPU, where the layout's rationale holds — XLA pads a [C, dim<128] f32
     # array's minor dim to 128 lanes, so packing saves 128/dim x HBM and
     # gather bandwidth. On CPU there is no lane padding and the pack/unpack
-    # shuffle is pure overhead (measured: -36% DLRM train throughput, BENCH_r04
-    # vs r03), so "auto" resolves to unpacked there. "on"/"off" force it
+    # shuffle is pure overhead (measured: -36% DLRM train throughput on a CPU,
+    # docs/perf.md), so "auto" resolves to unpacked there. "on"/"off" force it
     # either way (tests exercise the packed path on CPU via "on").
     packed: str = "auto"  # auto | on | off
     # Unique-budget for the hash dedup engine (ops/dedup.py): per lookup,

@@ -26,7 +26,6 @@ donation makes the updates in-place in practice.
 from __future__ import annotations
 
 import dataclasses
-import functools as _ft
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -35,21 +34,11 @@ import numpy as _np
 from flax import struct
 
 from deeprec_tpu.config import TableConfig
-from deeprec_tpu.utils import hashing
+from deeprec_tpu.utils import backend, hashing
 
 
 def _key_dtype(cfg: TableConfig):
     return jnp.dtype(cfg.key_dtype)
-
-
-@_ft.lru_cache(maxsize=1)
-def _backend_is_tpu() -> bool:
-    """Whether jax resolves to a TPU backend (cached — the backend cannot
-    change within a process). The packed layout's "auto" gate."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 def empty_key(cfg: TableConfig) -> int:
@@ -333,10 +322,10 @@ class EmbeddingTable:
         """Pack factor for a [C, width] per-row array under this table's
         layout policy. cfg.packed="auto" packs only where the layout can
         win — TPU, where XLA pads the minor dim to 128 lanes; on CPU there
-        is no padding and packing measured -34% (BENCH_r04 vs r03), so auto
+        is no padding and packing measured -36% (docs/perf.md), so auto
         resolves to unpacked. "on"/"off" force it either way."""
         mode = self.cfg.packed
-        if mode == "off" or (mode == "auto" and not _backend_is_tpu()):
+        if mode == "off" or (mode == "auto" and not backend.on_tpu()):
             return 1
         from deeprec_tpu.ops.packed import pack_factor
 

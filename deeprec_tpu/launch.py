@@ -40,11 +40,8 @@ def initialize(
     Call before creating any arrays."""
     import jax
 
-    try:
-        if jax.distributed.is_initialized():
-            return
-    except AttributeError:  # older jax without the predicate
-        pass
+    if jax.distributed.is_initialized():
+        return
 
     kw = {}
     coordinator = coordinator or os.environ.get("DEEPREC_COORDINATOR")
@@ -240,6 +237,10 @@ def main(argv=None):
     initialize(args.coordinator, args.num_processes, args.process_id)
 
     import jax
+
+    from deeprec_tpu.utils.backend import enable_compile_cache
+
+    enable_compile_cache()
 
     print(
         f"deeprec_tpu.launch: process {jax.process_index()}/"

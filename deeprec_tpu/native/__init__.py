@@ -2,14 +2,20 @@
 
 Native C++ is the right tool for the host-side KV store backing multi-tier
 embedding storage (DeepRec keeps this layer in C++ too — SURVEY.md §2.1). The
-library auto-builds with `make` on first use; a pure-numpy fallback keeps the
-framework functional in build-less environments (behavior-identical, slower).
+library is built by `make` from the tracked sources on the machine that loads
+it — every process goes through `make` once (a no-op when the binary is
+current), so a binary that does not match the sources is never what runs. A
+pure-numpy fallback keeps the framework functional where the build fails
+(behavior-identical, slower); the failure is reported once, with the
+compiler's output.
 """
 from __future__ import annotations
 
 import ctypes
 import os
 import subprocess
+import threading
+import warnings
 from typing import Optional, Tuple
 
 import numpy as np
@@ -18,37 +24,38 @@ from deeprec_tpu.analysis.annotations import not_thread_safe
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SO = os.path.join(_DIR, "libdeeprec_host.so")
-_lib = None
-_build_attempted = False
+_lib: Optional[ctypes.CDLL] = None
+_loaded = False
+_load_lock = threading.Lock()
 
 
-def _try_build() -> Optional[ctypes.CDLL]:
-    global _build_attempted
-    if _build_attempted:
-        return None
-    _build_attempted = True
+def _build_and_load() -> Optional[ctypes.CDLL]:
     try:
         subprocess.run(
-            ["make", "-s"], cwd=_DIR, check=True, capture_output=True, timeout=120
+            ["make", "-s"], cwd=_DIR, check=True, capture_output=True,
+            text=True, timeout=120,
         )
-        return ctypes.CDLL(_SO)
-    except Exception:
+        lib = ctypes.CDLL(_SO)
+    except (OSError, subprocess.SubprocessError) as e:
+        warnings.warn(
+            "deeprec_tpu.native: building libdeeprec_host.so failed, the "
+            "numpy fallback takes over (same results, slower): "
+            f"{e}\n{getattr(e, 'stderr', None) or ''}",
+            RuntimeWarning, stacklevel=3,
+        )
         return None
+    _configure(lib)
+    return lib
 
 
 def load_library() -> Optional[ctypes.CDLL]:
-    global _lib
-    if _lib is not None:
-        return _lib
-    if os.path.exists(_SO):
-        try:
-            _lib = ctypes.CDLL(_SO)
-            return _lib
-        except OSError:
-            pass
-    _lib = _try_build()
-    if _lib is not None:
-        _configure(_lib)
+    """The host library, or None where it cannot be built (the callers'
+    numpy path). Builds at most once per process."""
+    global _lib, _loaded
+    with _load_lock:
+        if not _loaded:
+            _lib = _build_and_load()
+            _loaded = True
     return _lib
 
 
@@ -111,7 +118,6 @@ class HostKV:
         self.dim = dim
         self._lib = load_library()
         if self._lib is not None:
-            _configure(self._lib)
             self._h = self._lib.hkv_create(dim, initial_capacity)
             self._fallback = None
         else:
@@ -224,7 +230,6 @@ def criteo_parse_native(
     lib = load_library()
     if lib is None or not hasattr(lib, "criteo_parse"):
         return None
-    _configure(lib)
     labels = np.zeros(max_rows, np.float32)
     dense = np.zeros((max_rows, num_dense), np.float32)
     cats = np.zeros((max_rows, num_cat), np.int32)

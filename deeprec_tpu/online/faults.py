@@ -321,7 +321,7 @@ def spawn_worker(argv: List[str], env: Optional[dict] = None,
                  cwd: Optional[str] = None) -> subprocess.Popen:
     """Start a worker with line-buffered captured stdout (stderr merged),
     CPU-pinned jax defaults unless the caller overrides."""
-    e = {**os.environ, "PYTHONPATH": "", "JAX_PLATFORMS": "cpu"}
+    e = {**os.environ, "JAX_PLATFORMS": "cpu"}
     if env:
         e.update({k: str(v) for k, v in env.items()})
     return subprocess.Popen(

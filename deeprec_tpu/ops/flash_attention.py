@@ -22,11 +22,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deeprec_tpu.utils import backend
+
 NEG_INF = -1e30
-
-
-def _use_pallas() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 # ------------------------------------------------------------ reference impl
@@ -55,10 +53,10 @@ def _masked_scores(q, k, mk, qb, kb, block_q, block_k, sm_scale, causal):
     """Scaled QK^T with padding + causal masking — the one definition all
     three kernels (fwd, dKdV, dQ) share; a drift here would silently
     desynchronize forward and backward. Inlines at trace time.
-    q [block_q, D] f32, k [block_k, D] f32, mk [block_k] int; qb/kb are
-    the Q/K *block* indices."""
+    q [block_q, D] f32, k [block_k, D] f32, mk [1, block_k] int; qb/kb
+    are the Q/K *block* indices."""
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
-    s = jnp.where(mk[None, :] > 0, s, NEG_INF)
+    s = jnp.where(mk > 0, s, NEG_INF)
     if causal:
         qpos = qb * block_q + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 0
@@ -71,18 +69,19 @@ def _masked_scores(q, k, mk, qb, kb, block_q, block_k, sm_scale, causal):
 
 
 def _ds_from_p(p, do, v, delta, sm_scale):
-    """dS = P ∘ (dO·Vᵀ − Δ)·scale — shared by both backward kernels."""
+    """dS = P ∘ (dO·Vᵀ − Δ)·scale — shared by both backward kernels.
+    delta is a [block_q, 1] column."""
     dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-    return p * (dp - delta[:, None]) * sm_scale
+    return p * (dp - delta) * sm_scale
 
 
 def _probs_from_lse(s, lse):
     """exp(s − LSE) with the dead-row guard: a row whose visible keys are
     ALL masked stores lse ≈ NEG_INF, and exp(NEG_INF − NEG_INF) = 1 would
     broadcast garbage into dk/dv/dq — such rows attend to nothing, so
-    their probabilities are exactly zero. Shared by every backward path."""
-    dead = lse <= NEG_INF * 0.5
-    return jnp.where(dead[..., None], 0.0, jnp.exp(s - lse[..., None]))
+    their probabilities are exactly zero. Shared by every backward path;
+    lse carries a trailing singleton axis (a column per query row)."""
+    return jnp.where(lse <= NEG_INF * 0.5, 0.0, jnp.exp(s - lse))
 
 
 def _fa_fwd_kernel(
@@ -113,7 +112,7 @@ def _fa_fwd_kernel(
         q = q_ref[0].astype(jnp.float32)  # [block_q, D]
         k = k_ref[0].astype(jnp.float32)  # [block_k, D]
         v = v_ref[0].astype(jnp.float32)
-        mk = mask_ref[0]  # [block_k]
+        mk = mask_ref[0]  # [1, block_k]
         s = _masked_scores(q, k, mk, qb, kb, block_q, block_k, sm_scale,
                            causal)
         m = m_scr[:]
@@ -130,7 +129,7 @@ def _fa_fwd_kernel(
     def _finish():
         l_safe = jnp.maximum(l_scr[:], 1e-30)
         o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
-        lse_ref[0] = (m_scr[:, 0] + jnp.log(l_safe[:, 0])).astype(jnp.float32)
+        lse_ref[0] = m_scr[:] + jnp.log(l_safe)
 
 
 def _pallas_forward(q, k, v, mask, causal, sm_scale, block_q, block_k, interpret):
@@ -143,7 +142,11 @@ def _pallas_forward(q, k, v, mask, causal, sm_scale, block_q, block_k, interpret
     qr = q.reshape(BH, Lq, D)
     kr = k.reshape(BH, S, D)
     vr = v.reshape(BH, S, D)
-    maskr = jnp.repeat(mask.astype(jnp.int32), H, axis=0)  # [BH, S]
+    # Mosaic needs the last two dims of a block (8, 128)-aligned or whole,
+    # which a (1, block) block over a 2-D array is not: the key mask rides
+    # as [BH, 1, S] rows and the LSE as [BH, Lq, 1] columns — also the
+    # shapes the kernels consume them in.
+    maskr = jnp.repeat(mask.astype(jnp.int32), H, axis=0)[:, None, :]
 
     num_kb = S // block_k
     grid = (BH, Lq // block_q, num_kb)
@@ -158,15 +161,15 @@ def _pallas_forward(q, k, v, mask, causal, sm_scale, block_q, block_k, interpret
             pl.BlockSpec((1, block_q, D), lambda b, i, kb: (b, i, 0)),
             pl.BlockSpec((1, block_k, D), lambda b, i, kb: (b, kb, 0)),
             pl.BlockSpec((1, block_k, D), lambda b, i, kb: (b, kb, 0)),
-            pl.BlockSpec((1, block_k), lambda b, i, kb: (b, kb)),
+            pl.BlockSpec((1, 1, block_k), lambda b, i, kb: (b, 0, kb)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, kb: (b, i, 0)),
-            pl.BlockSpec((1, block_q), lambda b, i, kb: (b, i)),
+            pl.BlockSpec((1, block_q, 1), lambda b, i, kb: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Lq, D), q.dtype),
-            jax.ShapeDtypeStruct((BH, Lq), jnp.float32),
+            jax.ShapeDtypeStruct((BH, Lq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
@@ -251,9 +254,9 @@ def _fa_bwd_dkdv_kernel(
         k = k_ref[0].astype(jnp.float32)       # [block_k, D]
         v = v_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)     # [block_q, D]
-        lse = lse_ref[0].astype(jnp.float32)   # [block_q]
-        delta = delta_ref[0].astype(jnp.float32)
-        mk = mask_ref[0]                       # [block_k]
+        lse = lse_ref[0]                       # [block_q, 1]
+        delta = delta_ref[0]
+        mk = mask_ref[0]                       # [1, block_k]
         s = _masked_scores(q, k, mk, qb, kb, block_q, block_k, sm_scale,
                            causal)
         p = _probs_from_lse(s, lse)            # exact probs from saved LSE
@@ -292,8 +295,8 @@ def _fa_bwd_dq_kernel(
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0].astype(jnp.float32)
-        delta = delta_ref[0].astype(jnp.float32)
+        lse = lse_ref[0]
+        delta = delta_ref[0]
         mk = mask_ref[0]
         s = _masked_scores(q, k, mk, qb, kb, block_q, block_k, sm_scale,
                            causal)
@@ -318,13 +321,13 @@ def _pallas_backward(q, k, v, mask, causal, sm_scale, block_q, block_k,
     kr = k.reshape(BH, S, D)
     vr = v.reshape(BH, S, D)
     dor = do.reshape(BH, Lq, D)
-    lser = lse.reshape(BH, Lq)
-    maskr = jnp.repeat(mask.astype(jnp.int32), H, axis=0)  # [BH, S]
+    lser = lse.astype(jnp.float32).reshape(BH, Lq, 1)
+    maskr = jnp.repeat(mask.astype(jnp.int32), H, axis=0)[:, None, :]
     # delta = rowsum(do * o): cheap elementwise+reduce, XLA fuses it; the
     # kernels read it per Q block.
     delta = jnp.sum(
         do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
-    ).reshape(BH, Lq)
+    ).reshape(BH, Lq, 1)
 
     num_qb, num_kb = Lq // block_q, S // block_k
     qspec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
@@ -341,10 +344,10 @@ def _pallas_backward(q, k, v, mask, causal, sm_scale, block_q, block_k,
             pl.BlockSpec((1, block_q, D), lambda b, kb, qb: (b, qb, 0)),  # q
             pl.BlockSpec((1, block_k, D), lambda b, kb, qb: (b, kb, 0)),  # k
             pl.BlockSpec((1, block_k, D), lambda b, kb, qb: (b, kb, 0)),  # v
-            pl.BlockSpec((1, block_k), lambda b, kb, qb: (b, kb)),        # mask
+            pl.BlockSpec((1, 1, block_k), lambda b, kb, qb: (b, 0, kb)),  # mask
             pl.BlockSpec((1, block_q, D), lambda b, kb, qb: (b, qb, 0)),  # do
-            pl.BlockSpec((1, block_q), lambda b, kb, qb: (b, qb)),        # lse
-            pl.BlockSpec((1, block_q), lambda b, kb, qb: (b, qb)),        # delta
+            pl.BlockSpec((1, block_q, 1), lambda b, kb, qb: (b, qb, 0)),  # lse
+            pl.BlockSpec((1, block_q, 1), lambda b, kb, qb: (b, qb, 0)),  # delta
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda b, kb, qb: (b, kb, 0)),
@@ -372,10 +375,10 @@ def _pallas_backward(q, k, v, mask, causal, sm_scale, block_q, block_k,
             qspec,                                                        # q
             pl.BlockSpec((1, block_k, D), lambda b, i, kb: (b, kb, 0)),   # k
             pl.BlockSpec((1, block_k, D), lambda b, i, kb: (b, kb, 0)),   # v
-            pl.BlockSpec((1, block_k), lambda b, i, kb: (b, kb)),         # mask
+            pl.BlockSpec((1, 1, block_k), lambda b, i, kb: (b, 0, kb)),   # mask
             qspec,                                                        # do
-            pl.BlockSpec((1, block_q), lambda b, i, kb: (b, i)),          # lse
-            pl.BlockSpec((1, block_q), lambda b, i, kb: (b, i)),          # delta
+            pl.BlockSpec((1, block_q, 1), lambda b, i, kb: (b, i, 0)),    # lse
+            pl.BlockSpec((1, block_q, 1), lambda b, i, kb: (b, i, 0)),    # delta
         ],
         out_specs=[qspec],
         out_shape=[jax.ShapeDtypeStruct((BH, Lq, D), q.dtype)],
@@ -412,7 +415,7 @@ def _blockwise_backward(q, k, v, mask, causal, sm_scale, block_k, o, lse, do):
         if causal:
             kpos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (Lq, block_k), 1)
             s = jnp.where((kpos <= qpos)[None, None], s, NEG_INF)
-        p = _probs_from_lse(s, lse)  # exact probabilities (dead rows -> 0)
+        p = _probs_from_lse(s, lse[..., None])  # exact (dead rows -> 0)
         dp = jnp.einsum("bhld,bhsd->bhls", dof, vs)
         ds = p * (dp - delta[..., None]) * sm_scale
         dq = dq + jnp.einsum("bhls,bhsd->bhld", ds, ks)
@@ -449,9 +452,9 @@ def flash_attention(
 
 def _fa_impl(q, k, v, mask, causal, sm_scale, block_q, block_k, interpret):
     scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(q.shape[-1])
-    if _use_pallas() or interpret:
+    if backend.on_tpu() or interpret:
         return _pallas_forward(q, k, v, mask, causal, scale, block_q, block_k,
-                               interpret or not _use_pallas())
+                               interpret or not backend.on_tpu())
     return _blockwise_forward(q, k, v, mask, causal, scale, block_k)
 
 
@@ -463,10 +466,10 @@ def _fa_fwd(q, k, v, mask, causal, sm_scale, block_q, block_k, interpret):
 def _fa_bwd(causal, sm_scale, block_q, block_k, interpret, res, do):
     q, k, v, mask, o, lse = res
     scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(q.shape[-1])
-    if _use_pallas() or interpret:
+    if backend.on_tpu() or interpret:
         dq, dk, dv = _pallas_backward(
             q, k, v, mask, causal, scale, block_q, block_k, o, lse, do,
-            interpret or not _use_pallas(),
+            interpret or not backend.on_tpu(),
         )
     else:
         dq, dk, dv = _blockwise_backward(

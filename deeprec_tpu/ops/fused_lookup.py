@@ -39,12 +39,10 @@ from typing import Dict, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from deeprec_tpu.utils import backend
+
 _BLOCK = 8  # rows per grid step; sublane-aligned for f32
 _LANES = 128  # Mosaic HBM tiling: DMA row slices must be lane-aligned
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _dma_ok(dim: int, dtype) -> bool:
@@ -118,8 +116,8 @@ def _note_fallback(kernel: str, reason: str, shape, dtype) -> None:
 
 
 def _row_reason(dim: int, dtype) -> str:
-    """Why _on_tpu() + _dma_ok rejected a single-row-DMA dispatch."""
-    if not _on_tpu():
+    """Why backend.on_tpu() + _dma_ok rejected a single-row-DMA dispatch."""
+    if not backend.on_tpu():
         return "not_tpu"
     if dim % _LANES != 0:
         return "dim_unaligned"
@@ -127,8 +125,8 @@ def _row_reason(dim: int, dtype) -> str:
 
 
 def _pair_reason(shape, dtype) -> str:
-    """Why _on_tpu() + _dma_pair_ok rejected a pair-granule dispatch."""
-    if not _on_tpu():
+    """Why backend.on_tpu() + _dma_pair_ok rejected a pair-granule dispatch."""
+    if not backend.on_tpu():
         return "not_tpu"
     _, dim = shape
     if dim % _LANES != 0:
@@ -162,20 +160,6 @@ def _pad_updates(slot_ix, new_rows, block):
             ),
         ])
     return ixp, new_rows
-
-
-def _compiler_params(pltpu_mod, **kw):
-    """Mosaic compiler params across jax versions: TPUCompilerParams was
-    renamed CompilerParams and grew fields over time (has_side_effects is
-    absent in older jax — safe to drop there: these kernels' outputs are
-    always consumed, the flag only guards against DCE). Unknown fields are
-    filtered rather than crashing the whole kernel path."""
-    import dataclasses
-
-    cls = getattr(pltpu_mod, "CompilerParams", None) \
-        or pltpu_mod.TPUCompilerParams
-    names = {f.name for f in dataclasses.fields(cls)}
-    return cls(**{k: v for k, v in kw.items() if k in names})
 
 
 def _sr_bits(seed, shape):
@@ -213,7 +197,7 @@ def gather_rows_pair(values: jnp.ndarray, ix: jnp.ndarray, *,
     n = ix.shape[0]
     C, D = values.shape
     if not interpret and not (
-        _on_tpu() and _dma_pair_ok(values.shape, values.dtype)
+        backend.on_tpu() and _dma_pair_ok(values.shape, values.dtype)
     ):
         _note_fallback("gather_rows_pair",
                        _pair_reason(values.shape, values.dtype),
@@ -268,6 +252,7 @@ def gather_rows_pair(values: jnp.ndarray, ix: jnp.ndarray, *,
     )
     out = pl.pallas_call(
         kernel,
+        name="gather_rows_pair",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((np_, D), values.dtype),
         interpret=interpret,
@@ -286,7 +271,7 @@ def apply_rows_sr_pair(values: jnp.ndarray, slot_ix: jnp.ndarray,
     U, D = new_rows.shape
     C = values.shape[0]
     if not interpret and not (
-        _on_tpu() and _dma_pair_ok(values.shape, values.dtype)
+        backend.on_tpu() and _dma_pair_ok(values.shape, values.dtype)
     ):
         _note_fallback("apply_rows_sr_pair",
                        _pair_reason(values.shape, values.dtype),
@@ -353,10 +338,11 @@ def apply_rows_sr_pair(values: jnp.ndarray, slot_ix: jnp.ndarray,
     )
     return pl.pallas_call(
         kernel,
+        name="apply_rows_sr_pair",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(values.shape, values.dtype),
         input_output_aliases={3: 0},
-        compiler_params=_compiler_params(pltpu, has_side_effects=True),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
         interpret=interpret,
     )(ixp, new_rows, bits, values)
 
@@ -374,10 +360,12 @@ def gather_rows(values: jnp.ndarray, ix: jnp.ndarray, *,
     flag — see AUTO_TRUSTS_BF16_PAIR)."""
     n = ix.shape[0]
     if pair_kernels and _dma_pair_ok(values.shape, values.dtype) and (
-        interpret or _on_tpu()
+        interpret or backend.on_tpu()
     ):
         return gather_rows_pair(values, ix, block=block, interpret=interpret)
-    if not interpret and not (_on_tpu() and _dma_ok(values.shape[1], values.dtype)):
+    if not interpret and not (
+        backend.on_tpu() and _dma_ok(values.shape[1], values.dtype)
+    ):
         _note_fallback("gather_rows",
                        _row_reason(values.shape[1], values.dtype),
                        values.shape, values.dtype)
@@ -428,6 +416,7 @@ def gather_rows(values: jnp.ndarray, ix: jnp.ndarray, *,
     )
     out = pl.pallas_call(
         kernel,
+        name="gather_rows",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((np_, D), values.dtype),
         interpret=interpret,
@@ -454,10 +443,10 @@ def fused_gather_combine(values: jnp.ndarray, row_ix: jnp.ndarray,
     B, L = row_ix.shape
     C, D = values.shape
     pair = pair_kernels and _dma_pair_ok(values.shape, values.dtype) and (
-        interpret or _on_tpu()
+        interpret or backend.on_tpu()
     )
     if not pair and not interpret and not (
-        _on_tpu() and _dma_ok(D, values.dtype)
+        backend.on_tpu() and _dma_ok(D, values.dtype)
     ):
         _note_fallback("fused_gather_combine", _row_reason(D, values.dtype),
                        values.shape, values.dtype)
@@ -538,6 +527,7 @@ def fused_gather_combine(values: jnp.ndarray, row_ix: jnp.ndarray,
     )
     out = pl.pallas_call(
         kernel,
+        name="fused_gather_combine",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Bp, D), jnp.float32),
         interpret=interpret,
@@ -575,11 +565,13 @@ def apply_rows_sr(values: jnp.ndarray, slot_ix: jnp.ndarray,
     U, D = new_rows.shape
     C = values.shape[0]
     if use_pallas and pair_kernels and _dma_pair_ok(values.shape, values.dtype) and (
-        interpret or _on_tpu()
+        interpret or backend.on_tpu()
     ):
         return apply_rows_sr_pair(values, slot_ix, new_rows, seed,
                                   interpret=interpret)
-    if not interpret and not (use_pallas and _on_tpu() and _dma_ok(D, values.dtype)):
+    if not interpret and not (
+        use_pallas and backend.on_tpu() and _dma_ok(D, values.dtype)
+    ):
         if use_pallas:
             # only a *rejected* Pallas request is a fallback worth noting;
             # use_pallas=False callers asked for the XLA scatter.
@@ -657,10 +649,11 @@ def apply_rows_sr(values: jnp.ndarray, slot_ix: jnp.ndarray,
     )
     return pl.pallas_call(
         kernel,
+        name="apply_rows_sr",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(values.shape, values.dtype),
         input_output_aliases={3: 0},
-        compiler_params=_compiler_params(pltpu, has_side_effects=True),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
         interpret=interpret,
     )(ixp, new_rows, bits, values)
 
@@ -799,7 +792,7 @@ def fused_sparse_forward(values: jnp.ndarray, ids: jnp.ndarray, *,
     flat = jnp.where(ids >= 0, ids, -1).reshape(-1).astype(jnp.int32)
 
     if not interpret and not (
-        use_pallas and _on_tpu() and _dma_ok(D, values.dtype)
+        use_pallas and backend.on_tpu() and _dma_ok(D, values.dtype)
     ):
         if use_pallas:
             _note_fallback("fused_sparse_forward",
@@ -1007,6 +1000,7 @@ def fused_sparse_forward(values: jnp.ndarray, ids: jnp.ndarray, *,
     )
     out, uids, inv, cnt, ovf = pl.pallas_call(
         kernel,
+        name="fused_sparse_forward",
         grid_spec=grid_spec,
         out_shape=(
             jax.ShapeDtypeStruct((B, D), jnp.float32),
@@ -1015,7 +1009,7 @@ def fused_sparse_forward(values: jnp.ndarray, ids: jnp.ndarray, *,
             jax.ShapeDtypeStruct((U, 1), jnp.int32),
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ),
-        compiler_params=_compiler_params(pltpu, has_side_effects=True),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
         interpret=interpret,
     )(flat, values)
     return _combine_epilogue(
@@ -1070,7 +1064,7 @@ def fused_sparse_backward(values: jnp.ndarray,
             )
 
     if not fusable or (
-        not interpret and not (use_pallas and _on_tpu()
+        not interpret and not (use_pallas and backend.on_tpu()
                                and _dma_ok(D, values.dtype))
     ):
         if use_pallas and not interpret:
@@ -1237,6 +1231,7 @@ def fused_sparse_backward(values: jnp.ndarray,
     )
     outs = pl.pallas_call(
         kernel,
+        name="fused_sparse_backward",
         grid_spec=grid_spec,
         out_shape=tuple(
             [jax.ShapeDtypeStruct(values.shape, values.dtype)]
@@ -1244,7 +1239,7 @@ def fused_sparse_backward(values: jnp.ndarray,
                for n in snames]
         ),
         input_output_aliases={8 + i: i for i in range(1 + K)},
-        compiler_params=_compiler_params(pltpu, has_side_effects=True),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
         interpret=interpret,
     )(
         jnp.clip(res.uids, -1, C - 1).astype(jnp.int32),

@@ -832,7 +832,8 @@ def expected_lookup_apply_ops(
     `lookup_unique` + `apply_gradients` program (no sharding, no admission
     filter, one per-row optimizer slot unless overridden).
 
-    Base constants are CALIBRATED against the lowered program (jax 0.4.37;
+    Base constants are CALIBRATED against the lowered program (they hold on
+    the installed jax 0.9.0: tests/test_traffic_diet.py counts the same ops;
     the extra ops over a hand inventory come from jnp.unique / hash-dedup
     internals and clip/where index lowering).  The diet deltas are the
     structural facts this PR is about and what the CI assertion guards:

@@ -35,7 +35,6 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import PartitionSpec as P
 
-from deeprec_tpu.parallel.compat import shard_map
 from deeprec_tpu.parallel.trainer import ShardedTrainer
 from deeprec_tpu.training import metrics as M
 from deeprec_tpu.training.trainer import PipelineCarry, TrainState
@@ -93,7 +92,7 @@ class AsyncShardedTrainer(ShardedTrainer):
         views_spec, res_spec, _ = self._carry_specs()
 
         @partial(
-            shard_map,
+            jax.shard_map,
             mesh=self.mesh,
             in_specs=(state_spec, batch_spec),
             out_specs=(state_spec, views_spec, res_spec),
@@ -239,7 +238,7 @@ class AsyncShardedTrainer(ShardedTrainer):
         out_metric_spec = {"loss": P(), "accuracy": P()}
 
         @partial(
-            shard_map,
+            jax.shard_map,
             mesh=self.mesh,
             in_specs=(astate_spec, batch_spec, P()),
             out_specs=(astate_spec, out_metric_spec),
@@ -263,7 +262,7 @@ class AsyncShardedTrainer(ShardedTrainer):
         out_metric_spec = {"loss": P(), "accuracy": P()}
 
         @partial(
-            shard_map,
+            jax.shard_map,
             mesh=self.mesh,
             in_specs=(astate_spec, batch_spec, P()),
             out_specs=(astate_spec, out_metric_spec),

@@ -86,11 +86,9 @@ def ring_attention_sharded(
     sharded, output sequence-sharded."""
     from jax.sharding import PartitionSpec as P
 
-    from deeprec_tpu.parallel.compat import shard_map
-
     fn = functools.partial(ring_attention, axis_name=axis, causal=causal)
     seq = P(None, None, axis, None)
-    return shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(seq, seq, seq, P(None, axis)),

@@ -27,8 +27,6 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from deeprec_tpu.parallel.compat import shard_map
-
 from deeprec_tpu.embedding.table import EmbeddingTable
 from deeprec_tpu.optim.apply import ensure_slots
 from deeprec_tpu.parallel import placement as placement_lib
@@ -969,7 +967,7 @@ class ShardedTrainer(Trainer):
         out_metric_spec = {"loss": P(), "accuracy": P()}
 
         @partial(
-            shard_map,
+            jax.shard_map,
             mesh=self.mesh,
             in_specs=(state_spec, batch_spec, P()),
             out_specs=(state_spec, out_metric_spec),
@@ -999,7 +997,7 @@ class ShardedTrainer(Trainer):
         out_metric_spec = {"loss": P(), "accuracy": P()}
 
         @partial(
-            shard_map,
+            jax.shard_map,
             mesh=self.mesh,
             in_specs=(state_spec, batch_spec, P()),
             out_specs=(state_spec, out_metric_spec),
@@ -1138,7 +1136,7 @@ class ShardedTrainer(Trainer):
         out_metric_spec = {"loss": P(), "accuracy": P()}
 
         @partial(
-            shard_map,
+            jax.shard_map,
             mesh=self.mesh,
             in_specs=(state_spec, batch_spec, P()),
             out_specs=(state_spec, out_metric_spec),
@@ -1170,7 +1168,7 @@ class ShardedTrainer(Trainer):
         out_metric_spec = {"loss": P(), "accuracy": P()}
 
         @partial(
-            shard_map,
+            jax.shard_map,
             mesh=self.mesh,
             in_specs=(state_spec, batch_spec, P()),
             out_specs=(state_spec, out_metric_spec),
@@ -1229,7 +1227,7 @@ class ShardedTrainer(Trainer):
         state_spec, batch_spec = self._specs_for(state, batch)
 
         @partial(
-            shard_map,
+            jax.shard_map,
             mesh=self.mesh,
             in_specs=(state_spec, batch_spec),
             out_specs=(P(), P(self.axis)),

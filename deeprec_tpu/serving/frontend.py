@@ -1674,7 +1674,9 @@ def main(argv=None):
 
         from deeprec_tpu.online import faults as _faults
         from deeprec_tpu.serving.predictor import ModelServer, Predictor
+        from deeprec_tpu.utils.backend import enable_compile_cache
 
+        enable_compile_cache()
         pred = Predictor(model, args.ckpt, quantize=args.quantize)
         reuse_bytes = int(args.reuse_mb * (1 << 20))
         server = ModelServer(pred, max_batch=args.max_batch,

@@ -853,7 +853,7 @@ class CheckpointManager:
         restore materializes it on one device per process — correct up to
         host/device memory, which covers single-slice pods. A per-process
         shard-part file format (no global gather anywhere) is the
-        pod-scale follow-up; see docs/STATUS-round2.md.
+        pod-scale follow-up.
         """
         if jax.process_count() > 1 and not self._is_sharded():
             raise RuntimeError(

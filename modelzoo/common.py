@@ -357,9 +357,12 @@ def main(name: str, model_fn: Callable, data_kind: str, argv=None,
     """defaults: per-model argparse default overrides (the reference's
     per-model train.py files hard-code model-appropriate vocab/lr the same
     way)."""
+    from deeprec_tpu.utils.backend import enable_compile_cache
+
     p = build_argparser(name)
     if defaults:
         p.set_defaults(**defaults)
     args = p.parse_args(argv)
+    enable_compile_cache()
     model = model_fn(args)
     return run(model, args, data_kind)

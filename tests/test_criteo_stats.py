@@ -1,6 +1,6 @@
 """CriteoStats: the deterministic Criteo-marginal-matched generator.
 
-The real-data-AUC proxy (VERDICT r4 ask #3): marginals pinned to public
+The real-data-AUC proxy (round-4 review, ask #3): marginals pinned to public
 Kaggle Criteo summary statistics, label from a hash-derived logistic
 model with a computable Bayes ceiling. These tests pin the statistical
 contract the AUC protocol (modelzoo/benchmark/auc_protocol.py) relies on.

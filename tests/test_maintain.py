@@ -4,7 +4,7 @@ DeepRec closes implicitly (embedding_var.h:142 LookupOrCreateKey never
 refuses a key; multi_tier_storage.h:47 + eviction_manager.h:39 manage
 tiers in background threads).
 
-The VERDICT round-1 acceptance test: overfill a table DURING training and
+The round-1 review's acceptance test: overfill a table DURING training and
 converge anyway — single-device and sharded.
 """
 import jax

@@ -148,10 +148,9 @@ def test_scatter_packed_width1():
 
 def test_packed_knob_resolution():
     """cfg.packed gates the layout: "auto" is backend-dependent (unpacked
-    off-TPU — packing measured -36% train throughput on CPU, BENCH_r04 vs
-    r03), "on"/"off" force it. Slots follow the same policy."""
+    off-TPU — packing measured -36% train throughput on CPU, docs/perf.md), "on"/"off" force it. Slots follow the same policy."""
     from deeprec_tpu.config import TableConfig
-    from deeprec_tpu.embedding.table import EmbeddingTable, _backend_is_tpu
+    from deeprec_tpu.embedding.table import EmbeddingTable
     from deeprec_tpu.optim.apply import ensure_slots
     from deeprec_tpu.optim.sparse import Adagrad
 
@@ -164,8 +163,7 @@ def test_packed_knob_resolution():
     assert on.pack() == 8
     assert off.pack() == 1
     # tests run with JAX_PLATFORMS=cpu (conftest) -> auto stays unpacked
-    assert auto.pack() == (8 if _backend_is_tpu() else 1)
-    assert not _backend_is_tpu()
+    assert auto.pack() == 1
 
     s_on, s_off = on.create(), off.create()
     assert s_on.values.shape == (32, 128)

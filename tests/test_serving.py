@@ -110,7 +110,7 @@ def test_feature_store_read_through(tmp_path):
 
 def test_http_server_end_to_end(tmp_path):
     """train -> save -> serve over HTTP -> delta-update -> prediction shifts
-    (the VERDICT round-1 acceptance flow for the serving frontend)."""
+    (the round-1 review's acceptance flow for the serving frontend)."""
     import json
     import urllib.request
 

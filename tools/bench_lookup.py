@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Benchmark the embedding hot path: XLA gather/scatter vs fused Pallas.
 
-Answers the VERDICT round-1 question "does op-composed lookup reach the
+Answers the round-1 review's question "does op-composed lookup reach the
 roofline on TPU, or does the fused kernel win?" — the reference spent 5.5k
 LoC of CUDA on this exact question for GPUs (fused_embedding_ops.cc).
 
@@ -428,7 +428,7 @@ def main_packed(args):
     kernel='auto' would serve it (the packed array is DMA-eligible at
     128 lanes; the unpacked small-dim arm self-gates to XLA). On TPU the
     packed array dodges the 128-lane minor-dim padding (P× less HBM read
-    per gather); on CPU it measured -36% (BENCH_r04 vs r03) — this
+    per gather); on CPU it measured -36% (docs/perf.md) — this
     prints the per-backend verdict the TableConfig.packed='auto' gate
     encodes."""
     import jax
