@@ -1113,29 +1113,24 @@ def _tier_paging_report():
     }
 
 
-def _profile_phases(trainer, batches):
-    """Host-timed per-phase breakdown (training/profiler.py): jitted
-    sub-programs isolate the sparse phases, deltas attribute the rest."""
+def _overlap_model_inputs(trainer, batches):
+    """Host-clock times (best of 8, ms) of the three blocking programs the
+    overlap model of `_pipeline_report` is fed with: the whole step, the
+    hoistable routing phase alone, and lookup + sparse apply alone (the
+    dense side is the difference). A model's inputs on whatever platform
+    this runs on, not a phase breakdown: the device time of each phase
+    comes from a trace, by the scopes of utils/scopes.py."""
     import jax
     import jax.numpy as jnp
-
-    from deeprec_tpu.training.profiler import PhaseProfiler
 
     state = trainer.init(0)
     for i in range(4):
         state, mets = trainer.train_step(state, batches[i % len(batches)])
     jax.block_until_ready(mets["loss"])
 
-    # The phase sub-programs DONATE the table pytree (like the step path
-    # does) — without donation the output materializes a full copy of
-    # every table per call and the copy, not the phase, dominates.
-    lookup_jit = jax.jit(  # noqa: DRT001 — built once per bench invocation, reused across the timed loop
-        lambda tables, b, step: trainer._lookup_all(tables, b, step, True)[0],
-        donate_argnums=0,
-    )
     # The hoistable routing phase (id dedup + id exchange; ids only, no
     # table state) — what pipeline_mode="lookahead" overlaps with the
-    # dense compute. Timed standalone so the overlap model has a number.
+    # dense compute.
     route_jit = jax.jit(lambda b: trainer._route_all(b, True))  # noqa: DRT001 — built once per bench invocation, reused across the timed loop
 
     def sparse(tables, b, step):
@@ -1146,44 +1141,37 @@ def _profile_phases(trainer, batches):
         return trainer._apply_all(tables, bundle_res, g, step,
                                   jnp.float32(trainer.sparse_opt.lr))
 
+    # DONATES the table pytree (like the step path does) — without
+    # donation the output materializes a full copy of every table per call
+    # and the copy, not the phase, dominates.
     sparse_jit = jax.jit(sparse, donate_argnums=0)  # noqa: DRT001 — built once per bench invocation, reused across the timed loop
-    prof = PhaseProfiler()
-    b0 = batches[0]
-    # Full-step phase FIRST: the sub-programs below then take over (and
-    # donate) the final state's table buffers.
+    best = {}
+
+    def clock(name, t0, out):
+        jax.block_until_ready(out)
+        ms = (time.perf_counter() - t0) * 1e3
+        best[name] = min(best.get(name, ms), ms)
+
+    # Full step FIRST: the sub-program below then takes over (and donates)
+    # the final state's table buffers.
     for i in range(8):
-        b = batches[i % len(batches)]
-        with prof.phase("step", block=None):
-            state, mets = trainer.train_step(state, b)
-            jax.block_until_ready(mets["loss"])
+        t0 = time.perf_counter()
+        state, mets = trainer.train_step(state, batches[i % len(batches)])
+        clock("step", t0, mets["loss"])
     # Fresh host-round-tripped scalar: train_step donated the state (and
     # its step buffer) every iteration above.
     step0 = jnp.asarray(int(state.step), jnp.int32)
     # compile outside the timed loop; thread the donated tables through
-    tables = lookup_jit(dict(state.tables), b0, step0)
-    tables = sparse_jit(tables, b0, step0)
-    routes = route_jit(b0)
-    jax.block_until_ready(jax.tree.leaves(tables)[0])
-    jax.block_until_ready(jax.tree.leaves(routes)[0])
+    tables = sparse_jit(dict(state.tables), batches[0], step0)
+    jax.block_until_ready((tables, route_jit(batches[0])))
     for i in range(8):
         b = batches[i % len(batches)]
-        with prof.phase("route"):
-            routes = route_jit(b)
-            jax.block_until_ready(jax.tree.leaves(routes)[0])
-        with prof.phase("lookup"):
-            tables = lookup_jit(tables, b, step0)
-            jax.block_until_ready(jax.tree.leaves(tables)[0])
-        with prof.phase("lookup_plus_apply"):
-            tables = sparse_jit(tables, b, step0)
-            jax.block_until_ready(jax.tree.leaves(tables)[0])
-    rep = prof.phase_report()
-    rep["derived_sparse_apply_ms"] = round(
-        rep["lookup_plus_apply"]["min_ms"] - rep["lookup"]["min_ms"], 3
-    )
-    rep["derived_dense_plus_overhead_ms"] = round(
-        rep["step"]["min_ms"] - rep["lookup_plus_apply"]["min_ms"], 3
-    )
-    return rep
+        t0 = time.perf_counter()
+        clock("route", t0, route_jit(b))
+        t0 = time.perf_counter()
+        tables = sparse_jit(tables, b, step0)
+        clock("lookup_plus_apply", t0, tables)
+    return {k: round(v, 3) for k, v in best.items()}
 
 
 def _pipeline_report(trainer, batches, B, k_curve, K, pipeline_arg, smoke):
@@ -1247,11 +1235,9 @@ def _pipeline_report(trainer, batches, B, k_curve, K, pipeline_arg, smoke):
     # (overlap target), other (stays serial: value gather + embedding
     # exchange + apply + dense update). Sub-program timings come off the
     # single-step path; the off-arm K-scan step anchors the total.
-    phases = _profile_phases(trainer, batches)
-    route_ms = phases["route"]["min_ms"]
-    dense_ms = max(
-        0.0, phases["step"]["min_ms"] - phases["lookup_plus_apply"]["min_ms"]
-    )
+    times = _overlap_model_inputs(trainer, batches)
+    route_ms = times["route"]
+    dense_ms = max(0.0, times["step"] - times["lookup_plus_apply"])
     step_off_ms = grid["off"]["ms_per_step"]
     other_ms = max(0.0, step_off_ms - dense_ms - route_ms)
     modeled = {
@@ -1285,7 +1271,7 @@ def _pipeline_report(trainer, batches, B, k_curve, K, pipeline_arg, smoke):
             pipeline_mode="lookahead",
         )["pipeline_buffer_bytes"]),
     }
-    return report, phases
+    return report
 
 
 def _obs_overhead_report(trainer, batches, B, smoke):
@@ -1449,10 +1435,10 @@ def workload():
     # In-step pipelining grid: measured off/lookahead(/chunked) arms +
     # the overlap model + overlap efficiency (round 11). "off" skips it.
     pipeline_arg = os.environ.get("BENCH_PIPELINE", "grid")
-    pipeline, pipe_phases = (
+    pipeline = (
         _pipeline_report(trainer, batches, B, k_curve, K, pipeline_arg, smoke)
         if pipeline_arg != "off"
-        else (None, None)
+        else None
     )
     # Skew-aware placement arm (round 12): measured per-shard exchange
     # imbalance uniform-hash vs ShardPlan on the 8-shard skewed multi-table
@@ -1470,13 +1456,6 @@ def workload():
     mesh_rec = (
         _run_cpu_mesh_worker("BENCH_MESH_WORKER", "mesh")
         if os.environ.get("BENCH_MESH", "off") != "off"
-        else None
-    )
-    # --profile reuses the phase breakdown the pipeline report already
-    # measured instead of running the (multi-second) protocol twice.
-    phases = (
-        (pipe_phases or _profile_phases(trainer, batches))
-        if os.environ.get("BENCH_PROFILE") == "1"
         else None
     )
 
@@ -1563,7 +1542,6 @@ def workload():
                 # overflow / steady compiles, nested K-scan — gated by
                 # tools/roofline.py --assert-hierarchy in CI smoke.
                 **({"mesh": mesh_rec} if mesh_rec else {}),
-                **({"phases": phases} if phases else {}),
                 "flags": {
                     "f32_row": _fl.AUTO_TRUSTS_F32_ROW,
                     "bf16_pair": _fl.AUTO_TRUSTS_BF16_PAIR,
@@ -1629,10 +1607,6 @@ def main():
                         "on — fresh-init (state-loss) rate, fold bytes, "
                         "training-thread stall and step-time parity (JSON "
                         "'tier_paging'); gated by roofline --assert-tier")
-    p.add_argument("--profile", action="store_true",
-                   help="add a per-phase step breakdown (lookup / sparse "
-                        "apply / dense+overhead, training/profiler.py) to "
-                        "the JSON")
     args = p.parse_args()
     if args.steps_per_dispatch < 1:
         p.error("--steps-per-dispatch must be >= 1")
@@ -1651,8 +1625,6 @@ def main():
     os.environ["BENCH_PLACEMENT"] = str(args.placement)
     os.environ["BENCH_MESH"] = str(args.mesh)
     os.environ["BENCH_TIER"] = "on" if args.tier_paging else "off"
-    if args.profile:
-        os.environ["BENCH_PROFILE"] = "1"
     if args.smoke:
         os.environ["BENCH_SMOKE"] = "1"
     from deeprec_tpu.utils.backend import enable_compile_cache

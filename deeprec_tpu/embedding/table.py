@@ -34,7 +34,7 @@ import numpy as _np
 from flax import struct
 
 from deeprec_tpu.config import TableConfig
-from deeprec_tpu.utils import backend, hashing
+from deeprec_tpu.utils import backend, hashing, scopes
 
 
 def _key_dtype(cfg: TableConfig):
@@ -446,6 +446,7 @@ class EmbeddingTable:
 
     # ------------------------------------------------------------ probe/insert
 
+    @scopes.scoped(scopes.ENGINE_PROBE)
     def _probe(
         self,
         keys: jnp.ndarray,
@@ -589,16 +590,17 @@ class EmbeddingTable:
         if train:
             # Seed the auto-budget EMA (Trainer.update_budgets) on every
             # path; the overflow counter only moves under a budget.
-            state = state.replace(
-                dedup_unique=state.dedup_unique
-                + jnp.sum(valid).astype(jnp.int32),
-                dedup_ids=state.dedup_ids + jnp.sum(counts),
-                dedup_overflow=(
-                    state.dedup_overflow + overflow
-                    if overflow is not None
-                    else state.dedup_overflow
-                ),
-            )
+            with scopes.scope(scopes.ENGINE_ROUTE):  # the dedup's counters
+                state = state.replace(
+                    dedup_unique=state.dedup_unique
+                    + jnp.sum(valid).astype(jnp.int32),
+                    dedup_ids=state.dedup_ids + jnp.sum(counts),
+                    dedup_overflow=(
+                        state.dedup_overflow + overflow
+                        if overflow is not None
+                        else state.dedup_overflow
+                    ),
+                )
         return state, dataclasses.replace(res, inverse=inverse)
 
     def _lookup_unique_impl(
@@ -673,7 +675,14 @@ class EmbeddingTable:
         `_finish_resolved` performs (the split the pipelined trainers use
         to place the gather after the previous step's apply). Returns the
         updated state and a UniqueLookup whose embeddings/rows are 0-sized
-        placeholders."""
+        placeholders.
+
+        In a trace of a train step all of it stands under `engine_insert`
+        but the probe loop, which `_probe` puts under `engine_probe` inside
+        it (a reader takes the innermost stage): what the step pays for the
+        rows it creates and for stamping the rows it touches. A read-only
+        resolve creates and stamps nothing; what is left of it (the
+        admission's read of the frequencies) is the gather's."""
         cfg = self.cfg
         if train and self.quantized:
             raise ValueError(
@@ -682,81 +691,88 @@ class EmbeddingTable:
                 "(Predictor(quantize='int8'))"
             )
         step = jnp.asarray(step, jnp.int32)
+        stage = scopes.ENGINE_INSERT if train else scopes.ENGINE_GATHER
+        with scopes.scope(stage):
+            bloom = state.bloom
+            want_create = valid
+            if not train:
+                want_create = jnp.zeros_like(valid)
+            elif cfg.ev.cbf_filter is not None:
+                # CBF admission: bump the sketch, only keys at/above threshold
+                # may occupy a table slot (bloom_filter_policy.h semantics).
+                from deeprec_tpu.embedding import filters as _filters
 
-        bloom = state.bloom
-        want_create = valid
-        if not train:
-            want_create = jnp.zeros_like(valid)
-        elif cfg.ev.cbf_filter is not None:
-            # CBF admission: bump the sketch, only keys at/above threshold may
-            # occupy a table slot (bloom_filter_policy.h semantics).
-            from deeprec_tpu.embedding import filters as _filters
+                bloom, est = _filters.cbf_add(
+                    cfg.ev.cbf_filter, bloom, uids, counts
+                )
+                want_create = valid & (est >= cfg.ev.cbf_filter.filter_freq)
 
-            bloom, est = _filters.cbf_add(cfg.ev.cbf_filter, bloom, uids, counts)
-            want_create = valid & (est >= cfg.ev.cbf_filter.filter_freq)
-
-        keys, slot_ix, created, failed = self._probe(state.keys, uids, want_create)
-
-        present = slot_ix >= 0
-        safe_ix = jnp.where(present, slot_ix, 0)
-
-        need_filter = (
-            cfg.ev.counter_filter is not None
-            and cfg.ev.counter_filter.filter_freq > 0
-        )
-        values = state.values
-        meta = state.meta
-        f_cur = None  # post-update per-uid frequency (admission input)
-        if train:
-            # Initialize newly created rows (bf16 tables stochastic-round
-            # the initializer, same as every later write).
-            init_rows = self._init_rows(uids, salt)
-            values = self._scatter(
-                values, jnp.where(created, slot_ix, -1), init_rows,
-                state.capacity, seed=step,
+            keys, slot_ix, created, failed = self._probe(
+                state.keys, uids, want_create
             )
-            # Fused metadata update: ONE [3, U] gather + ONE [3, U]
-            # scatter replace the former freq add / version set / dirty
-            # set trio. The gather also feeds the admission filter, whose
-            # legacy post-update freq read it subsumes (uids are unique,
-            # so each present id owns its slot and set == read-add-write).
-            upd_ix = jnp.where(present, slot_ix, state.capacity)
-            m_rows = meta.at[:, safe_ix].get(mode="clip")  # [3, U]
-            f_cur = m_rows[META_FREQ] + counts
-            new_rows = jnp.stack([
-                f_cur,
-                jnp.broadcast_to(step, f_cur.shape).astype(jnp.int32),
-                jnp.ones_like(f_cur),
-            ])
-            meta = meta.at[:, upd_ix].set(new_rows, mode="drop")
-        elif need_filter:
-            f_cur = meta[META_FREQ].at[safe_ix].get(mode="clip")
 
-        # Admission: counter filter gates on the (just updated) frequency.
-        admitted = present
-        if need_filter:
-            admitted = present & (f_cur >= cfg.ev.counter_filter.filter_freq)
+            present = slot_ix >= 0
+            safe_ix = jnp.where(present, slot_ix, 0)
 
-        new_state = state.replace(
-            keys=keys,
-            values=values,
-            meta=meta,
-            bloom=bloom,
-            insert_fails=state.insert_fails + jnp.sum(failed).astype(jnp.int32),
-        )
-        res = UniqueLookup(
-            uids=uids,
-            slot_ix=slot_ix,
-            inverse=jnp.zeros((0,), jnp.int32),  # filled by lookup_unique
-            counts=counts,
-            valid=valid,
-            admitted=admitted,
-            # Placeholders until _finish_resolved gathers the value rows.
-            embeddings=jnp.zeros((0, 0), jnp.float32),
-            rows=jnp.zeros((0, 0), jnp.float32),
-        )
-        return new_state, res
+            need_filter = (
+                cfg.ev.counter_filter is not None
+                and cfg.ev.counter_filter.filter_freq > 0
+            )
+            values = state.values
+            meta = state.meta
+            f_cur = None  # post-update per-uid frequency (admission input)
+            if train:
+                # Initialize newly created rows (bf16 tables stochastic-round
+                # the initializer, same as every later write).
+                init_rows = self._init_rows(uids, salt)
+                values = self._scatter(
+                    values, jnp.where(created, slot_ix, -1), init_rows,
+                    state.capacity, seed=step,
+                )
+                # Fused metadata update: ONE [3, U] gather + ONE [3, U]
+                # scatter replace the former freq add / version set / dirty
+                # set trio. The gather also feeds the admission filter, whose
+                # legacy post-update freq read it subsumes (uids are unique,
+                # so each present id owns its slot and set == read-add-write).
+                upd_ix = jnp.where(present, slot_ix, state.capacity)
+                m_rows = meta.at[:, safe_ix].get(mode="clip")  # [3, U]
+                f_cur = m_rows[META_FREQ] + counts
+                new_rows = jnp.stack([
+                    f_cur,
+                    jnp.broadcast_to(step, f_cur.shape).astype(jnp.int32),
+                    jnp.ones_like(f_cur),
+                ])
+                meta = meta.at[:, upd_ix].set(new_rows, mode="drop")
+            elif need_filter:
+                f_cur = meta[META_FREQ].at[safe_ix].get(mode="clip")
 
+            # Admission: counter filter gates on the (just updated) frequency.
+            admitted = present
+            if need_filter:
+                admitted = present & (f_cur >= cfg.ev.counter_filter.filter_freq)
+
+            new_state = state.replace(
+                keys=keys,
+                values=values,
+                meta=meta,
+                bloom=bloom,
+                insert_fails=state.insert_fails
+                + jnp.sum(failed).astype(jnp.int32),
+            )
+            res = UniqueLookup(
+                uids=uids,
+                slot_ix=slot_ix,
+                inverse=jnp.zeros((0,), jnp.int32),  # filled by lookup_unique
+                counts=counts,
+                valid=valid,
+                admitted=admitted,
+                # Placeholders until _finish_resolved gathers the value rows.
+                embeddings=jnp.zeros((0, 0), jnp.float32),
+                rows=jnp.zeros((0, 0), jnp.float32),
+            )
+            return new_state, res
+
+    @scopes.scoped(scopes.ENGINE_GATHER)
     def _finish_resolved(
         self, state: TableState, res: UniqueLookup, keep_rows: bool = True
     ) -> UniqueLookup:
@@ -805,14 +821,17 @@ class EmbeddingTable:
             state.keys, flat, jnp.zeros(flat.shape, bool)
         )
         del keys  # unchanged: no creation
-        present = slot_ix >= 0
-        safe_ix = jnp.where(present, slot_ix, 0)
-        emb = self._gather(state.values, safe_ix, state.capacity)
-        if self.quantized:
-            emb = self._dequant(emb, safe_ix, state)
-        emb = jnp.where(present[:, None], emb, self._init_rows(flat, salt))
-        emb = jnp.where(is_pad[:, None], 0.0, emb)
-        return emb.reshape(*shape, cfg.dim)
+        with scopes.scope(scopes.ENGINE_GATHER):
+            present = slot_ix >= 0
+            safe_ix = jnp.where(present, slot_ix, 0)
+            emb = self._gather(state.values, safe_ix, state.capacity)
+            if self.quantized:
+                emb = self._dequant(emb, safe_ix, state)
+            emb = jnp.where(
+                present[:, None], emb, self._init_rows(flat, salt)
+            )
+            emb = jnp.where(is_pad[:, None], 0.0, emb)
+            return emb.reshape(*shape, cfg.dim)
 
     # ---------------------------------------------------------------- updates
 

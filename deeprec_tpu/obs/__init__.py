@@ -11,8 +11,9 @@ Three halves:
   * ``obs.trace`` — sampled cross-process request tracing: a trace id
     born at the HTTP edge rides the frontend's TCP frames into the
     backend micro-batcher stages and back, training-side spans come from
-    ``PhaseProfiler`` / the checkpoint writer / the tier worker / the
-    delta poll loop, and everything serializes to Chrome-trace /
+    the checkpoint writer / the tier worker / the delta poll loop (the
+    train step's own phases and host spans are the profiler's:
+    ``utils/scopes.py``), and everything serializes to Chrome-trace /
     Perfetto JSON via ``tools/obs_trace.py``.
   * ``obs.schema`` — the single health-payload schema the predictor,
     the socket frontend, and the online loop all emit (the old JSON

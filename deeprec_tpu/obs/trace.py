@@ -6,9 +6,8 @@ One trace id is born at the HTTP edge (or extracted from the
 TCP frames into the backend (a flag bit on the PRED frame prefixes the
 npz body with two little-endian u64s: trace id, parent span id), and
 stamps every micro-batcher stage span (queue / pad / device / post) the
-request passes through. Training-side spans — ``PhaseProfiler.phase``,
-the checkpoint writer, the multi-tier worker, the delta poll loop —
-carry no trace id (they are process-timeline events), but land in the
+request passes through. Training-side spans — the checkpoint writer,
+the multi-tier worker, the delta poll loop — carry no trace id (they are process-timeline events), but land in the
 same files, so ``tools/obs_trace.py`` renders one train→delta→serve
 timeline.
 
@@ -267,8 +266,8 @@ def server_span(name: str, cat: str = "",
 
 
 def phase_span(name: str, t0: float, t1: float, cat: str = "train") -> None:
-    """Training-side timeline event (PhaseProfiler, checkpoint writer,
-    tier worker, delta poll): no trace id — rendered on the
+    """Training-side timeline event (checkpoint writer, tier worker,
+    delta poll): no trace id — rendered on the
     process/thread track. Flushed IMMEDIATELY: these are low-rate
     (save/poll cadence) and the processes emitting them get SIGKILLed by
     design (fault benches) — a buffered span that dies with the process

@@ -44,7 +44,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from deeprec_tpu.utils import hashing
+from deeprec_tpu.utils import hashing, scopes
 
 logger = logging.getLogger("deeprec_tpu.dedup")
 
@@ -206,6 +206,7 @@ def hash_dedup(
     return uids, inverse, counts, overflow
 
 
+@scopes.scoped(scopes.ENGINE_ROUTE)
 def route_ids(
     ids: jnp.ndarray,
     *,

@@ -39,7 +39,7 @@ from typing import Dict, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-from deeprec_tpu.utils import backend
+from deeprec_tpu.utils import backend, scopes
 
 _BLOCK = 8  # rows per grid step; sublane-aligned for f32
 _LANES = 128  # Mosaic HBM tiling: DMA row slices must be lane-aligned
@@ -252,7 +252,7 @@ def gather_rows_pair(values: jnp.ndarray, ix: jnp.ndarray, *,
     )
     out = pl.pallas_call(
         kernel,
-        name="gather_rows_pair",
+        name=scopes.KERNEL_GATHER_ROWS_PAIR,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((np_, D), values.dtype),
         interpret=interpret,
@@ -338,7 +338,7 @@ def apply_rows_sr_pair(values: jnp.ndarray, slot_ix: jnp.ndarray,
     )
     return pl.pallas_call(
         kernel,
-        name="apply_rows_sr_pair",
+        name=scopes.KERNEL_APPLY_ROWS_SR_PAIR,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(values.shape, values.dtype),
         input_output_aliases={3: 0},
@@ -416,7 +416,7 @@ def gather_rows(values: jnp.ndarray, ix: jnp.ndarray, *,
     )
     out = pl.pallas_call(
         kernel,
-        name="gather_rows",
+        name=scopes.KERNEL_GATHER_ROWS,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((np_, D), values.dtype),
         interpret=interpret,
@@ -527,7 +527,7 @@ def fused_gather_combine(values: jnp.ndarray, row_ix: jnp.ndarray,
     )
     out = pl.pallas_call(
         kernel,
-        name="fused_gather_combine",
+        name=scopes.KERNEL_FUSED_GATHER_COMBINE,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Bp, D), jnp.float32),
         interpret=interpret,
@@ -649,7 +649,7 @@ def apply_rows_sr(values: jnp.ndarray, slot_ix: jnp.ndarray,
     )
     return pl.pallas_call(
         kernel,
-        name="apply_rows_sr",
+        name=scopes.KERNEL_APPLY_ROWS_SR,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(values.shape, values.dtype),
         input_output_aliases={3: 0},
@@ -1000,7 +1000,7 @@ def fused_sparse_forward(values: jnp.ndarray, ids: jnp.ndarray, *,
     )
     out, uids, inv, cnt, ovf = pl.pallas_call(
         kernel,
-        name="fused_sparse_forward",
+        name=scopes.KERNEL_FUSED_SPARSE_FORWARD,
         grid_spec=grid_spec,
         out_shape=(
             jax.ShapeDtypeStruct((B, D), jnp.float32),
@@ -1231,7 +1231,7 @@ def fused_sparse_backward(values: jnp.ndarray,
     )
     outs = pl.pallas_call(
         kernel,
-        name="fused_sparse_backward",
+        name=scopes.KERNEL_FUSED_SPARSE_BACKWARD,
         grid_spec=grid_spec,
         out_shape=tuple(
             [jax.ShapeDtypeStruct(values.shape, values.dtype)]

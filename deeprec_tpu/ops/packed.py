@@ -32,6 +32,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from deeprec_tpu.ops import fused_lookup as _fl
+from deeprec_tpu.utils import scopes
 
 LANES = 128
 
@@ -79,6 +80,17 @@ def unpack_array(arr, capacity: int):
     return arr.reshape(capacity, -1)
 
 
+def _read_rows(arr, ix, use_pallas, pair_kernels, interpret):
+    """arr[ix] (clip) through the configured kernel, under `rows_gather`:
+    whatever a trace shows there that is not the row kernel is wrapper (the
+    loop jax builds round a vmapped kernel, its slices and fills)."""
+    with scopes.scope(scopes.ROWS_GATHER):
+        if use_pallas:
+            return _fl.gather_rows(arr, ix, pair_kernels=pair_kernels,
+                                   interpret=interpret)
+        return arr.at[ix].get(mode="clip")
+
+
 def gather_rows_any(arr: jnp.ndarray, ix: jnp.ndarray, capacity: int, *,
                     use_pallas: bool = False, pair_kernels: bool = False,
                     interpret: bool = False) -> jnp.ndarray:
@@ -90,17 +102,9 @@ def gather_rows_any(arr: jnp.ndarray, ix: jnp.ndarray, capacity: int, *,
     """
     p = row_factor(arr, capacity)
     if p == 1:
-        if use_pallas:
-            return _fl.gather_rows(arr, ix, pair_kernels=pair_kernels,
-                                   interpret=interpret)
-        return arr.at[ix].get(mode="clip")
+        return _read_rows(arr, ix, use_pallas, pair_kernels, interpret)
     ix = jnp.clip(ix.astype(jnp.int32), 0, capacity - 1)
-    g = ix // p
-    if use_pallas:
-        gran = _fl.gather_rows(arr, g, pair_kernels=pair_kernels,
-                               interpret=interpret)
-    else:
-        gran = arr.at[g].get(mode="clip")
+    gran = _read_rows(arr, ix // p, use_pallas, pair_kernels, interpret)
     n = ix.shape[0]
     w = arr.shape[1] // p
     sub = gran.reshape(n, p, w)
@@ -123,16 +127,20 @@ def scatter_rows_any(arr: jnp.ndarray, slot_ix: jnp.ndarray,
     granule occupy disjoint lanes, so the merge scatter cannot collide),
     then RMW whole granules; untouched lanes pass through SR unchanged
     (exactly-representable values round to themselves).
+
+    The row write itself stands under the `rows_scatter` scope (and the
+    packed layout's read of the old granules under `rows_gather`).
     """
     p = row_factor(arr, capacity)
     rows = rows.astype(jnp.float32)
     slot_ix = slot_ix.astype(jnp.int32)
     seed = jnp.asarray(seed, jnp.int32)
     if p == 1:
-        return _fl.apply_rows_sr(arr, slot_ix, rows, seed,
-                                 use_pallas=use_pallas,
-                                 pair_kernels=pair_kernels,
-                                 interpret=interpret)
+        with scopes.scope(scopes.ROWS_SCATTER):
+            return _fl.apply_rows_sr(arr, slot_ix, rows, seed,
+                                     use_pallas=use_pallas,
+                                     pair_kernels=pair_kernels,
+                                     interpret=interpret)
     u, w = rows.shape
     ok = slot_ix >= 0
     g = jnp.where(ok, slot_ix // p, -1)
@@ -144,15 +152,13 @@ def scatter_rows_any(arr: jnp.ndarray, slot_ix: jnp.ndarray,
     patch = jnp.zeros((u, p, w), jnp.float32).at[inv, r].set(rows)
     mask = jnp.zeros((u, p), bool).at[inv, r].set(ok)
     # Old granule contents ride the same DMA gather the lookup path uses.
-    if use_pallas:
-        gran = _fl.gather_rows(arr, jnp.clip(ug, 0, arr.shape[0] - 1),
-                               pair_kernels=pair_kernels,
-                               interpret=interpret)
-    else:
-        gran = arr.at[jnp.clip(ug, 0, arr.shape[0] - 1)].get(mode="clip")
+    gran = _read_rows(arr, jnp.clip(ug, 0, arr.shape[0] - 1), use_pallas,
+                      pair_kernels, interpret)
     merged = jnp.where(
         mask[:, :, None], patch, gran.reshape(u, p, w).astype(jnp.float32)
     ).reshape(u, p * w)
-    return _fl.apply_rows_sr(arr, jnp.where(ug >= 0, ug, -1), merged, seed,
-                             use_pallas=use_pallas,
-                             pair_kernels=pair_kernels, interpret=interpret)
+    with scopes.scope(scopes.ROWS_SCATTER):
+        return _fl.apply_rows_sr(arr, jnp.where(ug >= 0, ug, -1), merged,
+                                 seed, use_pallas=use_pallas,
+                                 pair_kernels=pair_kernels,
+                                 interpret=interpret)

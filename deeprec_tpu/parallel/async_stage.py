@@ -38,6 +38,7 @@ from jax.sharding import PartitionSpec as P
 from deeprec_tpu.parallel.trainer import ShardedTrainer
 from deeprec_tpu.training import metrics as M
 from deeprec_tpu.training.trainer import PipelineCarry, TrainState
+from deeprec_tpu.utils import scopes
 
 # The stale-by-one carry IS the generic pipeline carry (training/trainer.py):
 # TrainState + one batch's prefetched lookup. The exact pipelined scan
@@ -99,22 +100,20 @@ class AsyncShardedTrainer(ShardedTrainer):
             check_vma=False,
         )
         def run(state, batch):
-            tables = {
-                bname: self._squeeze(bname, ts)
-                for bname, ts in state.tables.items()
-            }
+            tables = self._squeeze_all(state.tables)
             # Split-phase lookup (route -> resolve -> finish) with
             # keep_rows=False: the stale apply never reuses the forward
             # residual (reuse_rows=False above), so the carried results
             # drop the owner-side [O, D] row buffer instead of hauling it
             # across dispatches and through the K-step scan carry.
-            routes = self._route_all(batch, True)
-            tables, pending = self._resolve_all(
-                tables, routes, state.step, True
-            )
-            views, bundle_res = self._finish_all(
-                tables, pending, batch, True, keep_rows=False
-            )
+            with scopes.scope(scopes.PHASE_LOOKUP_EXCHANGE):
+                routes = self._route_all(batch, True)
+                tables, pending = self._resolve_all(
+                    tables, routes, state.step, True
+                )
+                views, bundle_res = self._finish_all(
+                    tables, pending, batch, True, keep_rows=False
+                )
             new_state = TrainState(
                 step=state.step,
                 tables={
@@ -134,8 +133,11 @@ class AsyncShardedTrainer(ShardedTrainer):
     # ------------------------------------------------------------- step
 
     def train_step_async(self, astate: AsyncState, batch, lr=None):
-        lr = jnp.asarray(self.sparse_opt.lr if lr is None else lr, jnp.float32)
-        return self._async_step(astate, batch, lr)
+        with self._step_span():
+            lr = jnp.asarray(
+                self.sparse_opt.lr if lr is None else lr, jnp.float32
+            )
+            return self._async_step(astate, batch, lr)
 
     def train_steps_async(self, astate: AsyncState, batches, lr=None):
         """K inner async steps per staged dispatch — the multi-step device
@@ -146,13 +148,16 @@ class AsyncShardedTrainer(ShardedTrainer):
         from deeprec_tpu.parallel.mesh import shard_batch
         from deeprec_tpu.training.trainer import stack_batches
 
-        if isinstance(batches, (list, tuple)):
-            batches = shard_batch(
-                self.mesh, stack_batches(batches), axis=self.axis,
-                stacked=True,
+        with self._step_span():
+            if isinstance(batches, (list, tuple)):
+                batches = shard_batch(
+                    self.mesh, stack_batches(batches), axis=self.axis,
+                    stacked=True,
+                )
+            lr = jnp.asarray(
+                self.sparse_opt.lr if lr is None else lr, jnp.float32
             )
-        lr = jnp.asarray(self.sparse_opt.lr if lr is None else lr, jnp.float32)
-        return self._async_steps(astate, batches, lr)
+            return self._async_steps(astate, batches, lr)
 
     def _async_body(self, astate: AsyncState, batch_t, lr):
         """One async step on per-shard values (runs INSIDE shard_map).
@@ -163,18 +168,26 @@ class AsyncShardedTrainer(ShardedTrainer):
         prev_batch = astate.batch
 
         # (1) dense fwd/bwd on the STALE embeddings (batch t-1)
-        embs = {n: v[0].astype(jnp.float32) for n, v in views.items()}
-
         def loss_fn(dense, embs):
             inputs = self._build_inputs(embs, views, prev_batch)
             out = self.model.apply(dense, inputs, train=True)
             loss, out = self._loss_from_logits(out, prev_batch)
             return loss, out
 
-        (loss, out), (g_dense, g_embs) = jax.value_and_grad(
-            loss_fn, argnums=(0, 1), has_aux=True
-        )(state.dense, embs)
-        g_dense = jax.lax.pmean(g_dense, self.axis)
+        with scopes.scope(scopes.PHASE_DENSE_FWD_BWD):
+            embs = {n: v[0].astype(jnp.float32) for n, v in views.items()}
+            (loss, out), (g_dense, g_embs) = jax.value_and_grad(
+                loss_fn, argnums=(0, 1), has_aux=True
+            )(state.dense, embs)
+            g_dense = jax.lax.pmean(g_dense, self.axis)
+            mets = {"loss": jax.lax.pmean(loss, self.axis)}
+            if not isinstance(out, dict):
+                probs = jax.nn.sigmoid(out)
+                mets["accuracy"] = jax.lax.pmean(
+                    M.accuracy(probs, prev_batch["label"]), self.axis
+                )
+            else:
+                mets["accuracy"] = jnp.zeros(())
 
         # (2) exchange/lookup for batch t — reads the step-start tables,
         # no data dependency on (1): XLA overlaps it with the matmuls.
@@ -182,36 +195,32 @@ class AsyncShardedTrainer(ShardedTrainer):
         # stale apply below (that pre-apply gather IS the documented
         # staleness — the exact pipelined scan moves it after the apply).
         # keep_rows=False: the carried results never reuse the residual.
-        tables = {
-            bname: self._squeeze(bname, ts)
-            for bname, ts in state.tables.items()
-        }
-        routes_t = self._route_all(batch_t, True)
-        tables, pending_t = self._resolve_all(tables, routes_t, step, True)
-        views_t, res_t = self._finish_all(
-            tables, pending_t, batch_t, True, keep_rows=False
-        )
+        tables = self._squeeze_all(state.tables)
+        with scopes.scope(scopes.PHASE_LOOKUP_EXCHANGE):
+            routes_t = self._route_all(batch_t, True)
+            tables, pending_t = self._resolve_all(
+                tables, routes_t, step, True
+            )
+            views_t, res_t = self._finish_all(
+                tables, pending_t, batch_t, True, keep_rows=False
+            )
 
         # (3) stale-apply batch t-1's sparse grads
-        tables = self._apply_all(tables, astate.bundle_res, g_embs, step, lr)
+        with scopes.scope(scopes.PHASE_SPARSE_APPLY):
+            tables = self._apply_all(
+                tables, astate.bundle_res, g_embs, step, lr
+            )
 
         # (4) dense update
-        updates, opt_state = self.dense_opt.update(
-            g_dense, state.opt_state, state.dense
-        )
-        dense = optax.apply_updates(state.dense, updates)
-
-        mets = {"loss": jax.lax.pmean(loss, self.axis)}
-        if not isinstance(out, dict):
-            probs = jax.nn.sigmoid(out)
-            mets["accuracy"] = jax.lax.pmean(
-                M.accuracy(probs, prev_batch["label"]), self.axis
+        with scopes.scope(scopes.PHASE_DENSE_APPLY):
+            updates, opt_state = self.dense_opt.update(
+                g_dense, state.opt_state, state.dense
             )
-        else:
-            mets["accuracy"] = jnp.zeros(())
+            dense = optax.apply_updates(state.dense, updates)
+            step = step + 1
 
         new_inner = TrainState(
-            step=step + 1,
+            step=step,
             tables={
                 bname: self._unsqueeze(bname, ts)
                 for bname, ts in tables.items()

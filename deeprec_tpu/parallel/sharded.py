@@ -45,12 +45,11 @@ import jax
 import jax.numpy as jnp
 from flax import struct
 
-from deeprec_tpu.training.profiler import phase_scope
-
 from deeprec_tpu.embedding.table import EmbeddingTable, TableState, UniqueLookup, empty_key
 from deeprec_tpu.optim import apply as optim_apply
 from deeprec_tpu.optim.sparse import SparseOptimizer
 from deeprec_tpu.parallel.mesh import DATA_AXIS, AxisSpec
+from deeprec_tpu.utils import scopes
 
 
 @struct.dataclass
@@ -450,7 +449,7 @@ class ShardedTable:
         )
         parts = []
         for ci, (a, b) in enumerate(self._col_chunks(e_g.shape[1])):
-            with phase_scope(f"exchange_chunk{ci}"):
+            with scopes.scope(scopes.exchange_chunk(ci)):
                 parts.append(jax.lax.psum_scatter(
                     e_g[:, a:b].astype(wire), self.axis,
                     scatter_dimension=0, tiled=True,
@@ -566,7 +565,7 @@ class ShardedTable:
         e_out = e_out * sl.owned[:, None].astype(wire)
         parts = []
         for ci, (a, b) in enumerate(self._col_chunks(e_out.shape[1])):
-            with phase_scope(f"exchange_chunk{ci}"):
+            with scopes.scope(scopes.exchange_chunk(ci)):
                 parts.append(jax.lax.all_to_all(
                     e_out[:, a:b].reshape(N, Bd, b - a), self.axis,
                     split_axis=0, concat_axis=0, tiled=True,
@@ -609,7 +608,7 @@ class ShardedTable:
                 .at[sslot_safe]
                 .set(grad_u[:, a:b].astype(wire), mode="drop")
             )
-            with phase_scope(f"exchange_chunk{ci}"):
+            with scopes.scope(scopes.exchange_chunk(ci)):
                 g_recv = jax.lax.all_to_all(
                     g_buf.reshape(N, Bd, b - a), self.axis, split_axis=0,
                     concat_axis=0, tiled=True,
@@ -685,7 +684,7 @@ class ShardedTable:
         U = uids.shape[0]
 
         # --- intra tier: id/count gather inside the host group.
-        with phase_scope("hier_intra_ids"):
+        with scopes.scope(scopes.HIER_INTRA_IDS):
             g_uids = jax.lax.all_gather(uids, ia, tiled=True)  # [I*U]
             g_counts = jax.lax.all_gather(counts, ia, tiled=True)
         owner = placement.plan_owner(g_uids, N, plan)  # [I*U]
@@ -730,7 +729,7 @@ class ShardedTable:
         buf_counts = jnp.zeros((J * Bg,), jnp.int32).at[sslot_safe].set(
             r_counts, mode="drop"
         )
-        with phase_scope("hier_inter_ids"):
+        with scopes.scope(scopes.HIER_INTER_IDS):
             recv_ids = jax.lax.all_to_all(
                 buf_ids.reshape(J, Bg), ea, split_axis=0, concat_axis=0,
                 tiled=True,
@@ -772,7 +771,7 @@ class ShardedTable:
         )
         parts = []
         for ci, (a, b) in enumerate(self._col_chunks(D)):
-            with phase_scope(f"hier_inter_chunk{ci}"):
+            with scopes.scope(scopes.hier_inter_chunk(ci)):
                 e_back = jax.lax.all_to_all(
                     e_out[:, a:b].reshape(J, Bg, b - a), ea,
                     split_axis=0, concat_axis=0, tiled=True,
@@ -789,7 +788,7 @@ class ShardedTable:
             e_g = v_r[sl.h_r_inverse] * sl.h_rel_mask[:, None].astype(
                 jnp.float32
             )
-            with phase_scope(f"hier_intra_chunk{ci}"):
+            with scopes.scope(scopes.hier_intra_chunk(ci)):
                 parts.append(jax.lax.psum_scatter(
                     e_g.astype(wire), ia, scatter_dimension=0, tiled=True,
                 ))
@@ -818,7 +817,7 @@ class ShardedTable:
             # dtype; the relay segment-sums its positions in fp32 (the
             # cross-device duplicate merge happens HERE, before the
             # expensive tier — the byte diet of the whole design).
-            with phase_scope(f"hier_intra_chunk{ci}"):
+            with scopes.scope(scopes.hier_intra_chunk(ci)):
                 g_g = jax.lax.all_gather(
                     grad_u[:, a:b].astype(wire), ia, tiled=True
                 )  # [I*U, b-a]
@@ -835,7 +834,7 @@ class ShardedTable:
                 .at[sslot_safe]
                 .set(r_grad.astype(wire), mode="drop")
             )
-            with phase_scope(f"hier_inter_chunk{ci}"):
+            with scopes.scope(scopes.hier_inter_chunk(ci)):
                 g_recv = jax.lax.all_to_all(
                     g_buf.reshape(J, Bg, b - a), ea, split_axis=0,
                     concat_axis=0, tiled=True,
@@ -896,7 +895,7 @@ class ShardedTable:
         O = sl.owner_res.uids.shape[0]
         parts = []
         for ci, (a, b) in enumerate(self._col_chunks(D)):
-            with phase_scope(f"exchange_chunk{ci}"):
+            with scopes.scope(scopes.exchange_chunk(ci)):
                 g_g = jax.lax.all_gather(
                     grad_u[:, a:b].astype(wire), self.axis, tiled=True
                 )  # [G, b-a] — G = N·U shrinks with the unique budget

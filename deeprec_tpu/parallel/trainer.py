@@ -39,6 +39,7 @@ from deeprec_tpu.training.trainer import (
     TrainState,
     stack_batches,
 )
+from deeprec_tpu.utils import scopes
 
 
 def _local_cfg(cfg, num_shards: int):
@@ -220,6 +221,13 @@ class ShardedTrainer(Trainer):
     def _squeeze(self, bname, ts):
         ax = 1 if self.bundles[bname].stacked else 0
         return jax.tree.map(lambda a: jnp.squeeze(a, axis=ax), ts)
+
+    def _squeeze_all(self, tables):
+        """Every bundle's per-shard view (the shard axis off): the first
+        thing a step body does with the tables, so part of its lookup."""
+        with scopes.scope(scopes.PHASE_LOOKUP_EXCHANGE):
+            return {bname: self._squeeze(bname, ts)
+                    for bname, ts in tables.items()}
 
     def _unsqueeze(self, bname, ts):
         ax = 1 if self.bundles[bname].stacked else 0
@@ -904,11 +912,10 @@ class ShardedTrainer(Trainer):
     def _sharded_micro(self, tables, dense, batch, step, lr):
         """One (micro-)batch inside shard_map: lookups, fwd/bwd, sparse
         applies; returns tables, pmean'd dense grads (unapplied), metrics."""
-        with jax.named_scope("phase_lookup_exchange"):
+        with scopes.scope(scopes.PHASE_LOOKUP_EXCHANGE):
             tables, views, bundle_res = self._lookup_all(
                 tables, batch, step, True
             )
-        embs = {n: v[0].astype(jnp.float32) for n, v in views.items()}
 
         def loss_fn(dense, embs):
             inputs = self._build_inputs(embs, views, batch)
@@ -916,23 +923,24 @@ class ShardedTrainer(Trainer):
             loss, out = self._loss_from_logits(out, batch)
             return loss, out
 
-        with jax.named_scope("phase_dense_fwd_bwd"):
+        with scopes.scope(scopes.PHASE_DENSE_FWD_BWD):
+            embs = {n: v[0].astype(jnp.float32) for n, v in views.items()}
             (loss, out), (g_dense, g_embs) = jax.value_and_grad(
                 loss_fn, argnums=(0, 1), has_aux=True
             )(dense, embs)
-        # Data-parallel dense grads: mean over replicas via ICI allreduce.
-        g_dense = jax.lax.pmean(g_dense, self.axis)
-        with jax.named_scope("phase_sparse_apply"):
+            # Data-parallel dense grads: mean over replicas via ICI
+            # allreduce.
+            g_dense = jax.lax.pmean(g_dense, self.axis)
+            mets = {"loss": jax.lax.pmean(loss, self.axis)}
+            if not isinstance(out, dict):
+                probs = jax.nn.sigmoid(out)
+                mets["accuracy"] = jax.lax.pmean(
+                    M.accuracy(probs, batch["label"]), self.axis
+                )
+            else:
+                mets["accuracy"] = jnp.zeros(())
+        with scopes.scope(scopes.PHASE_SPARSE_APPLY):
             tables = self._apply_all(tables, bundle_res, g_embs, step, lr)
-
-        mets = {"loss": jax.lax.pmean(loss, self.axis)}
-        if not isinstance(out, dict):
-            probs = jax.nn.sigmoid(out)
-            mets["accuracy"] = jax.lax.pmean(
-                M.accuracy(probs, batch["label"]), self.axis
-            )
-        else:
-            mets["accuracy"] = jnp.zeros(())
         return tables, g_dense, mets
 
     def _sharded_body(self, state: TrainState, batch, lr):
@@ -940,19 +948,18 @@ class ShardedTrainer(Trainer):
         squeeze the shard axis off the tables, micro-step, dense update,
         re-wrap. Shared by the single-step path and the K-step scan."""
         step = state.step
-        tables = {
-            bname: self._squeeze(bname, ts)
-            for bname, ts in state.tables.items()
-        }
+        tables = self._squeeze_all(state.tables)
         tables, g_dense, mets = self._sharded_micro(
             tables, state.dense, batch, step, lr
         )
-        updates, opt_state = self.dense_opt.update(
-            g_dense, state.opt_state, state.dense
-        )
-        dense = optax.apply_updates(state.dense, updates)
+        with scopes.scope(scopes.PHASE_DENSE_APPLY):
+            updates, opt_state = self.dense_opt.update(
+                g_dense, state.opt_state, state.dense
+            )
+            dense = optax.apply_updates(state.dense, updates)
+            step = step + 1
         new_state = TrainState(
-            step=step + 1,
+            step=step,
             tables={
                 bname: self._unsqueeze(bname, ts)
                 for bname, ts in tables.items()
@@ -1018,13 +1025,13 @@ class ShardedTrainer(Trainer):
         window's first batch (same program as the sequential lookup)."""
         from deeprec_tpu.training.trainer import PipelineCarry
 
-        tables = {
-            bname: self._squeeze(bname, ts)
-            for bname, ts in state.tables.items()
-        }
-        routes = self._route_all(batch0, True)
-        tables, pending = self._resolve_all(tables, routes, state.step, True)
-        views, res = self._finish_all(tables, pending, batch0, True)
+        tables = self._squeeze_all(state.tables)
+        with scopes.scope(scopes.PHASE_LOOKUP_EXCHANGE):
+            routes = self._route_all(batch0, True)
+            tables, pending = self._resolve_all(
+                tables, routes, state.step, True
+            )
+            views, res = self._finish_all(tables, pending, batch0, True)
         new_state = TrainState(
             step=state.step,
             tables={
@@ -1064,19 +1071,15 @@ class ShardedTrainer(Trainer):
 
         state = carry.inner
         step = state.step
-        tables = {
-            bname: self._squeeze(bname, ts)
-            for bname, ts in state.tables.items()
-        }
+        tables = self._squeeze_all(state.tables)
         if batch_next is not None:
-            with jax.named_scope("phase_route_next"):
+            with scopes.scope(scopes.PHASE_ROUTE_NEXT):
                 routes = self._route_all(batch_next, True)
                 tables, pending = self._resolve_all(
                     tables, routes, step + 1, True
                 )
         views = carry.views
         prev_batch = carry.batch
-        embs = {n: v[0].astype(jnp.float32) for n, v in views.items()}
 
         def loss_fn(dense, embs):
             inputs = self._build_inputs(embs, views, prev_batch)
@@ -1084,34 +1087,37 @@ class ShardedTrainer(Trainer):
             loss, out = self._loss_from_logits(out, prev_batch)
             return loss, out
 
-        with jax.named_scope("phase_dense_fwd_bwd"):
+        with scopes.scope(scopes.PHASE_DENSE_FWD_BWD):
+            embs = {n: v[0].astype(jnp.float32) for n, v in views.items()}
             (loss, out), (g_dense, g_embs) = jax.value_and_grad(
                 loss_fn, argnums=(0, 1), has_aux=True
             )(state.dense, embs)
-        g_dense = jax.lax.pmean(g_dense, self.axis)
-        with jax.named_scope("phase_sparse_apply"):
+            g_dense = jax.lax.pmean(g_dense, self.axis)
+            mets = {"loss": jax.lax.pmean(loss, self.axis)}
+            if not isinstance(out, dict):
+                probs = jax.nn.sigmoid(out)
+                mets["accuracy"] = jax.lax.pmean(
+                    M.accuracy(probs, prev_batch["label"]), self.axis
+                )
+            else:
+                mets["accuracy"] = jnp.zeros(())
+        with scopes.scope(scopes.PHASE_SPARSE_APPLY):
             tables = self._apply_all(tables, carry.bundle_res, g_embs, step, lr)
         if batch_next is not None:
-            with jax.named_scope("phase_finish_exchange"):
+            with scopes.scope(scopes.PHASE_FINISH_EXCHANGE):
                 views_n, res_n = self._finish_all(
                     tables, pending, batch_next, True
                 )
         else:
             batch_next, views_n, res_n = prev_batch, views, carry.bundle_res
-        updates, opt_state = self.dense_opt.update(
-            g_dense, state.opt_state, state.dense
-        )
-        dense = optax.apply_updates(state.dense, updates)
-        mets = {"loss": jax.lax.pmean(loss, self.axis)}
-        if not isinstance(out, dict):
-            probs = jax.nn.sigmoid(out)
-            mets["accuracy"] = jax.lax.pmean(
-                M.accuracy(probs, prev_batch["label"]), self.axis
+        with scopes.scope(scopes.PHASE_DENSE_APPLY):
+            updates, opt_state = self.dense_opt.update(
+                g_dense, state.opt_state, state.dense
             )
-        else:
-            mets["accuracy"] = jnp.zeros(())
+            dense = optax.apply_updates(state.dense, updates)
+            step = step + 1
         new_state = TrainState(
-            step=step + 1,
+            step=step,
             tables={
                 bname: self._unsqueeze(bname, ts)
                 for bname, ts in tables.items()
@@ -1177,10 +1183,7 @@ class ShardedTrainer(Trainer):
         def run(state, batch, lr):
             step = state.step
             A = next(iter(batch.values())).shape[0]
-            tables0 = {
-                bname: self._squeeze(bname, ts)
-                for bname, ts in state.tables.items()
-            }
+            tables0 = self._squeeze_all(state.tables)
 
             def micro(carry, mb):
                 tables, g_acc = carry
@@ -1191,11 +1194,12 @@ class ShardedTrainer(Trainer):
 
             g0 = jax.tree.map(jnp.zeros_like, state.dense)
             (tables, g_acc), mets = jax.lax.scan(micro, (tables0, g0), batch)
-            g_mean = jax.tree.map(lambda g: g / jnp.float32(A), g_acc)
-            updates, opt_state = self.dense_opt.update(
-                g_mean, state.opt_state, state.dense
-            )
-            dense = optax.apply_updates(state.dense, updates)
+            with scopes.scope(scopes.PHASE_DENSE_APPLY):
+                g_mean = jax.tree.map(lambda g: g / jnp.float32(A), g_acc)
+                updates, opt_state = self.dense_opt.update(
+                    g_mean, state.opt_state, state.dense
+                )
+                dense = optax.apply_updates(state.dense, updates)
             new_state = TrainState(
                 step=step + 1,
                 tables={
@@ -1234,10 +1238,7 @@ class ShardedTrainer(Trainer):
             check_vma=False,
         )
         def run(state, batch):
-            tables = {
-                bname: self._squeeze(bname, ts)
-                for bname, ts in state.tables.items()
-            }
+            tables = self._squeeze_all(state.tables)
             tables, views, _ = self._lookup_all(
                 tables, batch, state.step, False
             )

@@ -60,7 +60,7 @@ import numpy as np
 from deeprec_tpu.analysis.annotations import not_thread_safe
 from deeprec_tpu.embedding.table import EmbeddingTable, TableState, empty_key
 from deeprec_tpu.training.trainer import TrainState, Trainer
-from deeprec_tpu.utils import hashing
+from deeprec_tpu.utils import hashing, scopes
 
 _log = logging.getLogger(__name__)
 
@@ -916,7 +916,8 @@ class CheckpointManager:
         self.wait()  # at most one save in flight
         kind = self._effective_kind(kind)
         t0 = time.perf_counter()
-        plan = self._stage(state, kind, snapshot=True)
+        with scopes.host_span(scopes.CKPT_SAVE):  # the caller-side half
+            plan = self._stage(state, kind, snapshot=True)
         # Account (and rebind last_save) BEFORE the writer starts: a fast
         # writer could otherwise finish and stamp write_ms into the
         # PREVIOUS save's record right as this one replaces it.
@@ -986,6 +987,7 @@ class CheckpointManager:
 
     # ------------------------------------------------------- save halves
 
+    @scopes.host_spanned(scopes.CKPT_SAVE)
     def _save(self, state: TrainState, kind: str) -> Tuple[TrainState, str]:
         self.wait()  # serialize behind any in-flight async save
         kind = self._effective_kind(kind)
@@ -1386,6 +1388,7 @@ class CheckpointManager:
             for d in names
         )
 
+    @scopes.host_spanned(scopes.CKPT_RESTORE)
     def restore(self, template: Optional[TrainState] = None,
                 chunk: Optional[int] = None) -> TrainState:
         """Latest full checkpoint + all newer deltas, onto the trainer's

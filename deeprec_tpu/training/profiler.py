@@ -3,127 +3,20 @@
 DeepRec exposes per-step timelines via RunOptions.trace_level +
 StepStatsCollector and modelzoo --timeline flags (SURVEY.md §5). On TPU the
 native equivalent is the XLA/JAX profiler: traces capture HLO-level device
-timelines viewable in TensorBoard/Perfetto. One context manager + a
-step-windowed helper matching the reference's "--timeline N" UX.
+timelines viewable in TensorBoard/Perfetto. `StepWindowTracer` is the
+step-windowed helper matching the reference's "--timeline N" UX; what the
+trace then names (the step's phases, the engine's stages, the host spans)
+is the vocabulary of utils/scopes.py, and docs/profiling.md says how to
+reduce a trace by it. `LatencyHistogram` is the serving stages' timer.
 """
 from __future__ import annotations
 
 import bisect
-import contextlib
 import os
 import threading
-import time
-from typing import Dict, Iterator
+from typing import Dict
 
 import jax
-
-
-@contextlib.contextmanager
-def trace(logdir: str = "/tmp/deeprec_tpu_trace") -> Iterator[str]:
-    """Capture a device trace for the enclosed block."""
-    os.makedirs(logdir, exist_ok=True)
-    jax.profiler.start_trace(logdir)
-    try:
-        yield logdir
-    finally:
-        jax.profiler.stop_trace()
-
-
-def phase_scope(name: str):
-    """`jax.named_scope("phase_<name>")` — the in-program half of phase
-    attribution (see PhaseProfiler): ops emitted under it group per phase
-    in device traces. The trainers wrap their step phases in it
-    (lookup / route_next / dense_fwd_bwd / sparse_apply /
-    finish_exchange), and the chunked exchange (`ShardedTable` with
-    exchange_chunks > 1) scopes each column-chunk collective as
-    `exchange_chunk<i>` so a trace shows the chunk pipeline instead of
-    one opaque collective."""
-    return jax.named_scope(f"phase_{name}")
-
-
-class PhaseProfiler:
-    """Named-phase step breakdown (lookup / exchange / dense fwd-bwd /
-    sparse apply / metadata ...).
-
-    Two halves, matching how phase attribution works on an async device:
-
-      * Inside the compiled step the trainers wrap each phase in
-        `jax.named_scope("phase_<name>")` (training/trainer.py), so device
-        traces (StepWindowTracer / `trace()`) group the emitted ops per
-        phase — that is where TPU per-phase DEVICE time comes from.
-      * Host-side, `phase(name)` wraps a blocking call (e.g. a jitted
-        sub-program of just the lookups, or lookup+apply) in a
-        `jax.profiler.TraceAnnotation` plus a wall-clock accumulator;
-        `phase_report()` returns {phase: {calls, total_ms, mean_ms}}.
-        `bench.py --profile` uses this to time phase sub-programs and
-        report where the step went — the measurement that verifies a hot-
-        path diet actually moved engine time, without trace parsing.
-
-    The two compose: annotations from (2) bracket the dispatches of (1) on
-    the host timeline when a trace is being captured.
-    """
-
-    def __init__(self):
-        self._times: Dict[str, list] = {}
-
-    @contextlib.contextmanager
-    def phase(self, name: str, block=None) -> Iterator[None]:
-        """Time the enclosed block under `name`. Pass `block` (an array or
-        pytree) to `jax.block_until_ready` before the clock stops so async
-        dispatch doesn't attribute device time to the NEXT phase.
-
-        When obs tracing is configured (DEEPREC_TRACE), each phase also
-        lands as a timeline span in the obs JSONL — the training half of
-        the train→delta→serve Perfetto timeline (tools/obs_trace.py)."""
-        from deeprec_tpu.obs import trace as obs_trace
-
-        t0 = time.perf_counter()
-        t0w = time.time()
-        with jax.profiler.TraceAnnotation(f"phase_{name}"):
-            try:
-                yield
-            finally:
-                if block is not None:
-                    jax.block_until_ready(block)  # noqa: DRT002 — the profiler's purpose: phase attribution requires blocking
-                self._times.setdefault(name, []).append(
-                    time.perf_counter() - t0
-                )
-                obs_trace.phase_span(f"phase_{name}", t0w, time.time())
-
-    def timed(self, name: str, fn, *args, **kwargs):
-        """Run fn(*args, **kwargs), block on its result, record under
-        `name`, return the result."""
-        out = None
-        with self.phase(name):
-            out = fn(*args, **kwargs)
-            jax.block_until_ready(out)
-        return out
-
-    def record(self, name: str, seconds: float) -> None:
-        """Fold an externally measured duration into phase `name` — the
-        entry point for HOST phases whose cost is accounted elsewhere:
-        checkpoint stalls (CheckpointManager.last_save["stall_ms"]),
-        multi-tier sync stalls (MultiTierTable.sync_stall_ms), writer
-        drain time. These subsystems time themselves (their stalls span
-        their own internal sync points), so the profiler takes the number
-        instead of wrapping the call."""
-        self._times.setdefault(name, []).append(float(seconds))
-
-    def reset(self) -> None:
-        self._times.clear()
-
-    def phase_report(self) -> Dict[str, Dict[str, float]]:
-        """{phase: {calls, total_ms, mean_ms, min_ms}} over everything
-        recorded since the last reset()."""
-        out = {}
-        for name, ts in self._times.items():
-            out[name] = {
-                "calls": len(ts),
-                "total_ms": round(sum(ts) * 1e3, 3),
-                "mean_ms": round(sum(ts) / len(ts) * 1e3, 3),
-                "min_ms": round(min(ts) * 1e3, 3),
-            }
-        return out
 
 
 class LatencyHistogram:

@@ -38,6 +38,7 @@ from deeprec_tpu.features import SparseFeature
 from deeprec_tpu.optim.apply import apply_gradients, ensure_slots
 from deeprec_tpu.optim.sparse import SparseOptimizer
 from deeprec_tpu.training import metrics as M
+from deeprec_tpu.utils import scopes
 
 
 @struct.dataclass
@@ -256,6 +257,7 @@ class Trainer:
         }
         self._auto_frac: Dict[str, float] = {}  # bundle -> budget fraction
         self._unique_ema: Dict[str, float] = {}  # bundle -> raw EMA
+        self._dispatches = 0  # train dispatches so far: the step spans' number
         self._make_jits()
 
     def _make_jits(self):
@@ -618,14 +620,13 @@ class Trainer:
         """Forward + backward + SPARSE applies for one (micro-)batch; returns
         updated tables, the dense-grad pytree (NOT applied) and metrics.
 
-        Phases carry `jax.named_scope` annotations (training/profiler.py:
-        the per-phase step breakdown) so device traces group the emitted
-        ops under lookup / dense fwd-bwd / sparse apply."""
-        with jax.named_scope("phase_lookup"):
+        Every operation stands under one phase scope (utils/scopes.py),
+        so a device trace splits the step by lookup / dense fwd-bwd /
+        sparse apply."""
+        with scopes.scope(scopes.PHASE_LOOKUP):
             tables, views, bundle_res = self._lookup_all(
                 tables, batch, step, True
             )
-        embs = {n: v[0].astype(jnp.float32) for n, v in views.items()}
 
         def loss_fn(dense, embs):
             inputs = self._build_inputs(embs, views, batch)
@@ -638,20 +639,21 @@ class Trainer:
             loss, out = self._loss_from_logits(out, batch)
             return loss, out
 
-        with jax.named_scope("phase_dense_fwd_bwd"):
+        with scopes.scope(scopes.PHASE_DENSE_FWD_BWD):
+            embs = {n: v[0].astype(jnp.float32) for n, v in views.items()}
             (loss, out), (g_dense, g_embs) = jax.value_and_grad(
                 loss_fn, argnums=(0, 1), has_aux=True
             )(dense, embs)
-        with jax.named_scope("phase_sparse_apply"):
+            mets = {"loss": loss}
+            if not isinstance(out, dict):
+                probs = jax.nn.sigmoid(out)
+                mets["accuracy"] = M.accuracy(probs, batch["label"])
+            else:
+                mets["accuracy"] = jnp.zeros(())
+        with scopes.scope(scopes.PHASE_SPARSE_APPLY):
             tables = self._apply_all(tables, bundle_res, g_embs, step, lr)
-        mets = {"loss": loss}
-        if not isinstance(out, dict):
-            probs = jax.nn.sigmoid(out)
-            mets["accuracy"] = M.accuracy(probs, batch["label"])
-        else:
-            mets["accuracy"] = jnp.zeros(())
         if self.sentinel is not None:
-            with jax.named_scope("phase_sentinel"):
+            with scopes.scope(scopes.PHASE_SENTINEL):
                 tables, mets["_sentinel"] = self._sentinel_observe(
                     tables, bundle_res, loss, g_dense, g_embs, step
                 )
@@ -739,12 +741,16 @@ class Trainer:
             dict(state.tables), state.dense, batch, step, lr
         )
         if self.sentinel is not None:
-            mets, guard = self._sentinel_fold(mets, guard)
-        updates, opt_state = self.dense_opt.update(g_dense, state.opt_state,
-                                                   state.dense)
-        dense = optax.apply_updates(state.dense, updates)
+            with scopes.scope(scopes.PHASE_SENTINEL):
+                mets, guard = self._sentinel_fold(mets, guard)
+        with scopes.scope(scopes.PHASE_DENSE_APPLY):
+            updates, opt_state = self.dense_opt.update(
+                g_dense, state.opt_state, state.dense
+            )
+            dense = optax.apply_updates(state.dense, updates)
+            step = step + 1
         return TrainState(
-            step=step + 1, tables=tables, dense=dense, opt_state=opt_state
+            step=step, tables=tables, dense=dense, opt_state=opt_state
         ), mets
 
     def _accum_impl(self, state: TrainState, batch, lr, guard=None):
@@ -768,10 +774,12 @@ class Trainer:
         (tables, g_acc), mets = jax.lax.scan(
             micro, (dict(state.tables), g0), batch
         )
-        g_mean = jax.tree.map(lambda g: g / jnp.float32(A), g_acc)
-        updates, opt_state = self.dense_opt.update(g_mean, state.opt_state,
-                                                   state.dense)
-        dense = optax.apply_updates(state.dense, updates)
+        with scopes.scope(scopes.PHASE_DENSE_APPLY):
+            g_mean = jax.tree.map(lambda g: g / jnp.float32(A), g_acc)
+            updates, opt_state = self.dense_opt.update(
+                g_mean, state.opt_state, state.dense
+            )
+            dense = optax.apply_updates(state.dense, updates)
         sen = mets.pop("_sentinel", None)  # [A]-stacked micro observations
         mets = jax.tree.map(jnp.mean, mets)
         if self.sentinel is not None and sen is not None:
@@ -834,9 +842,12 @@ class Trainer:
         """Fill the pipeline: full split-phase lookup of the window's
         first batch (identical program to the sequential lookup)."""
         tables = dict(state.tables)
-        routes = self._route_all(batch0, True)
-        tables, pending = self._resolve_all(tables, routes, state.step, True)
-        views, res = self._finish_all(tables, pending, batch0, True)
+        with scopes.scope(scopes.PHASE_LOOKUP):
+            routes = self._route_all(batch0, True)
+            tables, pending = self._resolve_all(
+                tables, routes, state.step, True
+            )
+            views, res = self._finish_all(tables, pending, batch0, True)
         return PipelineCarry(
             inner=TrainState(step=state.step, tables=tables,
                              dense=state.dense, opt_state=state.opt_state),
@@ -865,14 +876,13 @@ class Trainer:
         step = state.step
         tables = dict(state.tables)
         if batch_next is not None:
-            with jax.named_scope("phase_route_next"):
+            with scopes.scope(scopes.PHASE_ROUTE_NEXT):
                 routes = self._route_all(batch_next, True)
                 tables, pending = self._resolve_all(
                     tables, routes, step + 1, True
                 )
         views = carry.views
         prev_batch = carry.batch
-        embs = {n: v[0].astype(jnp.float32) for n, v in views.items()}
 
         def loss_fn(dense, embs):
             inputs = self._build_inputs(embs, views, prev_batch)
@@ -885,40 +895,43 @@ class Trainer:
             loss, out = self._loss_from_logits(out, prev_batch)
             return loss, out
 
-        with jax.named_scope("phase_dense_fwd_bwd"):
+        with scopes.scope(scopes.PHASE_DENSE_FWD_BWD):
+            embs = {n: v[0].astype(jnp.float32) for n, v in views.items()}
             (loss, out), (g_dense, g_embs) = jax.value_and_grad(
                 loss_fn, argnums=(0, 1), has_aux=True
             )(state.dense, embs)
-        with jax.named_scope("phase_sparse_apply"):
+            mets = {"loss": loss}
+            if not isinstance(out, dict):
+                probs = jax.nn.sigmoid(out)
+                mets["accuracy"] = M.accuracy(probs, prev_batch["label"])
+            else:
+                mets["accuracy"] = jnp.zeros(())
+        with scopes.scope(scopes.PHASE_SPARSE_APPLY):
             tables = self._apply_all(tables, carry.bundle_res, g_embs, step, lr)
-        mets = {"loss": loss}
-        if not isinstance(out, dict):
-            probs = jax.nn.sigmoid(out)
-            mets["accuracy"] = M.accuracy(probs, prev_batch["label"])
-        else:
-            mets["accuracy"] = jnp.zeros(())
         guard = carry.guard
         if self.sentinel is not None:
             # Sentinel over batch t: the apply above wrote batch t's rows,
             # so the row pass reads them BEFORE finish(t+1)'s gather.
-            with jax.named_scope("phase_sentinel"):
+            with scopes.scope(scopes.PHASE_SENTINEL):
                 tables, mets["_sentinel"] = self._sentinel_observe(
                     tables, carry.bundle_res, loss, g_dense, g_embs, step
                 )
-            mets, guard = self._sentinel_fold(mets, guard)
+                mets, guard = self._sentinel_fold(mets, guard)
         if batch_next is not None:
-            with jax.named_scope("phase_finish_exchange"):
+            with scopes.scope(scopes.PHASE_FINISH_EXCHANGE):
                 views_n, res_n = self._finish_all(
                     tables, pending, batch_next, True
                 )
         else:
             batch_next, views_n, res_n = prev_batch, views, carry.bundle_res
-        updates, opt_state = self.dense_opt.update(
-            g_dense, state.opt_state, state.dense
-        )
-        dense = optax.apply_updates(state.dense, updates)
+        with scopes.scope(scopes.PHASE_DENSE_APPLY):
+            updates, opt_state = self.dense_opt.update(
+                g_dense, state.opt_state, state.dense
+            )
+            dense = optax.apply_updates(state.dense, updates)
+            step = step + 1
         new_state = TrainState(
-            step=step + 1, tables=tables, dense=dense, opt_state=opt_state
+            step=step, tables=tables, dense=dense, opt_state=opt_state
         )
         return PipelineCarry(
             inner=new_state, batch=batch_next, views=views_n,
@@ -997,10 +1010,11 @@ class Trainer:
         device transfer (device_put returns immediately). Idempotent —
         re-staging a staged batch is a cheap no-op."""
         keep = self.input_keys()
-        return self._stage_put({
-            k: v for k, v in batch.items()
-            if k in keep or k.startswith("label")
-        })
+        with scopes.host_span(scopes.STAGE_BATCH):
+            return self._stage_put({
+                k: v for k, v in batch.items()
+                if k in keep or k.startswith("label")
+            })
 
     def _stage_put(self, batch):
         # ShardedTrainer overrides with mesh placement.
@@ -1046,6 +1060,13 @@ class Trainer:
 
         return guard if guard is not None else guard_init()
 
+    def _step_span(self):
+        """The host span of one train dispatch (`deeprec.train_step`),
+        numbered by a host count of dispatches."""
+        n = self._dispatches
+        self._dispatches = n + 1
+        return scopes.step_span(n)
+
     def train_step(self, state: TrainState, batch, lr: Optional[float] = None,
                    guard=None):
         # lr always rides as a traced scalar so schedules never recompile.
@@ -1053,10 +1074,15 @@ class Trainer:
         # (guard/sentinel.guard_carry) — a device reference, never read
         # host-side here; omitted entirely when no sentinel is configured
         # so sentinel-less trainers trace the exact legacy signature.
-        lr = jnp.asarray(self.sparse_opt.lr if lr is None else lr, jnp.float32)
-        if self.sentinel is None:
-            return self._train_step(state, batch, lr)
-        return self._train_step(state, batch, lr, self._guard_or_init(guard))
+        with self._step_span():
+            lr = jnp.asarray(
+                self.sparse_opt.lr if lr is None else lr, jnp.float32
+            )
+            if self.sentinel is None:
+                return self._train_step(state, batch, lr)
+            return self._train_step(
+                state, batch, lr, self._guard_or_init(guard)
+            )
 
     def train_steps(self, state: TrainState, batches,
                     lr: Optional[float] = None, guard=None):
@@ -1075,13 +1101,16 @@ class Trainer:
         per inner step. Run checkpoint/eval/maintain() at K-step
         boundaries (they are host-side and see only the returned state).
         Compiles once per K; see docs/perf.md for the K-curve."""
-        if isinstance(batches, (list, tuple)):
-            batches = stack_batches(batches)
-        lr = jnp.asarray(self.sparse_opt.lr if lr is None else lr, jnp.float32)
-        if self.sentinel is None:
-            return self._train_steps(state, batches, lr)
-        return self._train_steps(state, batches, lr,
-                                 self._guard_or_init(guard))
+        with self._step_span():
+            if isinstance(batches, (list, tuple)):
+                batches = stack_batches(batches)
+            lr = jnp.asarray(
+                self.sparse_opt.lr if lr is None else lr, jnp.float32
+            )
+            if self.sentinel is None:
+                return self._train_steps(state, batches, lr)
+            return self._train_steps(state, batches, lr,
+                                     self._guard_or_init(guard))
 
     def train_step_accum(self, state: TrainState, batch, accum_steps: int,
                          lr: Optional[float] = None, guard=None):
@@ -1093,16 +1122,23 @@ class Trainer:
             return x.reshape(accum_steps, x.shape[0] // accum_steps,
                              *x.shape[1:])
 
-        lr = jnp.asarray(self.sparse_opt.lr if lr is None else lr, jnp.float32)
-        if self.sentinel is None:
-            return self._train_step_accum(state, jax.tree.map(split, batch),
-                                          lr)
-        return self._train_step_accum(state, jax.tree.map(split, batch), lr,
-                                      self._guard_or_init(guard))
+        with self._step_span():
+            lr = jnp.asarray(
+                self.sparse_opt.lr if lr is None else lr, jnp.float32
+            )
+            if self.sentinel is None:
+                return self._train_step_accum(
+                    state, jax.tree.map(split, batch), lr
+                )
+            return self._train_step_accum(
+                state, jax.tree.map(split, batch), lr,
+                self._guard_or_init(guard),
+            )
 
     def eval_step(self, state: TrainState, batch):
         return self._eval_step(state, batch)
 
+    @scopes.host_spanned(scopes.EVICT_TABLES)
     def evict_tables(self, state: TrainState, step=None) -> TrainState:
         """Apply each table's eviction policies (TTL / L2) and rebuild —
         run at checkpoint cadence like the reference
@@ -1258,6 +1294,7 @@ class Trainer:
                           "modeled exchange bytes per mesh position",
                           {"table": tname, "shard": str(i)}).set(xb)
 
+    @scopes.host_spanned(scopes.UPDATE_BUDGETS)
     def update_budgets(
         self, state: TrainState, *, slack: float = 1.5, ema: float = 0.5
     ) -> Tuple[TrainState, Dict[str, Dict[str, float]]]:
@@ -1335,6 +1372,7 @@ class Trainer:
         base trainer — no-op; ShardedTrainer implements."""
         return state, {}
 
+    @scopes.host_spanned(scopes.MAINTAIN)
     def maintain(
         self,
         state: TrainState,

@@ -44,7 +44,17 @@ def enable_compile_cache() -> str:
     # stack of the trace as location info, and so into the cache key: the
     # same train step traced from two callers (or after an edit that moves
     # a caller's line) would never hit. One frame is enough for an error.
-    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    # (Not `jax_include_full_tracebacks_in_locations=False`: that also cuts
+    # every instruction's `op_name` down to its primitive. The scopes of
+    # utils/scopes.py, which a device trace is read by, are then left only
+    # in the function names of the stack frames, where the bodies of loops
+    # hold none: the compiler rebuilds the loops round the vmapped row
+    # kernels without their metadata, and half a train step reads as
+    # another stage's (PERF.md section 6, PR 26). With the scopes in
+    # `op_name` the TPU compiler names a Pallas call after the innermost of
+    # them, `closed_call.N` where it was `tpu_custom_call.N`: a reader
+    # knows a kernel by its custom-call target, not by that name.)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed

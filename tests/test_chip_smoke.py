@@ -25,7 +25,7 @@ TINY = chip_smoke.Size(
 
 
 _CACHE_CONFIG = ("jax_compilation_cache_dir", "jax_compilation_cache_max_size",
-                 "jax_include_full_tracebacks_in_locations")
+                 "jax_traceback_in_locations_limit")
 
 
 @pytest.fixture

@@ -1,0 +1,417 @@
+"""The vocabulary of trace names (utils/scopes.py): what reaches the compiled
+step's `op_name`s, what reaches the host plane of a trace, and that it is
+the one the benchmark's reader holds (benchmark/phases.json)."""
+import glob
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+
+from benchmark import phase_reduce
+from deeprec_tpu.data import SyntheticCriteo
+from deeprec_tpu.models import DLRMDCN
+from deeprec_tpu.optim import Adagrad
+from deeprec_tpu.training import Trainer
+from deeprec_tpu.utils import scopes
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+VOCAB = scopes.vocabulary()
+# what executes nothing: a trace never times it
+FREE = {"parameter", "constant", "tuple", "get-tuple-element", "bitcast"}
+# the shard_map call's own name, which the partitioner gives to what it makes
+# at the call's boundary; the program's operations stand under the body
+BOUNDARY = re.compile(r"^jit\([^)]*\)/shard_map(/[\w\-]+\.\d+)?$")
+
+
+def model():
+    return DLRMDCN(emb_dim=8, capacity=1 << 10, bottom=(16, 8), top=(16, 1),
+                   num_cat=26, num_dense=13, cross_depth=1)
+
+
+def batches(n, batch_size=64):
+    gen = SyntheticCriteo(batch_size=batch_size, num_cat=26, num_dense=13,
+                          vocab=300, seed=3)
+    return [{k: jnp.asarray(v) for k, v in gen.batch().items()}
+            for _ in range(n)]
+
+
+@pytest.fixture(scope="module")
+def mesh():
+    from deeprec_tpu.parallel import make_mesh
+
+    return make_mesh(8)
+
+
+def op_names(compiled):
+    """[(opcode, op_name)] of a compiled program's instructions that carry
+    a name of jax's own (`jit(...)/...`; the compiler's and the
+    parameters' names are not the program's)."""
+    out = []
+    for line in compiled.as_text().splitlines():
+        m = re.search(r'op_name="((?:[^"\\]|\\.)*)"', line)
+        op = re.search(r"= .*? ([\w\-]+)\(", line)
+        if m and op and m.group(1).startswith("jit(") \
+                and not BOUNDARY.match(m.group(1)):
+            out.append((op.group(1), m.group(1)))
+    return out
+
+
+def found(names):
+    got = [phase_reduce.scope_of(n, VOCAB) for _, n in names]
+    return ({s.phase for s in got}, {s.stage for s in got},
+            {s.rows for s in got})
+
+
+def assert_every_instruction_has_a_phase(names):
+    bare = [(op, n) for op, n in names if op not in FREE
+            and not phase_reduce.scope_of(n, VOCAB).phase]
+    assert not bare, bare[:10]
+
+
+# ----------------------------------------------------------- the vocabulary
+
+
+def test_names_are_plain_and_distinct():
+    names = [n for group in VOCAB.values()
+             for n in ([group] if isinstance(group, str) else group)]
+    names += [scopes.exchange_chunk(3), scopes.hier_intra_chunk(0),
+              scopes.hier_inter_chunk(12)]
+    assert len(set(names)) == len(names)
+    for name in names:
+        assert re.fullmatch(r"[A-Za-z_][A-Za-z0-9_.]*", name), name
+    assert all(n.startswith("phase_") for n in scopes.PHASES)
+    assert all(n.startswith("engine_") for n in scopes.STAGES)
+    assert all(n.startswith("rows_") for n in scopes.ROWS)
+    assert all(n.startswith("deeprec.") for n in scopes.HOST_SPANS)
+
+
+def test_the_benchmark_holds_the_same_vocabulary():
+    with open(os.path.join(ROOT, "benchmark", "phases.json")) as f:
+        data = json.load(f)
+    data.pop("note")
+    reads = data.pop("reads")
+    assert data == VOCAB
+    # what each per-layer metric reads is a name of the vocabulary, and a
+    # metric of the manifest (BENCHMARK.json) with a reader of its own
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        listed = {m["name"] for m in json.load(f)["per_layer"]}
+    for metric, what in reads.items():
+        (kind, name), = what.items()
+        assert name in {"stage": scopes.STAGES, "loop": scopes.STAGES,
+                        "phase": scopes.PHASES + (phase_reduce.UNPHASED,),
+                        "rows": ("wrapper", "kernel"),
+                        "span": (scopes.TRAIN_STEP,)}[kind], metric
+        assert metric in listed
+        assert os.path.exists(os.path.join(
+            ROOT, "benchmark", "layer_metrics", metric + ".py"))
+
+
+def test_no_scope_or_span_is_written_outside_the_module():
+    hits = []
+    for path in glob.glob(os.path.join(ROOT, "deeprec_tpu", "**", "*.py"),
+                          recursive=True):
+        if path.endswith(os.path.join("utils", "scopes.py")):
+            continue
+        with open(path) as f:
+            for i, line in enumerate(f, 1):
+                if re.search(r"named_scope\(|TraceAnnotation\(", line):
+                    hits.append(f"{path}:{i}")
+    assert not hits, hits
+
+
+# ------------------------------------------------- what the compiled step holds
+
+
+def test_train_step_names_every_phase_stage_and_row_funnel():
+    tr = Trainer(model(), Adagrad(lr=0.1), optax.adam(1e-3),
+                 unique_budget=48)
+    names = op_names(tr._train_step.lower(
+        tr.init(0), batches(1)[0], jnp.float32(0.1)).compile())
+    phases, stages, rows = found(names)
+    assert phases == {scopes.PHASE_LOOKUP, scopes.PHASE_DENSE_FWD_BWD,
+                      scopes.PHASE_SPARSE_APPLY, scopes.PHASE_DENSE_APPLY}
+    assert stages == set(scopes.STAGES) | {""}
+    assert rows == set(scopes.ROWS) | {""}
+    assert_every_instruction_has_a_phase(names)
+    text = "\n".join(n for _, n in names)
+    # the 26-table vmap wraps the outermost scope under it; the probe,
+    # nested in the insert's, is the innermost stage of its operations
+    for stage in (scopes.ENGINE_ROUTE, scopes.ENGINE_INSERT,
+                  scopes.ENGINE_GATHER):
+        assert f"/{scopes.PHASE_LOOKUP}/vmap({stage})/" in text
+    probe = [n for _, n in names if scopes.ENGINE_PROBE in n]
+    assert any("/while/body/" in n for n in probe)
+    assert all(phase_reduce.scope_of(n, VOCAB).stage == scopes.ENGINE_PROBE
+               for n in probe)
+    # the optimizer's slot rows take the same two funnels, under the apply
+    assert f"/{scopes.PHASE_SPARSE_APPLY}/vmap({scopes.ROWS_SCATTER})/" in text
+    assert f"/{scopes.PHASE_SPARSE_APPLY}/vmap({scopes.ROWS_GATHER})/" in text
+    # the backward pass: jax wraps the scope's name, or what is under it
+    back = [n for _, n in names if "transpose(" in n]
+    assert back and all(
+        phase_reduce.scope_of(n, VOCAB).phase == scopes.PHASE_DENSE_FWD_BWD
+        for n in back)
+    assert any(f"transpose({scopes.PHASE_DENSE_FWD_BWD})" in n for n in back)
+
+
+def test_a_read_only_lookup_shows_no_insert():
+    """Eval and serving resolve with `train=False`: nothing is created or
+    stamped, and a trace of it must not show time under `engine_insert`."""
+    tr = Trainer(model(), Adagrad(lr=0.1), optax.adam(1e-3),
+                 unique_budget=48)
+    names = op_names(tr._eval_step.lower(
+        tr.init(0), batches(1)[0]).compile())
+    _, stages, rows = found(names)
+    assert scopes.ENGINE_INSERT not in stages
+    assert {scopes.ENGINE_PROBE, scopes.ENGINE_GATHER} <= stages
+    assert scopes.ROWS_SCATTER not in rows
+
+
+def test_sentinel_and_pipelined_steps_name_their_phases():
+    from deeprec_tpu.guard import SentinelConfig
+    from deeprec_tpu.training import stack_batches
+
+    tr = Trainer(model(), Adagrad(lr=0.1), optax.adam(1e-3),
+                 unique_budget=48, pipeline_mode="lookahead",
+                 sentinel=SentinelConfig(row_norm_max=1e3))
+    names = op_names(tr._train_steps.lower(
+        tr.init(0), stack_batches(batches(3)), jnp.float32(0.1),
+        tr._guard_or_init(None)).compile())
+    phases, stages, _ = found(names)
+    assert {scopes.PHASE_LOOKUP, scopes.PHASE_ROUTE_NEXT,
+            scopes.PHASE_FINISH_EXCHANGE, scopes.PHASE_SENTINEL,
+            scopes.PHASE_DENSE_FWD_BWD, scopes.PHASE_SPARSE_APPLY,
+            scopes.PHASE_DENSE_APPLY} <= phases
+    assert set(scopes.STAGES) <= stages
+    # the lookahead's route and probe stand under the phase that hoists them
+    hoisted = {phase_reduce.scope_of(n, VOCAB).stage for _, n in names
+               if phase_reduce.scope_of(n, VOCAB).phase
+               == scopes.PHASE_ROUTE_NEXT}
+    assert {scopes.ENGINE_ROUTE, scopes.ENGINE_PROBE,
+            scopes.ENGINE_INSERT} <= hoisted
+    assert scopes.ENGINE_GATHER not in hoisted
+
+
+@pytest.mark.parametrize("comm, chunks", [("allgather", 1), ("a2a", 3)])
+def test_sharded_train_step_names_the_same_vocabulary(mesh, comm, chunks):
+    from deeprec_tpu.parallel import ShardedTrainer, shard_batch
+
+    kw = dict(pipeline_mode="chunked", pipeline_chunks=chunks) \
+        if chunks > 1 else {}
+    tr = ShardedTrainer(model(), Adagrad(lr=0.1), optax.adam(1e-3),
+                        mesh=mesh, comm=comm, unique_budget=48, **kw)
+    batch = shard_batch(mesh, batches(1)[0])
+    names = op_names(tr._train_step.lower(
+        tr.init(0), batch, jnp.float32(0.1)).compile())
+    phases, stages, rows = found(names)
+    want = {scopes.PHASE_LOOKUP_EXCHANGE, scopes.PHASE_DENSE_FWD_BWD,
+            scopes.PHASE_SPARSE_APPLY, scopes.PHASE_DENSE_APPLY}
+    assert phases == want, phases
+    assert set(scopes.STAGES) <= stages and set(scopes.ROWS) <= rows
+    assert_every_instruction_has_a_phase(names)
+    text = "\n".join(n for _, n in names)
+    for i in range(chunks if chunks > 1 else 0):
+        assert scopes.exchange_chunk(i) in text
+
+
+# ------------------------------------------------------- the host's spans
+
+
+def host_events(trace_dir):
+    """{name: [stats dict of each event]} of the program's spans on the
+    host planes of the trace under `trace_dir`."""
+    path, = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    wanted = set(scopes.HOST_SPANS) | {scopes.TRAIN_STEP}
+    out = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in wanted:
+                    out.setdefault(e.name, []).append(
+                        (e.start_ns, dict(e.stats)))
+    return {k: [s for _, s in sorted(v, key=lambda x: x[0])]
+            for k, v in out.items()}
+
+
+def traced(tmp_path, body):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    return host_events(str(tmp_path))
+
+
+def test_a_three_step_trace_holds_three_numbered_step_spans(tmp_path):
+    tr = Trainer(model(), Adagrad(lr=0.1), optax.adam(1e-3),
+                 unique_budget=48)
+    state = tr.init(0)
+    host = [{k: jax.device_get(v) for k, v in b.items()}
+            for b in batches(3)]
+    state, mets = tr.train_step(state, tr.stage_batch(host[0]))  # compiles
+    jax.block_until_ready(mets["loss"])
+    tr._dispatches = 0
+
+    def body():
+        nonlocal state
+        for b in host:
+            state, mets = tr.train_step(state, tr.stage_batch(b))
+        jax.block_until_ready(mets["loss"])
+
+    events = traced(tmp_path, body)
+    assert [s["step_num"] for s in events[scopes.TRAIN_STEP]] == [0, 1, 2]
+    assert len(events[scopes.STAGE_BATCH]) == 3
+    assert int(state.step) == 4  # the spans count dispatches, not the state
+
+
+def test_step_spans_count_dispatches_of_every_entry_point(tmp_path):
+    tr = Trainer(model(), Adagrad(lr=0.1), optax.adam(1e-3),
+                 unique_budget=48)
+    state = tr.init(0)
+    bs = batches(4, batch_size=32)
+
+    def body():
+        nonlocal state
+        state, _ = tr.train_step(state, bs[0])
+        state, _ = tr.train_steps(state, bs[1:3])
+        big = {k: jnp.concatenate([a[k], b[k]]) for a, b in [bs[:2]]
+               for k in a}
+        state, mets = tr.train_step_accum(state, big, 2)
+        jax.block_until_ready(mets["loss"])
+
+    events = traced(tmp_path, body)
+    assert [s["step_num"] for s in events[scopes.TRAIN_STEP]] == [0, 1, 2]
+    assert int(state.step) == 4  # 1 + 2 + 1 steps in three dispatches
+
+
+def test_maintenance_and_checkpoint_calls_leave_their_spans(tmp_path):
+    from deeprec_tpu.training.checkpoint import CheckpointManager
+
+    tr = Trainer(model(), Adagrad(lr=0.1), optax.adam(1e-3),
+                 unique_budget=48)
+    state, _ = tr.train_step(tr.init(0), batches(1)[0])
+    ckpt = CheckpointManager(str(tmp_path / "ckpt"), tr)
+
+    def body():
+        nonlocal state
+        state, _ = tr.maintain(state)     # update_budgets inside it
+        state = tr.evict_tables(state)
+        state, _ = ckpt.save(state)
+        state, _ = ckpt.save_incremental_async(state)
+        ckpt.wait()
+        state = ckpt.restore()
+
+    events = traced(tmp_path / "trace", body)
+    count = {name: len(events.get(name, ())) for name in scopes.HOST_SPANS}
+    assert count == {scopes.STAGE_BATCH: 0, scopes.UPDATE_BUDGETS: 1,
+                     scopes.MAINTAIN: 1, scopes.EVICT_TABLES: 1,
+                     scopes.CKPT_SAVE: 2, scopes.CKPT_RESTORE: 1}
+
+
+def test_host_spans_allocate_nothing_when_no_profiler_runs():
+    """With no profiler running a span is a flag test: the objects entered
+    and left are freed at once, so a thousand spans leave nothing behind
+    (the same pin as obs/trace.py's disabled path)."""
+    import tracemalloc
+
+    with scopes.host_span(scopes.MAINTAIN):   # touch every lazy path once
+        pass
+    with scopes.step_span(0):
+        pass
+    N = 2000
+    tracemalloc.start()
+    try:
+        before = tracemalloc.take_snapshot()
+        for i in range(N):
+            with scopes.host_span(scopes.STAGE_BATCH):
+                pass
+            with scopes.step_span(i):
+                pass
+        after = tracemalloc.take_snapshot()
+    finally:
+        tracemalloc.stop()
+    grown = [st for st in after.compare_to(before, "filename")
+             if st.traceback[0].filename == scopes.__file__]
+    assert sum(st.count_diff for st in grown) < N / 100, grown
+    assert sum(st.size_diff for st in grown) < 4096, grown
+
+
+def test_the_entry_points_cache_setting_keeps_the_scopes(monkeypatch, tmp_path):
+    """Every entry point calls `enable_compile_cache()`. The setting it used
+    to make for stable Pallas cache keys
+    (`jax_include_full_tracebacks_in_locations=False`) cut every `op_name`
+    down to its primitive, and a trace of the real program lost its scopes;
+    the one-frame limit it makes now keeps them."""
+    from deeprec_tpu.utils import backend
+
+    names = ("jax_traceback_in_locations_limit",
+             "jax_include_full_tracebacks_in_locations")
+    before = {n: getattr(jax.config, n) for n in names}
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    try:
+        backend.enable_compile_cache()
+
+        def step(x):
+            with scopes.scope(scopes.PHASE_LOOKUP):
+                return jax.vmap(scopes.scoped(scopes.ENGINE_PROBE)(jnp.sin))(x)
+
+        text = jax.jit(step).lower(jnp.ones((2, 4))).compile().as_text()
+    finally:
+        for n, v in before.items():
+            jax.config.update(n, v)
+    assert f"{scopes.PHASE_LOOKUP}/vmap({scopes.ENGINE_PROBE})/sin" in text
+
+
+def test_a_kernel_serialises_the_same_from_two_callers(monkeypatch, tmp_path):
+    """What the setting is for (PR 21): a Pallas kernel is serialised into
+    its program with the locations of its trace, and so into the compile
+    cache's key. With one frame a location the kernel's own line is all
+    that is left, and the body is the same bytes whoever calls it; with
+    jax's default ten frames the callers are in it and it is not."""
+    from deeprec_tpu.ops import fused_lookup
+    from deeprec_tpu.utils import backend
+
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    name = "jax_traceback_in_locations_limit"
+    before = getattr(jax.config, name)
+    args = (jax.ShapeDtypeStruct((4096, 128), jnp.float32),
+            jax.ShapeDtypeStruct((512,), jnp.int32))
+
+    def bodies():  # functions of their own: jax keeps a lowering by them
+        def one(values, ix):
+            return fused_lookup.gather_rows(values, ix)
+
+        def other(values, ix):
+            def deeper(values, ix):
+                return fused_lookup.gather_rows(values, ix) + 0
+
+            return deeper(values, ix)
+
+        found = []
+        for fn in (one, other):
+            text = jax.jit(fn).trace(*args).lower(
+                lowering_platforms=("tpu",)).as_text()
+            body, = re.findall(r'\\22body\\22: \\22([^\\]*)\\22', text)
+            found.append(body)
+        return found
+
+    try:
+        jax.config.update(name, 10)
+        one, other = bodies()
+        assert one != other
+        backend.enable_compile_cache()
+        assert getattr(jax.config, name) == 1
+        one, other = bodies()
+        assert one == other
+    finally:
+        jax.config.update(name, before)

@@ -375,22 +375,6 @@ def test_columnar_checkpoint_restores_into_packed_meta(tmp_path):
 # ------------------------------------------------------ tooling satellites
 
 
-def test_phase_profiler_report():
-    from deeprec_tpu.training.profiler import PhaseProfiler
-
-    prof = PhaseProfiler()
-    x = jnp.ones((128, 128))
-    f = jax.jit(lambda a: a @ a)
-    for _ in range(2):
-        prof.timed("matmul", f, x)
-    with prof.phase("idle"):
-        pass
-    rep = prof.phase_report()
-    assert rep["matmul"]["calls"] == 2
-    assert rep["matmul"]["total_ms"] >= rep["matmul"]["min_ms"] > 0
-    assert rep["idle"]["calls"] == 1
-
-
 def test_traffic_op_model_matches_lowered_program():
     """In-suite drift gate (the CI smoke asserts the same through
     bench.py + roofline --assert-traffic): the traffic model's expected
