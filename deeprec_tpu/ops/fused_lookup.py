@@ -30,10 +30,25 @@ hardware bench crowns them. Everything else falls back to the
 identical-semantics XLA path. Off-TPU all calls are XLA, so every caller
 is oracle-testable on CPU (the kernels themselves via interpret mode,
 where the in-kernel SR branches are also covered).
+
+Batching. Stacked bundles vmap the lookup and the apply over their tables,
+and ``gather_rows`` / ``apply_rows_sr`` take their row indices as a
+scalar-prefetch operand. jax's batching rule for ``pallas_call`` adds no
+grid axis for a batched scalar prefetch: it loops over the tables,
+``dynamic_slice``s each whole [C, D] table out of the stack, runs the
+kernel on the slice and ``dynamic_update_slice``s the result into a fresh
+[T, ...] buffer (jax 0.9.0, ``_batch_with_explicit_loop``) — table-sized
+copies round a row read or write. So these two kernels carry a table axis
+themselves (grid (tables, row blocks), ``values`` whole in HBM as
+[T, C, D], a row's DMA at ``values_ref.at[t, idx]``, the scatter aliased
+onto the stacked array) and sit behind a ``custom_vmap`` hook that folds
+any vmap — and a second one on top, shards x tables — into that axis by a
+reshape (``_fold``). The unbatched call is T = 1. docs/kernels.md has the
+SMEM budget and the mixed-batching cases.
 """
 from __future__ import annotations
 
-
+import functools
 from typing import Dict, NamedTuple, Tuple
 
 import jax
@@ -43,6 +58,18 @@ from deeprec_tpu.utils import backend, scopes
 
 _BLOCK = 8  # rows per grid step; sublane-aligned for f32
 _LANES = 128  # Mosaic HBM tiling: DMA row slices must be lane-aligned
+# Row indices ride SMEM as a scalar prefetch, all tables of a call in one
+# operand; past this many bytes of them a stacked call splits into calls
+# over table ranges (_table_ranges). Two limits stand behind the number.
+# Mosaic's: a v5e core has 1 MiB of SMEM and what does not fit is refused
+# at compile time (26 x 10000 indices pass, 26 x 12000 do not:
+# tests/test_aot_kernels.py). The chip's, found the hard way (PERF.md,
+# PR 27): with all 26 x 8200 indices of the benchmark's uniform cell in one
+# call (853 KB of SMEM; the gather's [26, 8200, 128] result is 109 MB, which
+# XLA keeps in VMEM) the cell's first train step never returns, where calls
+# of 7 tables (230 KB, 29 MB) run, as do 26 x 2304 rows in one call (240 KB,
+# 31 MB). 256 KiB keeps every call inside what has been seen to run.
+_SMEM_INDEX_BYTES = 256 * 1024
 
 
 def _dma_ok(dim: int, dtype) -> bool:
@@ -137,29 +164,52 @@ def _pair_reason(shape, dtype) -> str:
 
 
 def _pad_rows(ix: jnp.ndarray, block: int, fill: int = 0) -> jnp.ndarray:
-    n = ix.shape[0]
-    pad = (-n) % block
+    """Pad the index axis (the last; tables may lead) to a block multiple."""
+    pad = (-ix.shape[-1]) % block
     if pad:
-        ix = jnp.concatenate([ix, jnp.full((pad,), fill, ix.dtype)])
+        ix = jnp.pad(ix, [(0, 0)] * (ix.ndim - 1) + [(0, pad)],
+                     constant_values=fill)
     return ix
 
 
 def _pad_updates(slot_ix, new_rows, block):
-    """Shared scatter preamble: pad slot indices (-1 = skip) and update
-    rows to a block multiple."""
+    """Shared scatter preamble: pad slot indices [..., U] (-1 = skip) and
+    update rows [..., U, D] to a block multiple."""
     ixp = _pad_rows(
-        jnp.where(slot_ix >= 0, slot_ix, -1).astype(jnp.int32).reshape(-1),
-        block, fill=-1,
+        jnp.where(slot_ix >= 0, slot_ix, -1).astype(jnp.int32), block,
+        fill=-1,
     )
-    if ixp.shape[0] != new_rows.shape[0]:
-        new_rows = jnp.concatenate([
-            new_rows,
-            jnp.zeros(
-                (ixp.shape[0] - new_rows.shape[0], new_rows.shape[1]),
-                new_rows.dtype,
-            ),
-        ])
+    pad = ixp.shape[-1] - new_rows.shape[-2]
+    if pad:
+        new_rows = jnp.pad(
+            new_rows, [(0, 0)] * (new_rows.ndim - 2) + [(0, pad), (0, 0)]
+        )
     return ixp, new_rows
+
+
+def _fold(op, axis_size, in_batched, *args):
+    """A vmap over `op`, whose operands and result all lead with a table
+    axis [T, ...], as ONE call of `op` on axis_size * T tables: unmapped
+    operands are broadcast, the two axes merge by a reshape (free: both
+    lead) and the result splits back. `op` is itself the hooked function,
+    so a second vmap on top folds into the same axis the same way."""
+    folded = []
+    for a, batched in zip(args, in_batched):
+        if not batched:
+            a = jnp.broadcast_to(a, (axis_size, *a.shape))
+        folded.append(a.reshape(-1, *a.shape[2:]))
+    out = op(*folded)
+    return out.reshape(axis_size, -1, *out.shape[1:])
+
+
+def _table_ranges(tables: int, rows: int):
+    """(first table, tables) of each Pallas call of a stacked row kernel:
+    one call for all tables while their `rows` padded indices apiece fit
+    the SMEM budget, else a few calls over table ranges. Every call is
+    handed the SAME whole stacked array and its range's first table as a
+    scalar, so a range costs one more call and never a slice of a table."""
+    per = max(1, _SMEM_INDEX_BYTES // (4 * rows))
+    return [(t0, min(per, tables - t0)) for t0 in range(0, tables, per)]
 
 
 def _sr_bits(seed, shape):
@@ -358,7 +408,6 @@ def gather_rows(values: jnp.ndarray, ix: jnp.ndarray, *,
     pair_kernels=True additionally routes eligible bf16 tables through the
     pair-granule kernel (explicit kernel="pallas" or a measured-winners
     flag — see AUTO_TRUSTS_BF16_PAIR)."""
-    n = ix.shape[0]
     if pair_kernels and _dma_pair_ok(values.shape, values.dtype) and (
         interpret or backend.on_tpu()
     ):
@@ -371,20 +420,32 @@ def gather_rows(values: jnp.ndarray, ix: jnp.ndarray, *,
                        values.shape, values.dtype)
         return values.at[ix].get(mode="clip")
 
+    return _gather_rows_op(block, interpret)(values[None], ix[None])[0]
+
+
+def _gather_rows_stacked(values, ix, *, block, interpret):
+    """The gather kernel, with its table axis: values [T, C, D], ix [T, n]
+    -> [T, n, D]. One call for a range of tables (_table_ranges; all of
+    them while their indices fit the budget): the grid is (table, row
+    block), the range's indices are one flat scalar prefetch, and a row's
+    DMA reads values_ref[t0 + t, idx] from the whole stacked array in HBM."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    C, D = values.shape
+    T, C, D = values.shape
+    n = ix.shape[1]
     ixp = _pad_rows(ix.astype(jnp.int32), block)
-    np_ = ixp.shape[0]
+    np_ = ixp.shape[1]
 
-    def kernel(ix_ref, values_ref, out_ref, scratch, sems):
-        base = pl.program_id(0) * block
+    def kernel(t0_ref, ix_ref, values_ref, out_ref, scratch, sems):
+        t = pl.program_id(0)
+        base = t * np_ + pl.program_id(1) * block
 
         def row_dma(slot, i):
             idx = jnp.clip(ix_ref[base + i], 0, C - 1)
             return pltpu.make_async_copy(
-                values_ref.at[idx], scratch.at[slot], sems.at[slot]
+                values_ref.at[t0_ref[0] + t, idx], scratch.at[slot],
+                sems.at[slot],
             )
 
         row_dma(0, 0).start()
@@ -402,26 +463,53 @@ def gather_rows(values: jnp.ndarray, ix: jnp.ndarray, *,
 
         jax.lax.fori_loop(0, block, body, 0)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(np_ // block,),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec(
-            (block, D), lambda i, ix_ref: (i, 0), memory_space=pltpu.VMEM
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((2, D), values.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        name=scopes.KERNEL_GATHER_ROWS,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((np_, D), values.dtype),
-        interpret=interpret,
-    )(ixp, values)
-    return out[:n]
+    def call(t0, tables):
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(tables, np_ // block),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(
+                (None, block, D), lambda t, i, *_: (t, i, 0),
+                memory_space=pltpu.VMEM,
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((2, D), values.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        )
+        return pl.pallas_call(
+            kernel,
+            name=scopes.KERNEL_GATHER_ROWS,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((tables, np_, D), values.dtype),
+            interpret=interpret,
+        )(jnp.full((1,), t0, jnp.int32),
+          ixp[t0:t0 + tables].reshape(-1), values)
+
+    outs = [call(*r) for r in _table_ranges(T, np_)]
+    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
+    return out[:, :n]
+
+
+@functools.lru_cache(maxsize=None)
+def _gather_rows_op(block, interpret):
+    """_gather_rows_stacked under the hook that folds a vmap into its
+    table axis (module docstring, "Batching")."""
+    op = jax.custom_batching.custom_vmap(functools.partial(
+        _gather_rows_stacked, block=block, interpret=interpret
+    ))
+
+    @op.def_vmap
+    def _(axis_size, in_batched, values, ix):
+        if not in_batched[0]:
+            # indices mapped over unmapped tables: S * n rows of each table
+            T, n = ix.shape[1:]
+            out = op(values, jnp.swapaxes(ix, 0, 1).reshape(T, -1))
+            out = out.reshape(T, axis_size, n, -1)
+            return jnp.swapaxes(out, 0, 1), True
+        return _fold(op, axis_size, in_batched, values, ix), True
+
+    return op
 
 
 # ----------------------------------------------------- fused gather+combine
@@ -585,27 +673,42 @@ def apply_rows_sr(values: jnp.ndarray, slot_ix: jnp.ndarray,
         ix = jnp.where(slot_ix >= 0, slot_ix, C)
         return values.at[ix].set(rows, mode="drop")
 
+    return _apply_rows_op(block, interpret)(
+        values[None], slot_ix[None], new_rows[None],
+        jnp.asarray(seed, jnp.int32).reshape(1),
+    )[0]
+
+
+def _apply_rows_stacked(values, slot_ix, new_rows, seed, *, block,
+                        interpret):
+    """The scatter kernel, with its table axis: values [T, C, D],
+    slot_ix [T, U], new_rows [T, U, D], seed [T] -> the updated [T, C, D],
+    aliased onto `values`: in place on a donated stacked table state."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    T, _, D = values.shape
     # Pad with -1 (skip): a 0-fill would scatter garbage rows into slot 0.
     ixp, new_rows = _pad_updates(slot_ix, new_rows, block)
-    Up = ixp.shape[0]
+    Up = ixp.shape[1]
     sr = values.dtype == jnp.bfloat16
     # Random bits come in as a tensor (not in-kernel PRNG): identical
     # numerics across compiled TPU and interpret mode, at the cost of
     # U*D*4 extra bytes of traffic — negligible next to the row writes.
     if sr:
-        bits = _sr_bits(seed, (Up, D))
+        # a table's bits are those of its own unbatched call
+        bits = jax.vmap(lambda s: _sr_bits(s, (Up, D)))(seed)
         bits_dim = D
     else:
         # f32 path never reads the bits: ship a 1-wide dummy, not U*D zeros.
-        bits = jnp.zeros((Up, 1), jnp.uint32)  # noqa: DRT003 — deliberate 1-wide dummy: f32 path never reads it, padding beats shipping U*D zeros
+        bits = jnp.zeros((T, Up, 1), jnp.uint32)  # noqa: DRT003 — deliberate 1-wide dummy: f32 path never reads it, padding beats shipping U*D zeros
         bits_dim = 1
 
-    def kernel(ix_ref, rows_ref, bits_ref, vin_ref, vout_ref, scratch, sems):
+    def kernel(t0_ref, ix_ref, rows_ref, bits_ref, vin_ref, vout_ref,
+               scratch, sems):
         del vin_ref  # aliased with vout_ref
-        g = pl.program_id(0)
+        t = pl.program_id(0)
+        base = t * Up + pl.program_id(1) * block
 
         def body(i, _):
             slot = i % 2
@@ -613,12 +716,13 @@ def apply_rows_sr(values: jnp.ndarray, slot_ix: jnp.ndarray,
             if sr:
                 row = _sr_round_in_kernel(row, bits_ref[pl.ds(i, 1), :])
             scratch[pl.ds(slot, 1), :] = row.astype(scratch.dtype)
-            idx = ix_ref[g * block + i]
+            idx = ix_ref[base + i]
 
             @pl.when(idx >= 0)
             def _():
                 dma = pltpu.make_async_copy(
-                    scratch.at[slot], vout_ref.at[idx], sems.at[slot]
+                    scratch.at[slot], vout_ref.at[t0_ref[0] + t, idx],
+                    sems.at[slot],
                 )
                 dma.start()
                 dma.wait()
@@ -627,35 +731,58 @@ def apply_rows_sr(values: jnp.ndarray, slot_ix: jnp.ndarray,
 
         jax.lax.fori_loop(0, block, body, 0)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(Up // block,),
-        in_specs=[
-            pl.BlockSpec(
-                (block, D), lambda i, ix_ref: (i, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                (block, bits_dim), lambda i, ix_ref: (i, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        scratch_shapes=[
-            pltpu.VMEM((2, D), values.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        name=scopes.KERNEL_APPLY_ROWS_SR,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(values.shape, values.dtype),
-        input_output_aliases={3: 0},
-        compiler_params=pltpu.CompilerParams(has_side_effects=True),
-        interpret=interpret,
-    )(ixp, new_rows, bits, values)
+    def rows_block(width):
+        return pl.BlockSpec(
+            (None, block, width),
+            lambda t, i, t0_ref, ix_ref: (t0_ref[0] + t, i, 0),
+            memory_space=pltpu.VMEM,
+        )
+
+    for t0, tables in _table_ranges(T, Up):
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(tables, Up // block),
+            in_specs=[rows_block(D), rows_block(bits_dim),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[
+                pltpu.VMEM((2, D), values.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        )
+        values = pl.pallas_call(
+            kernel,
+            name=scopes.KERNEL_APPLY_ROWS_SR,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(values.shape, values.dtype),
+            input_output_aliases={4: 0},
+            compiler_params=pltpu.CompilerParams(has_side_effects=True),
+            interpret=interpret,
+        )(jnp.full((1,), t0, jnp.int32),
+          ixp[t0:t0 + tables].reshape(-1), new_rows, bits, values)
+    return values
+
+
+@functools.lru_cache(maxsize=None)
+def _apply_rows_op(block, interpret):
+    """_apply_rows_stacked under the hook that folds a vmap into its
+    table axis (module docstring, "Batching")."""
+    op = jax.custom_batching.custom_vmap(functools.partial(
+        _apply_rows_stacked, block=block, interpret=interpret
+    ))
+
+    @op.def_vmap
+    def _(axis_size, in_batched, values, slot_ix, new_rows, seed):
+        if not in_batched[0]:
+            # Updates mapped over ONE table: each wants a table of its own
+            # (folding them into one breaks the unique-slot contract), so
+            # the table is copied axis_size times. Worth a reader's notice.
+            _note_fallback("apply_rows_sr", "values_unmapped",
+                           values.shape, values.dtype)
+        return _fold(op, axis_size, in_batched, values, slot_ix, new_rows,
+                     seed), True
+
+    return op
 
 
 # ------------------------------------------------------- fused sparse step

@@ -83,7 +83,9 @@ def unpack_array(arr, capacity: int):
 def _read_rows(arr, ix, use_pallas, pair_kernels, interpret):
     """arr[ix] (clip) through the configured kernel, under `rows_gather`:
     whatever a trace shows there that is not the row kernel is wrapper (the
-    loop jax builds round a vmapped kernel, its slices and fills)."""
+    indices' reshape to one scalar prefetch; before the kernels batched
+    themselves over tables, the loop jax built round a vmapped kernel, its
+    table-sized slices and fills)."""
     with scopes.scope(scopes.ROWS_GATHER):
         if use_pallas:
             return _fl.gather_rows(arr, ix, pair_kernels=pair_kernels,

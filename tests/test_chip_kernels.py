@@ -14,7 +14,10 @@ is tests/test_fused_lookup.py, test_fused_step.py and test_attention.py.
 
 On the training path (DLRM defaults on a TPU: dim-16 f32 tables in the
 packed [C/8, 128] layout): gather_rows and apply_rows_sr on f32 granules,
-alone, under the 26-table vmap of a stacked bundle, and inside lax.scan.
+alone, under the 26-table vmap of a stacked bundle, and inside lax.scan;
+and the same vmap at the benchmark cells' shape (26 tables of [2^18, 128],
+2304 and 8200 rows a table), where the kernels' own table axis has to hold:
+one Pallas call an operation and table range, no table-sized slice round it.
 Off the path: the bf16 pair kernels, fused_gather_combine, the fused sparse
 step, flash attention forward and backward.
 """
@@ -86,7 +89,7 @@ def _stacked():
     return vals, ix, rows
 
 
-def _xla_round(vals, ix, rows):
+def _xla_round(vals, ix, rows, rows_a_table=G):
     """One gather + one scatter of a stacked bundle in plain XLA. The
     gather reads at max(ix, 0): callers never gather a negative slot (the
     table passes `safe_ix`), and there `.at[].get` wraps where the kernel
@@ -95,7 +98,8 @@ def _xla_round(vals, ix, rows):
         vals, ix
     )
     new = jax.vmap(
-        lambda v, i, r: v.at[jnp.where(i >= 0, i, G)].set(r, mode="drop")
+        lambda v, i, r: v.at[jnp.where(i >= 0, i, rows_a_table)].set(
+            r, mode="drop")
     )(vals, ix, rows)
     return got, new
 
@@ -135,6 +139,65 @@ def test_row_kernels_inside_scan():
     want_new, want_sums = steps(_xla_round)(vals)
     assert _equal(new, want_new)
     assert _equal(sums, want_sums)
+
+
+# The benchmark cells' bundle: 26 dim-128 tables of 2^18 rows (3.5 GB), the
+# rows a table a step of `.zipf` (budget 2296 + 8) and `.uniform` (8192 + 8).
+C_CELL = 1 << 18
+
+
+def _cell(n, skip_all=False):
+    vals = _table(20, (T, C_CELL, LANES))
+    ix = jnp.stack([_unique_ix(200 + t, n, C_CELL) for t in range(T)])
+    if skip_all:
+        ix = jnp.full_like(ix, -1)
+    return vals, ix, _table(21, (T, n, LANES))
+
+
+def _rows_round(vals, ix, rows):
+    """One gather and one scatter of the bundle through the funnels the
+    engine calls, so under the `rows_*` scopes and the table vmap."""
+    from deeprec_tpu.ops import packed
+
+    got = jax.vmap(lambda v, i: packed.gather_rows_any(
+        v, i, C_CELL, use_pallas=True))(vals, jnp.maximum(ix, 0))
+    new = jax.vmap(lambda v, i, r: packed.scatter_rows_any(
+        v, i, r, C_CELL, use_pallas=True))(vals, ix, rows)
+    return got, new
+
+
+@pytest.mark.parametrize("n", [2304, 8200])
+def test_row_kernels_under_table_vmap_at_the_cells_shape(n):
+    vals, ix, rows = _cell(n)
+    want_got, want_new = jax.jit(
+        lambda v, i, r: _xla_round(v, i, r, C_CELL))(vals, ix, rows)
+    compiled = jax.jit(_rows_round, donate_argnums=0).lower(
+        vals, ix, rows).compile()
+    got, new = compiled(vals, ix, rows)  # in place on the donated stack
+    assert _equal(got, want_got)
+    assert _equal(new, want_new)
+    # the mechanism, in the compiled program: one Mosaic call an operation
+    # and a table range (tests/test_aot_kernels.py asks the same of the
+    # compiler without a chip), no loop round them, and nothing that slices
+    # a table out of the stack or writes one back
+    hlo = compiled.as_text()
+    calls = 2 * len(fl._table_ranges(T, n))
+    assert hlo.count('custom_call_target="tpu_custom_call"') == calls, hlo
+    assert " while(" not in hlo
+    for line in hlo.splitlines():
+        name, _, rest = line.strip().partition(" = ")
+        result, _, op = rest.partition(" ")
+        if "dynamic-" in name + " " + op.split("(", 1)[0]:
+            assert not result.startswith(
+                (f"f32[{C_CELL},{LANES}]", f"f32[{T},{C_CELL},{LANES}]")
+            ), line
+
+
+def test_all_skipped_scatter_at_the_cells_shape_touches_no_table():
+    """The insert that creates no row: what `insert` pays a step for."""
+    vals, ix, rows = _cell(2304, skip_all=True)
+    _, new = jax.jit(_rows_round)(vals, ix, rows)
+    assert _equal(new, vals)
 
 
 # ----------------------------------------------------------- off the path

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deeprec_tpu.ops import fused_lookup as fl
 from deeprec_tpu.ops.fused_lookup import (
     apply_rows_sr,
     fused_gather_combine,
@@ -257,3 +258,199 @@ def test_fused_gather_combine_pair_bf16(combiner):
     e = np.asarray(vals, np.float32)[np.clip(ix, 0, 127)]
     expect = (e * w[..., None]).sum(axis=1)
     np.testing.assert_allclose(np.asarray(out), expect, rtol=2e-2, atol=2e-2)
+
+
+# ------------------------------------------------ the kernels' table axis
+#
+# gather_rows and apply_rows_sr batch themselves: a vmap over tables (and a
+# second one over shards) folds into the table axis of ONE Pallas call,
+# where jax's own rule for a batched scalar prefetch would loop over the
+# tables and slice each out of the stack (module docstring, "Batching").
+
+T_, C_, D_ = 3, 64, 128
+
+
+def _stack(seed, dtype=jnp.float32, lead=(T_,)):
+    rng = np.random.default_rng(seed)
+    return jnp.asarray(
+        rng.normal(0, 1, (*lead, C_, D_)).astype(np.float32)
+    ).astype(dtype)
+
+
+def _gather_ix(seed, n, lead=(T_,)):
+    """Reads with out-of-range indices on both sides (clipped)."""
+    rng = np.random.default_rng(seed)
+    return jnp.asarray(rng.integers(-4, C_ + 4, (*lead, n)), jnp.int32)
+
+
+def _scatter_ix(seed, n, lead=(T_,), skip_every=4):
+    """Unique slots a table (the kernel's contract), some skipped."""
+    rng = np.random.default_rng(seed)
+    flat = np.stack([rng.permutation(C_)[:n]
+                     for _ in range(int(np.prod(lead)))])
+    flat[:, ::skip_every] = -1
+    return jnp.asarray(flat.reshape(*lead, n), jnp.int32)
+
+
+def _rows(seed, n, lead=(T_,)):
+    rng = np.random.default_rng(seed)
+    return jnp.asarray(rng.normal(0, 1, (*lead, n, D_)).astype(np.float32))
+
+
+def _gather(v, i):
+    return gather_rows(v, i, interpret=True)
+
+
+def _apply(v, i, r, s):
+    return apply_rows_sr(v, i, r, s, interpret=True)
+
+
+def _loop(fn, *args):
+    """fn over the leading axis of every argument, one call at a time."""
+    return jnp.stack([fn(*(a[t] for a in args))
+                      for t in range(args[0].shape[0])])
+
+
+def _same(a, b):
+    np.testing.assert_array_equal(
+        np.asarray(a.astype(jnp.float32)), np.asarray(b.astype(jnp.float32))
+    )
+
+
+@pytest.mark.parametrize("n", [16, 13, 1])  # 13, 1: not a block multiple
+def test_vmapped_gather_equals_the_per_table_loop(n):
+    vals, ix = _stack(10), _gather_ix(11, n)
+    _same(jax.vmap(_gather)(vals, ix), _loop(_gather, vals, ix))
+    want = jnp.take_along_axis(
+        vals, jnp.clip(ix, 0, C_ - 1)[:, :, None], axis=1)
+    _same(jax.vmap(_gather)(vals, ix), want)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("n", [16, 13])
+def test_vmapped_scatter_equals_the_per_table_loop(n, dtype):
+    """Skipped (negative) slots, padding, and for bf16 the same stochastic
+    rounding bits a table as its own unbatched call draws."""
+    vals, ix, rows = _stack(12, dtype), _scatter_ix(13, n), _rows(14, n)
+    seeds = jnp.arange(T_, dtype=jnp.int32) + 5
+    _same(jax.vmap(_apply)(vals, ix, rows, seeds),
+          _loop(_apply, vals, ix, rows, seeds))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_vmapped_scatter_with_one_seed_for_all_tables(dtype):
+    """The trainer's form: the seed is not mapped, every table draws from
+    the same one, as each unbatched call would."""
+    vals, ix, rows = _stack(15, dtype), _scatter_ix(16, 13), _rows(17, 13)
+    seed = jnp.int32(9)
+    got = jax.vmap(_apply, in_axes=(0, 0, 0, None))(vals, ix, rows, seed)
+    _same(got, _loop(lambda v, i, r: _apply(v, i, r, seed), vals, ix, rows))
+
+
+def test_vmapped_all_skipped_scatter_leaves_every_table_untouched():
+    """The insert that creates no row."""
+    vals, rows = _stack(18), _rows(19, 16)
+    ix = jnp.full((T_, 16), -1, jnp.int32)
+    _same(jax.vmap(_apply, in_axes=(0, 0, 0, None))(
+        vals, ix, rows, jnp.int32(0)), vals)
+
+
+def test_vmap_of_values_alone_and_of_indices_alone():
+    vals, ix = _stack(20), _gather_ix(21, 13)
+    _same(jax.vmap(_gather, in_axes=(0, None))(vals, ix[0]),
+          _loop(lambda v: _gather(v, ix[0]), vals))
+    # indices mapped over ONE table: T * n rows of that table
+    _same(jax.vmap(_gather, in_axes=(None, 0))(vals[0], ix),
+          _loop(lambda i: _gather(vals[0], i), ix))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_a_second_vmap_equals_the_double_loop(dtype):
+    """Shards x tables, as ShardedTrainer maps them."""
+    lead = (2, T_)
+    vals = _stack(22, dtype, lead)
+    ix, six = _gather_ix(23, 13, lead), _scatter_ix(24, 13, lead)
+    rows = _rows(25, 13, lead)
+    seeds = jnp.arange(6, dtype=jnp.int32).reshape(lead)
+    _same(jax.vmap(jax.vmap(_gather))(vals, ix),
+          _loop(lambda v, i: _loop(_gather, v, i), vals, ix))
+    _same(jax.vmap(jax.vmap(_apply))(vals, six, rows, seeds),
+          _loop(lambda *a: _loop(_apply, *a), vals, six, rows, seeds))
+
+
+def _walk(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs in its parameters, a
+    Pallas call's kernel body left out (its loops are the kernel's own)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _walk(sub)
+
+
+def _mechanism(fn, *args):
+    """(Pallas calls, loops, table-sized slices and write-backs) in the
+    jaxpr of fn."""
+    eqns = list(_walk(jax.make_jaxpr(fn)(*args).jaxpr))
+    names = [e.primitive.name for e in eqns]
+    table_sized = [
+        e for e in eqns
+        if e.primitive.name in ("dynamic_slice", "dynamic_update_slice")
+        and e.invars[0].aval.size >= C_ * D_
+    ]
+    return names.count("pallas_call"), names.count("while"), table_sized
+
+
+def test_vmapped_kernels_are_one_call_with_no_loop_and_no_table_slice():
+    """Pins the mechanism: under vmap, and under two, each operation is
+    exactly one pallas_call, with no `while` round it and no dynamic_slice
+    or dynamic_update_slice of anything as large as a [C, D] table."""
+    vals, ix, six, rows = (_stack(26), _gather_ix(27, 13),
+                           _scatter_ix(28, 13), _rows(29, 13))
+    seed = jnp.int32(1)
+    apply_tables = jax.vmap(_apply, in_axes=(0, 0, 0, None))
+    assert _mechanism(jax.vmap(_gather), vals, ix) == (1, 0, [])
+    assert _mechanism(apply_tables, vals, six, rows, seed) == (1, 0, [])
+    two = lambda a: jnp.stack([a, a])  # noqa: E731
+    assert _mechanism(jax.vmap(jax.vmap(_gather)),
+                      two(vals), two(ix)) == (1, 0, [])
+    assert _mechanism(jax.vmap(apply_tables, in_axes=(0, 0, 0, None)),
+                      two(vals), two(six), two(rows), seed) == (1, 0, [])
+
+
+def test_tables_past_the_smem_budget_split_into_ranges(monkeypatch):
+    """All tables' indices are one scalar prefetch; past the budget a call
+    splits over table ranges of the same whole array: as many calls as
+    ranges, still no loop and no table-sized slice, the same bits."""
+    vals, ix, six, rows = (_stack(30), _gather_ix(31, 13),
+                           _scatter_ix(32, 13), _rows(33, 13))
+    seed = jnp.int32(2)
+    apply_tables = jax.vmap(_apply, in_axes=(0, 0, 0, None))
+    want_g = jax.vmap(_gather)(vals, ix)
+    want_s = apply_tables(vals, six, rows, seed)
+    monkeypatch.setattr(fl, "_SMEM_INDEX_BYTES", 2 * 16 * 4)  # two tables
+    assert fl._table_ranges(T_, 16) == [(0, 2), (2, 1)]
+    assert _mechanism(jax.vmap(_gather), vals, ix) == (2, 0, [])
+    assert _mechanism(apply_tables, vals, six, rows, seed) == (2, 0, [])
+    _same(jax.vmap(_gather)(vals, ix), want_g)
+    _same(apply_tables(vals, six, rows, seed), want_s)
+
+
+def test_updates_mapped_over_one_table_are_noted_as_a_fallback():
+    """Folding them into one call would break the unique-slot contract:
+    each gets a copy of the table, and the repo's fallback counter says
+    so."""
+    from deeprec_tpu.obs.metrics import default_registry
+
+    vals, six, rows = _stack(34), _scatter_ix(35, 13), _rows(36, 13)
+    seed = jnp.int32(3)
+    got = jax.vmap(_apply, in_axes=(None, 0, 0, None))(
+        vals[0], six, rows, seed)
+    _same(got, _loop(lambda i, r: _apply(vals[0], i, r, seed), six, rows))
+    text = default_registry().render_prometheus()
+    assert 'kernel="apply_rows_sr",reason="values_unmapped"' in text.replace(
+        '", ', '",')
