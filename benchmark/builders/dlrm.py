@@ -50,7 +50,6 @@ class Program:
             unique_budget=int(mix["unique_budget"]),
         )
         self.fields = [f"C{c + 1}" for c in range(config["num_cat"])]
-        self.examples_per_step = int(mix["batch"])
         self._init = jax.jit(self.trainer.init)
         self._counters = jax.jit(self._counters_impl)
         self._rows = jax.jit(self._rows_impl)
@@ -79,6 +78,8 @@ class Program:
                           tot("dedup_unique"), tot("dedup_ids")])
 
     COUNTERS = ("insert_fails", "dedup_overflow", "dedup_unique", "dedup_ids")
+    # a step in which one of these rose is a failed step
+    FAIL_COUNTERS = ("insert_fails", "dedup_overflow")
 
     def counters(self, state):
         """Device int32 [4] in COUNTERS' order, summed over the tables."""

@@ -12,7 +12,13 @@ whichever is larger. `grad_gap` and `change_gap` are the worst leaf's gap,
 `grad_median_gap` and `change_median_gap` the median leaf's (steady from seed
 to seed where the worst leaf, a bias of a few elements, swings). A leaf whose
 reference gradient is under a thousandth of the median leaf's moves under
-Adam by round-off alone and is left out of the change. A number that a
+Adam by round-off alone and is left out of the change. So is a leaf of one
+element (the reference's `size`): under Adam it moves by about the learning
+rate whatever its gradient's size, so where its gradient is near nought a
+different rounding changes its whole step (two seeds in about sixty read a
+change gap of 0.56 and 0.63 on the last layer's bias, parent and change
+alike: PERF.md section 4); it is held on its gradient, among the leaves of
+`grad_gap` and `grad_median_gap`, as every leaf is. A number that a
 cell's `limits` file does not hold is read and printed but not compared:
 PERF.md names each with its readings. `fill_gap` is the harness's own and
 in no file: the share by which the rows the tables hold at the window's
@@ -67,7 +73,9 @@ def compare(prog: Dict, ref: Dict) -> Dict[str, Dict]:
         out[f"loss{i}_gap"] = {"value": gap if math.isfinite(gap)
                                else float("inf"), "leaf": ""}
     g_floor = 1e-3 * statistics.median(ref["grad"].values())
-    moved = [k for k in ref["change"] if ref["grad"][k] >= g_floor]
+    sizes = ref.get("size", {})
+    moved = [k for k in ref["change"]
+             if ref["grad"][k] >= g_floor and sizes.get(k, 2) > 1]
     for kind, leaves in (("grad", list(ref["grad"])), ("change", moved)):
         gaps = leaf_gaps(prog[kind], ref[kind], leaves)
         where = max(gaps, key=gaps.get)
@@ -136,9 +144,18 @@ def _tree_copy(tree):
 
 class ProgramReadings:
     """Collects the program's side of the comparison while set-up drives the
-    first steps through the timed call. `ref_init(ids [T, B]) -> [T, B, D]`
+    first steps through the timed call. `ref_init(ids [T, n]) -> [T, n, D]`
     is the reference's own initializer: the program's rows are measured from
-    it, so a program that initialized otherwise reads as a gap."""
+    it, so a program that initialized otherwise reads as a gap.
+
+    It covers any model on `Trainer` with Adagrad rows and a dense Adam,
+    whatever its fields are called and however their ids are shaped (a
+    field's ids are read flat: n is a batch's positions). Of the program
+    it needs `fields`, `read_rows(state, batch) -> [T, n, D]`,
+    `dense_params(state)` and `dense_first_moment(state)` (both {leaf
+    name: array} under the reference's names); of the configuration
+    `sparse_optimizer` (`lr`, `initial_accumulator_value`) and
+    `dense_optimizer` (`b1`)."""
 
     def __init__(self, program, config: Dict, ref_init):
         self.program, self.config, self.ref_init = program, config, ref_init
@@ -151,7 +168,8 @@ class ProgramReadings:
         self._dense0 = _tree_copy(self.program.dense_params(state))
 
     def _field_ids(self, host_batch):
-        return np.stack([host_batch[f] for f in self.fields])
+        return np.stack([np.asarray(host_batch[f]).reshape(-1)
+                         for f in self.fields])
 
     def after_step(self, state, host_batch, dev_batch, loss):
         self.loss.append(loss)

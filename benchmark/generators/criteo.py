@@ -1,7 +1,10 @@
-"""One general traffic generator for Criteo-shaped training batches.
+"""The generator `criteo`: Criteo-shaped training batches, one id a field.
 
-A traffic mix is a data file `traffic/<mix>.json`; this module is the only
-code that reads one. The laws are a copy of the program's
+A traffic mix is a data file `traffic/<mix>.json` that names its generator
+(`"generator": "criteo"`); the harness and the tools find this module by
+that name (`harness.load_mix`) and reach it through the generators'
+contract only: `check`, `make_batch`, `fill_steps`, `fill_batch`,
+`filled_rows`, `examples` (PERF.md section 3). The laws are a copy of the program's
 `data/synthetic.py::SyntheticCriteo` (bounded zipf by inverse CDF, uniform,
 lognormal dense features, a noisy logistic label over hidden per-id weights)
 with two changes that make it a yardstick. Batch `k` is a pure function of
@@ -17,7 +20,7 @@ empty. Fill batch j gives every field the next `min(unique_budget, batch)`
 ids of its vocabulary, repeated to the batch's length, so no step passes the
 budget.
 
-Keys of a mix file:
+Keys of a mix file of this generator:
   batch          examples per step
   num_cat        categorical fields (one id each: bags of 1)
   num_dense      numeric fields
@@ -28,28 +31,41 @@ Keys of a mix file:
 """
 from __future__ import annotations
 
-import json
-import os
 from typing import Dict, Optional
 
 import numpy as np
 
-HERE = os.path.dirname(os.path.abspath(__file__))
 _REQUIRED = ("batch", "num_cat", "num_dense", "vocab", "id_law",
              "unique_budget")
 
 
-def load_mix(name: str, base: str = HERE) -> Dict:
-    with open(os.path.join(base, "traffic", name + ".json")) as f:
-        mix = json.load(f)
+def check(mix: Dict) -> None:
+    """Raise ValueError where the mix lacks a key this generator needs or
+    holds a value it cannot draw from."""
+    name = mix.get("name")
     missing = [k for k in _REQUIRED if k not in mix]
     if missing:
         raise ValueError(f"traffic mix {name!r} lacks {missing}")
     if mix["id_law"] not in ("zipf", "uniform"):
         raise ValueError(f"traffic mix {name!r}: unknown id_law "
                          f"{mix['id_law']!r}")
-    mix["name"] = name
-    return mix
+    if mix["id_law"] == "zipf" and "zipf_a" not in mix:
+        raise ValueError(f"traffic mix {name!r} lacks ['zipf_a']")
+    if not 0 < mix["unique_budget"] <= mix["batch"]:
+        raise ValueError(f"traffic mix {name!r}: a unique_budget of "
+                         f"{mix['unique_budget']} for a batch of "
+                         f"{mix['batch']}")
+
+
+def examples(mix: Dict) -> int:
+    """Examples a step: one row of the batch is one example."""
+    return int(mix["batch"])
+
+
+def filled_rows(mix: Dict) -> int:
+    """Rows the tables hold once the fill batches went through: every id
+    of every field's vocabulary."""
+    return int(mix["num_cat"]) * int(mix["vocab"])
 
 
 def _mix32(x: np.ndarray) -> np.ndarray:

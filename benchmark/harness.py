@@ -6,14 +6,17 @@ drives the first steps through the timed call while reading what the
 comparison needs, fills the tables through the same call, measures the
 window, reads the per-layer metrics (traced
 run) and then, with the program's state freed, follows the same first steps
-in the plain reference and decides `correct`. It has no `if workload == ...`:
-everything of one configuration, mix, cell or per-layer metric is a file
-found by name.
+in the plain reference and decides `correct`. It has no `if workload == ...`
+and knows no model family, no generator and no scope: everything of one
+configuration, family, mix, generator, cell or per-layer metric is a file
+found by the name that data gives (`load_cell`, `load_module`; PERF.md
+section 3 lists the files and the contract of each).
 """
 from __future__ import annotations
 
 import gc
 import importlib
+import importlib.util
 import json
 import os
 import queue
@@ -22,7 +25,8 @@ import sys
 import threading
 import time
 from contextlib import contextmanager
-from typing import Callable, Dict, List, Optional
+from types import ModuleType
+from typing import Callable, Dict, List, NamedTuple, Optional
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -58,27 +62,80 @@ def load_config(manifest: Dict, name: str, root: str = ROOT) -> Dict:
     raise SystemExit(f"no configuration {name!r} in BENCHMARK.json")
 
 
-def load_cell(workload: str, root: str = ROOT, data: str = HERE):
-    """(manifest, cell, configuration, traffic mix, builder module,
-    reference module) of a workload, each found by the name the manifest
-    gives: the manifest under `root`, mixes and limits under `data`."""
-    from benchmark import traffic
+def load_module(kind: str, name: str, data: str = HERE) -> ModuleType:
+    """The module `<kind>/<name>.py`: the benchmark's own
+    (`benchmark.<kind>.<name>`), or, where a run is made under another
+    directory of data files (the tests'), the one that directory holds.
+    `kind` is `builders`, `reference`, `work`, `generators` or
+    `layer_metrics`."""
+    path = os.path.join(data, kind, name + ".py")
+    if data == HERE or not os.path.exists(path):
+        return importlib.import_module(f"benchmark.{kind}.{name}")
+    qualified = f"benchmark_data[{data}].{kind}.{name}"
+    if qualified not in sys.modules:
+        spec = importlib.util.spec_from_file_location(qualified, path)
+        module = importlib.util.module_from_spec(spec)
+        sys.modules[qualified] = module
+        try:
+            spec.loader.exec_module(module)
+        except BaseException:
+            del sys.modules[qualified]
+            raise
+    return sys.modules[qualified]
 
+
+def load_mix(name: str, data: str = HERE):
+    """(the mix `traffic/<name>.json`, its generator's module), the mix
+    checked by the generator it names."""
+    with open(os.path.join(data, "traffic", name + ".json")) as f:
+        mix = json.load(f)
+    mix["name"] = name
+    if "generator" not in mix:
+        raise ValueError(f"traffic mix {name!r} names no generator")
+    generator = load_module("generators", mix["generator"], data)
+    generator.check(mix)
+    return mix, generator
+
+
+class Cell(NamedTuple):
+    """A workload's data and modules, each found by a name that data gives:
+    the manifest under `root`; mixes, limits and any module the benchmark
+    itself does not hold under `data`."""
+    manifest: Dict
+    cell: Dict
+    config: Dict
+    mix: Dict
+    builder: ModuleType      # configuration's `builder`: class Program
+    reference: ModuleType    # configuration's `reference`: run, row_init
+    work: ModuleType         # configuration's `work`: the family's counts
+    generator: ModuleType    # mix's `generator`
+
+
+def load_cell(workload: str, root: str = ROOT, data: str = HERE) -> Cell:
     manifest = load_manifest(root)
     cell = find_cell(manifest, workload)
     config = load_config(manifest, cell["config"], root)
-    mix = traffic.load_mix(cell["traffic"], data)
-    builder = importlib.import_module(
-        f"benchmark.builders.{config['builder']}")
-    reference = importlib.import_module(
-        f"benchmark.reference.{config['reference']}")
-    return manifest, cell, config, mix, builder, reference
+    mix, generator = load_mix(cell["traffic"], data)
+    return Cell(manifest, cell, config, mix,
+                load_module("builders", config["builder"], data),
+                load_module("reference", config["reference"], data),
+                load_module("work", config["work"], data), generator)
 
 
-def load_peaks(device_kind: str) -> Dict:
-    with open(os.path.join(HERE, "peaks.json")) as f:
-        peaks = json.load(f)
+def load_peaks(device_kind: str, data: str = HERE,
+               required: bool = True) -> Optional[Dict]:
+    """The peaks of a device kind from `peaks.json` (the benchmark's, else
+    the one under `data`). A device that is in neither is an error, or,
+    off a TPU (the tests), no peaks."""
+    peaks: Dict = {}
+    for base in dict.fromkeys((data, HERE)):
+        path = os.path.join(base, "peaks.json")
+        if os.path.exists(path):
+            with open(path) as f:
+                peaks = {**json.load(f), **peaks}
     if device_kind not in peaks:
+        if not required:
+            return None
         raise SystemExit(f"no peaks for device kind {device_kind!r} in "
                          f"benchmark/peaks.json (it has {sorted(peaks)})")
     return peaks[device_kind]
@@ -219,8 +276,8 @@ def peak_bytes(chips: int) -> Optional[int]:
     return max(peaks) if peaks else None
 
 
-def load_layer_metric(name: str):
-    return importlib.import_module(f"benchmark.layer_metrics.{name}")
+def load_layer_metric(name: str, data: str = HERE) -> ModuleType:
+    return load_module("layer_metrics", name, data)
 
 
 def check_steps(program, state, next_batch, config: Dict, reference,
@@ -230,16 +287,11 @@ def check_steps(program, state, next_batch, config: Dict, reference,
     after the last. `next_batch()` gives (host batch, device batch).
     Returns (state, the program's readings as floats, the host batches)."""
     import jax
-    import numpy as np
 
     from benchmark import correct
 
-    salts = np.asarray([reference.field_salt(f) for f in program.fields],
-                       np.uint32)
-    init = config["embedding_init"]
-    ref_init = jax.jit(lambda ids: reference.init_rows(
-        ids, salts, config["emb_dim"], init["mean"], init["stddev"]))
-    readings = correct.ProgramReadings(program, config, ref_init)
+    readings = correct.ProgramReadings(
+        program, config, reference.row_init(config, program.fields))
     readings.before_first_step(state)
     host_batches = []
     for i in range(CHECK_STEPS):
@@ -266,17 +318,17 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         phases[name] = round(now - t_mark[0], 3)
         t_mark[0] = now
 
-    from benchmark import correct, traffic
+    from benchmark import correct
 
-    manifest, cell, config, mix, builder, reference = load_cell(
-        workload, root, data)
+    manifest, cell, config, mix, builder, reference, work, generator = \
+        load_cell(workload, root, data)
     limits = correct.load_limits(workload, data)
     import jax
 
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     device = device_facts(cell["chips"], require_tpu)
-    peaks = load_peaks(device["kind"]) if require_tpu else None
+    peaks = load_peaks(device["kind"], data, required=require_tpu)
     clock = CompileClock()
     mark("imports_and_device_s")
 
@@ -286,15 +338,15 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     jax.block_until_ready(state)
     mark("tables_and_weights_s")
 
-    n_fill = traffic.fill_steps(mix)
+    n_fill = generator.fill_steps(mix)
 
     def make_batch(k: int):
         # batches 0..CHECK_STEPS-1 are read for `correct`, the next n_fill
         # insert the vocabulary, the window takes the rest
         j = k - CHECK_STEPS
         if 0 <= j < n_fill:
-            return traffic.fill_batch(mix, seed, j)
-        return traffic.make_batch(mix, seed, k)
+            return generator.fill_batch(mix, seed, j)
+        return generator.make_batch(mix, seed, k)
 
     producer = Producer(make_batch, program.put)
     spans = Spans(annotate=trace)
@@ -372,12 +424,16 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     loss_host = np.asarray([float(x) for x in losses])
     cnt = np.stack([np.asarray(c) for c in [counters0] + counters])
     names = program.COUNTERS
-    rose = (np.diff(cnt[:, names.index("insert_fails")]) > 0) | (
-        np.diff(cnt[:, names.index("dedup_overflow")]) > 0)
+    # a step fails where a counter the program lists as a failure rose, or
+    # its loss is not finite
+    rose = np.zeros(steps, bool)
+    for name in program.FAIL_COUNTERS:
+        rose |= np.diff(cnt[:, names.index(name)]) > 0
     failed = int(np.sum(rose | ~np.isfinite(loss_host)))
     occ1 = program.occupied_rows(state)
     mem_peak = peak_bytes(cell["chips"])
-    examples = steps * program.examples_per_step
+    examples_per_step = generator.examples(mix)
+    examples = steps * examples_per_step
     capacity = program.capacity_rows()
     log(f"window: {steps} steps, {examples} examples in {window_s:.4f} s; "
         f"loss {loss_host[0]:.5f} -> {loss_host[-1]:.5f}; table occupancy "
@@ -385,13 +441,13 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         f"{occ1} at its end; compiles inside the window: {window_compiles}")
 
     # ---- free the program before the reference runs
-    examples_per_step = program.examples_per_step
     del state, program, producer, counters, counters0, losses, dev, loss, prev
     gc.collect()
 
     ctx = {
         "manifest": manifest, "cell": cell, "config": config, "mix": mix,
-        "peaks": peaks, "chips": cell["chips"], "steps": steps,
+        "work": work, "data": data, "trace_dir": trace_dir, "peaks": peaks,
+        "chips": cell["chips"], "steps": steps,
         "window_s": window_s, "examples": examples,
         "examples_per_step": examples_per_step, "spans": spans,
         "counters": cnt,
@@ -406,7 +462,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         from benchmark import trace_reduce
 
         ctx["trace"] = trace_reduce.reduce_dir(
-            trace_dir, ctx["chips"], ctx["traced_window_s"])
+            trace_dir, ctx["chips"], ctx["traced_window_s"], data)
         device["busy_s"] = ctx["trace"]["busy_s"]
         device["window_s"] = ctx["trace"]["window_s"]
         breakdown = ctx["trace"]["breakdown"]
@@ -414,7 +470,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
             {k: round(1e3 * v / max(traced_steps, 1), 3) for k, v in
              list(ctx["trace"]["by_file_s"].items())[:14]}))
         for m in manifest["per_layer"]:
-            value = load_layer_metric(m["name"]).read(ctx)
+            value = load_layer_metric(m["name"], data).read(ctx)
             if value is not None:
                 metrics[m["name"]] = {"value": float(value),
                                       "unit": m["unit"]}
@@ -434,8 +490,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     ref_readings = reference.run(config, check_batches, pseed)
     numbers = correct.compare(prog_readings, ref_readings)
     # exact: the window has to start on tables that hold the vocabulary
-    want = mix["num_cat"] * mix["vocab"]
-    numbers["fill_gap"] = {"value": abs(occ0 - want) / want, "leaf": ""}
+    want = generator.filled_rows(mix)
+    numbers["fill_gap"] = {"value": abs(occ0 - want) / max(want, 1),
+                           "leaf": ""}
     limits["fill_gap"] = 0.0
     occupancy = {"window_start_rows": occ0, "window_end_rows": occ1,
                  "filled_rows_wanted": want, "capacity_rows": capacity}

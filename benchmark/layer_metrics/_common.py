@@ -4,8 +4,6 @@ from __future__ import annotations
 import math
 from typing import Dict, List, Optional
 
-ENGINE_LAYERS = ("embedding engine", "row kernels")
-
 
 def layer_ms_per_step(ctx: Dict, layers) -> Optional[float]:
     trace, steps = ctx.get("trace"), ctx.get("traced_steps")
@@ -34,9 +32,19 @@ def quantile(values: List[float], q: float) -> float:
     return s[max(0, math.ceil(q * len(s)) - 1)]
 
 
-def counter_delta(ctx: Dict, name: str) -> int:
+def counter_delta(ctx: Dict, name: str) -> Optional[int]:
+    """The counter's rise over the window; None where the program has no
+    counter of that name."""
+    if name not in ctx["counter_names"]:
+        return None
     col = ctx["counter_names"].index(name)
     return int(ctx["counters"][-1, col] - ctx["counters"][0, col])
+
+
+def work(ctx: Dict, name: str):
+    """The function `name` of the configuration's work module, or None
+    where the family does not have it."""
+    return getattr(ctx.get("work"), name, None)
 
 
 def traced_examples_per_s(ctx: Dict) -> Optional[float]:

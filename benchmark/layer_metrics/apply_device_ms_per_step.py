@@ -5,7 +5,8 @@ LAYER = "sparse + dense apply"
 UNIT = "ms"
 MOVES = "train_examples_per_s"
 SOURCE = "device_trace"
+READS = {"layers": ["sparse + dense apply"]}
 
 
 def read(ctx):
-    return _common.layer_ms_per_step(ctx, ("sparse + dense apply",))
+    return _common.layer_ms_per_step(ctx, READS["layers"])

@@ -5,7 +5,8 @@ LAYER = "dense model"
 UNIT = "ms"
 MOVES = "train_examples_per_s"
 SOURCE = "device_trace"
+READS = {"layers": ["dense model"]}
 
 
 def read(ctx):
-    return _common.layer_ms_per_step(ctx, ("dense model",))
+    return _common.layer_ms_per_step(ctx, READS["layers"])

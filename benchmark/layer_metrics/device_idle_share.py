@@ -3,6 +3,7 @@ LAYER = "device"
 UNIT = "fraction"
 MOVES = "train_examples_per_s"
 SOURCE = "device_trace"
+READS = {"window": "busy"}
 
 
 def read(ctx):

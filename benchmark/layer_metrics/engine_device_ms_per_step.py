@@ -5,7 +5,8 @@ LAYER = "embedding engine"
 UNIT = "ms"
 MOVES = "train_examples_per_s"
 SOURCE = "device_trace"
+READS = {"layers": ["embedding engine", "row kernels"]}
 
 
 def read(ctx):
-    return _common.layer_ms_per_step(ctx, _common.ENGINE_LAYERS)
+    return _common.layer_ms_per_step(ctx, READS["layers"])

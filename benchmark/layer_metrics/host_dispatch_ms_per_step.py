@@ -3,6 +3,7 @@ LAYER = "trainer / step builder"
 UNIT = "ms"
 MOVES = "train_examples_per_s"
 SOURCE = "host_clock"
+READS = {"bench_span": "dispatch"}
 
 
 def read(ctx):

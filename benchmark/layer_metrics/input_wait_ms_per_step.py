@@ -3,6 +3,7 @@ LAYER = "host input"
 UNIT = "ms"
 MOVES = "train_examples_per_s"
 SOURCE = "host_clock"
+READS = {"bench_span": "input_wait"}
 
 
 def read(ctx):

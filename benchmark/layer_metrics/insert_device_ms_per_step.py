@@ -5,7 +5,8 @@ LAYER = "embedding engine"
 UNIT = "ms"
 MOVES = "train_examples_per_s"
 SOURCE = "device_trace"
+READS = {"stage": "engine_insert"}
 
 
 def read(ctx):
-    return phase_reduce.reading(ctx, "insert_device_ms_per_step")
+    return phase_reduce.reading(ctx, READS)

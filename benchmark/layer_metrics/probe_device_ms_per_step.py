@@ -5,7 +5,8 @@ LAYER = "embedding engine"
 UNIT = "ms"
 MOVES = "train_examples_per_s"
 SOURCE = "device_trace"
+READS = {"stage": "engine_probe"}
 
 
 def read(ctx):
-    return phase_reduce.reading(ctx, "probe_device_ms_per_step")
+    return phase_reduce.reading(ctx, READS)

@@ -5,7 +5,8 @@ LAYER = "embedding engine"
 UNIT = "count"
 MOVES = "train_examples_per_s"
 SOURCE = "device_trace"
+READS = {"loop": "engine_probe"}
 
 
 def read(ctx):
-    return phase_reduce.reading(ctx, "probe_passes_per_step")
+    return phase_reduce.reading(ctx, READS)

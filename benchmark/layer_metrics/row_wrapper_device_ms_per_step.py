@@ -5,7 +5,8 @@ LAYER = "row kernels"
 UNIT = "ms"
 MOVES = "train_examples_per_s"
 SOURCE = "device_trace"
+READS = {"rows": "wrapper"}
 
 
 def read(ctx):
-    return phase_reduce.reading(ctx, "row_wrapper_device_ms_per_step")
+    return phase_reduce.reading(ctx, READS)

@@ -5,7 +5,8 @@ LAYER = "sparse + dense apply"
 UNIT = "ms"
 MOVES = "train_examples_per_s"
 SOURCE = "device_trace"
+READS = {"phase": "phase_sparse_apply"}
 
 
 def read(ctx):
-    return phase_reduce.reading(ctx, "sparse_apply_device_ms_per_step")
+    return phase_reduce.reading(ctx, READS)

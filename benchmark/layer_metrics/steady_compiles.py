@@ -3,6 +3,7 @@ LAYER = "trainer / step builder"
 UNIT = "count"
 MOVES = "train_examples_per_s"
 SOURCE = "program_counter"
+READS = {"window": "compiles"}
 
 
 def read(ctx):

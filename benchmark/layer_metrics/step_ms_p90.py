@@ -5,6 +5,7 @@ LAYER = "benchmark loop"
 UNIT = "ms"
 MOVES = "train_examples_per_s"
 SOURCE = "device_trace"
+READS = {"step_program": "device_spans"}
 
 
 def read(ctx):

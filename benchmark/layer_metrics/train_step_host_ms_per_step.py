@@ -5,7 +5,8 @@ LAYER = "trainer / step builder"
 UNIT = "ms"
 MOVES = "train_examples_per_s"
 SOURCE = "program_span"
+READS = {"span": "deeprec.train_step"}
 
 
 def read(ctx):
-    return phase_reduce.reading(ctx, "train_step_host_ms_per_step")
+    return phase_reduce.reading(ctx, READS)

@@ -5,7 +5,8 @@ LAYER = "device"
 UNIT = "ms"
 MOVES = "train_examples_per_s"
 SOURCE = "device_trace"
+READS = {"layers": ["unattributed"]}
 
 
 def read(ctx):
-    return _common.layer_ms_per_step(ctx, ("unattributed",))
+    return _common.layer_ms_per_step(ctx, READS["layers"])

@@ -5,10 +5,12 @@ LAYER = "embedding engine"
 UNIT = "fraction"
 MOVES = "train_examples_per_s"
 SOURCE = "program_counter"
+READS = {"counters": ["dedup_unique", "dedup_ids"]}
 
 
 def read(ctx):
-    ids = _common.counter_delta(ctx, "dedup_ids")
-    if ids <= 0:
+    unique, ids = (_common.counter_delta(ctx, name)
+                   for name in READS["counters"])
+    if not ids or ids <= 0 or unique is None:
         return None
-    return _common.counter_delta(ctx, "dedup_unique") / ids
+    return unique / ids

@@ -5,7 +5,8 @@ LAYER = "device"
 UNIT = "ms"
 MOVES = "train_examples_per_s"
 SOURCE = "device_trace"
+READS = {"phase": "unphased"}
 
 
 def read(ctx):
-    return phase_reduce.reading(ctx, "unphased_device_ms_per_step")
+    return phase_reduce.reading(ctx, READS)

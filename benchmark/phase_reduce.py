@@ -7,14 +7,36 @@ gives it to what the program says it was doing: every HLO instruction's
 `op_name` (in the HLO module the profiler stores beside the events, as the
 sources are) holds the `jax.named_scope`s it was traced under, wrapped in
 the transforms above them (`vmap(engine_probe)`,
-`transpose(jvp(phase_dense_fwd_bwd))`). `phases.json` is the vocabulary, and
-says which name each per-layer metric reads; of an `op_name`'s tokens the
-outermost phase, the innermost stage, a `rows_*` token and a kernel's name
-are kept. An instruction the compiler made itself carries no name of jax's
-and inherits by `trace_reduce.inherit_sources`, handed the scopes in place
-of the sources; one the program made outside every phase stays unphased.
-Times are self times, so the phases and the unphased rest add up to the busy
-time.
+`transpose(jvp(phase_dense_fwd_bwd))`).
+
+The vocabulary is data: `phases.json` (the program's, which
+tests/test_scopes.py holds equal to deeprec_tpu/utils/scopes.py) and every
+`phases/*.json` beside it, merged on load. It is a set of GROUPS of scope
+names, each with a pick: of an `op_name`'s tokens the outermost (or
+innermost) one that is a name of the group is the instruction's pick in
+that group. Four groups have keys of their own in a vocabulary file:
+`phases` (with `exchange`, prefixes of further phase names; outermost),
+`stages`, `rows` and `kernels` (innermost); a file declares any further
+group under `groups`: `{"<group>": {"pick": "innermost" | "outermost",
+"names": [...]}}`. An instruction the compiler made itself carries no name
+of jax's and inherits by `trace_reduce.inherit_sources`, handed the scopes
+in place of the sources; one the program made outside every phase stays
+unphased. Times are self times, so the phases and the unphased rest add up
+to the busy time.
+
+What a per-layer metric reads stands in its own module as `READS`, one of
+(`reading` below):
+  {"phase": name}   device self time whose pick of `phase` is that name
+                    (`unphased`: none)
+  {"stage": name}   likewise of `stage`
+  {"scope": name}   likewise of whichever group holds the name
+  {"rows": "kernel" | "wrapper"}  under a `rows` pick: the Pallas calls,
+                    or the rest
+  {"kernel": name}  the Pallas calls whose pick of `kernel` is that name,
+                    wherever they stand
+  {"loop": name}    executions of the bodies of the `while`s whose pick (in
+                    the name's group) is that name, a step
+  {"span": name}    host time inside the program's spans of that name
 
 The scopes have to be in the `op_name`s: `enable_compile_cache()` sets
 `jax_traceback_in_locations_limit=1` for that. (With
@@ -24,20 +46,19 @@ frame; inside a loop's body that path starts at the body, and the compiler
 rebuilds the loops round the vmapped row kernels without metadata, so half
 a step cannot be given to its stage. Tried on the chip, PERF.md section 6.)
 
-The harness hands a reader no directory, so the file is found where the
-harness writes it (`<root>/benchmark_out/trace`), parsed once a run, and
-held to the harness's own reduction of the same file: a busy time that
-differs is another run's trace and gives no reading. A trace of a program
-without the engine's scopes (the parent of the PR that added them) gives no
-reading either.
+The file is parsed once a run and held to the harness's own reduction of
+the same file: a busy time that differs is another run's trace and gives no
+reading. Nor does a name of a group of which the trace holds no name at all
+(a program from before it wrote that group's scopes): a reader never
+returns 0 for what the program did not say.
 """
 from __future__ import annotations
 
 import bisect
-import functools
+import glob
+import importlib
 import json
 import os
-import re
 import sys
 from typing import Dict, List, NamedTuple, Optional, Tuple
 
@@ -45,91 +66,135 @@ from benchmark import trace_reduce
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 TRACE_DIR = os.path.join(os.path.dirname(HERE), "benchmark_out", "trace")
-KERNEL_TARGET = "tpu_custom_call"
 UNPHASED = "unphased"
-_TOKEN = re.compile(r"[A-Za-z_][A-Za-z0-9_.]*")
-_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([^\s=]+)\s*=\s")
-_BODY = re.compile(r"\bbody=%?([\w.\-]+)")
+# the groups that have keys of their own in a vocabulary file: (key of the
+# names, group, pick)
+_BUILT_IN = (("phases", "phase", "outermost"), ("stages", "stage", "innermost"),
+             ("rows", "rows", "innermost"), ("kernels", "kernel", "innermost"))
+KINDS = ("phase", "stage", "scope", "rows", "kernel", "loop", "span")
 
 
-class Scope(NamedTuple):
-    phase: str = ""
-    stage: str = ""
-    rows: str = ""
-    kernel: str = ""   # the program's name of the Pallas call, if any
+def load_vocabulary(data: str = HERE) -> Dict:
+    """The vocabulary files merged: `phases.json`, then `phases/*.json` in
+    the order of their names (the benchmark's, and those of `data` where a
+    run is made under another directory of data files). Lists are joined,
+    `groups` group by group; `note` and `reads` are dropped."""
+    paths = [os.path.join(HERE, "phases.json")]
+    paths += trace_reduce.data_files("phases", data)
+    merged: Dict = {"groups": {}}
+    for path in paths:
+        with open(path) as f:
+            part = json.load(f)
+        part.pop("note", None)
+        part.pop("reads", None)   # phases.json keeps the key, empty
+        for group, spec in part.pop("groups", {}).items():
+            have = merged["groups"].setdefault(
+                group, {"pick": spec["pick"], "names": []})
+            if have["pick"] != spec["pick"]:
+                raise ValueError(f"{path}: group {group!r} is picked "
+                                 f"{have['pick']} elsewhere")
+            have["names"] += [n for n in spec["names"]
+                              if n not in have["names"]]
+        for key, value in part.items():
+            if isinstance(value, list):
+                have = merged.setdefault(key, [])
+                have += [v for v in value if v not in have]
+            elif merged.setdefault(key, value) != value:
+                raise ValueError(f"{path}: {key!r} is {merged[key]!r} "
+                                 "elsewhere")
+    return merged
 
 
-@functools.lru_cache(maxsize=1)
-def load_vocabulary() -> Dict:
-    with open(os.path.join(HERE, "phases.json")) as f:
-        return json.load(f)
+class Group(NamedTuple):
+    name: str
+    outermost: bool
+    names: frozenset
+    prefixes: Tuple[str, ...]   # beginnings of further names of the group
+
+
+def groups_of(vocab: Dict) -> Tuple[Group, ...]:
+    """The groups a vocabulary declares, the four with keys of their own
+    first (a dict as `deeprec_tpu.utils.scopes.vocabulary()` gives it has
+    only those)."""
+    out = []
+    extra = dict(vocab.get("groups", {}))
+    for key, group, pick in _BUILT_IN:
+        more = extra.pop(group, {"names": []})
+        out.append(Group(
+            group, pick == "outermost",
+            frozenset(vocab.get(key, ())) | frozenset(more["names"]),
+            tuple(vocab.get("exchange", ())) if group == "phase" else ()))
+    for group, spec in extra.items():
+        if spec["pick"] not in ("innermost", "outermost"):
+            raise ValueError(f"group {group!r}: pick {spec['pick']!r}")
+        out.append(Group(group, spec["pick"] == "outermost",
+                         frozenset(spec["names"]), ()))
+    return tuple(out)
+
+
+class Scope(tuple):
+    """An instruction's pick in each group, in the groups' order ("" where
+    it stands under no name of the group); `scope.stage` is the pick of the
+    group `stage`."""
+
+    def __new__(cls, picks, groups: Tuple[str, ...]):
+        self = super().__new__(cls, picks)
+        self.groups = groups
+        return self
+
+    def __getattr__(self, group: str) -> str:
+        try:
+            return self[self.groups.index(group)]
+        except ValueError:
+            raise AttributeError(group) from None
 
 
 def scope_of(op_name: str, vocab: Dict) -> Scope:
-    """The scopes an `op_name` holds. Names of the vocabulary hold no `/`,
-    `(`, `)` or space, so its tokens are found whatever wraps them. Where
-    the compiler joined the names of fused operations with `;`, the first
-    one speaks."""
-    tokens = _TOKEN.findall(op_name.split(";", 1)[0])
-    families = tuple(vocab["exchange"])
+    """The scopes an `op_name` holds, group by group."""
+    return _scope_of(op_name, groups_of(vocab))
 
-    def last(names):
-        return next((t for t in reversed(tokens) if t in names), "")
 
-    return Scope(
-        next((t for t in tokens
-              if t in vocab["phases"] or t.startswith(families)), ""),
-        last(vocab["stages"]), last(vocab["rows"]), last(vocab["kernels"]))
+def _scope_of(op_name: str, groups: Tuple[Group, ...]) -> Scope:
+    tokens = trace_reduce.tokens_of(op_name)
+    picks = []
+    for g in groups:
+        order = tokens if g.outermost else reversed(tokens)
+        picks.append(next(
+            (t for t in order if t in g.names
+             or (g.prefixes and t.startswith(g.prefixes))), ""))
+    return Scope(picks, tuple(g.name for g in groups))
 
 
 class Module(NamedTuple):
     scopes: Dict[str, Scope]       # instruction -> its scopes, inherited
     kernels: frozenset             # instructions that are Pallas calls
-    probe_bodies: Dict[str, str]   # instruction -> the probe loop it is a
+    loop_bodies: Dict[str, str]    # instruction -> the `while` it is a
                                    # direct member of the body of
 
 
 def module_scopes(hlo_text: str, vocab: Dict) -> Module:
     """Scopes of every instruction of one module's HLO text."""
+    groups = groups_of(vocab)
+    names = tuple(g.name for g in groups)
     instrs = trace_reduce.parse_hlo(hlo_text)
-    body_of: Dict[str, str] = {}   # while instruction -> its body
-    fusions = set()
-    for line in hlo_text.splitlines():
-        m = _INSTR.match(line)
-        if not m or m.group(1) not in instrs:
-            continue
-        name = m.group(1)
-        i = line.find('op_name="')
-        op_name = line[i + 9:line.find('"', i + 9)] if i >= 0 else ""
+    for info in instrs.values():
         # a name of jax's own (`jit(step)/...`) speaks for itself, with or
         # without a scope in it; no name, or one the compiler gave (a bare
         # `scatter-add`, a parameter's path), inherits
-        instrs[name]["source"] = "|".join(
-            scope_of(op_name, vocab)) if "/" in op_name else ""
-        body = _BODY.search(line) if " while(" in line else None
-        if body:
-            body_of[name] = body.group(1)
-        elif " fusion(" in line:
-            fusions.add(name)
+        info["source"] = ("|" + "|".join(_scope_of(info["op_name"], groups))
+                          if "/" in info["op_name"] else "")
     inherited = trace_reduce.inherit_sources(instrs)
-    scopes = {name: Scope(*key.split("|")) if key else Scope()
+    scopes = {name: Scope(key[1:].split("|") if key else [""] * len(names),
+                          names)
               for name, (key, _) in inherited.items()}
-    calls = {name for name, (_, target) in inherited.items()
-             if target == KERNEL_TARGET or (target and scopes[name].kernel)}
-    # the compiler may fuse a Pallas call with the write of its result (a
-    # `kind=kCustom` fusion): the trace then times the fusion, not the call
-    holds_call = {instrs[name]["computation"] for name in calls}
-    kernels = frozenset(calls | {
-        name for name in fusions
-        if holds_call.intersection(instrs[name]["calls"])})
-    counted = {reads["loop"] for reads in vocab["reads"].values()
-               if "loop" in reads}
-    loops = {body: name for name, body in body_of.items()
-             if scopes[name].stage in counted}
-    probe_bodies = {name: loops[info["computation"]]
-                    for name, info in instrs.items()
-                    if info["computation"] in loops}
-    return Module(scopes, kernels, probe_bodies)
+    kernel_names = next(g.names for g in groups if g.name == "kernel")
+    kernels = frozenset(trace_reduce.pallas_calls(instrs, kernel_names))
+    loops = {info["body"]: name for name, info in instrs.items()
+             if info["op"] == "while" and info["body"]}
+    loop_bodies = {name: loops[info["computation"]]
+                   for name, info in instrs.items()
+                   if info["computation"] in loops}
+    return Module(scopes, kernels, loop_bodies)
 
 
 def read_modules(path: str, vocab: Dict, wanted) -> Dict[str, Module]:
@@ -186,28 +251,37 @@ def read_trace(path: str, vocab: Dict):
 def reduce_events(modules: Dict[str, Module], ops: Dict[str, List[Tuple]],
                   host: List[Tuple], chips: int, vocab: Dict) -> Dict:
     """Seconds of device self time (a mean over `chips` devices) by phase,
-    by stage, under `rows_*` by kernel and wrapper, by kernel name; the
-    executions of the probe loops' bodies; the program's host spans."""
+    by stage, by any scope name, under a `rows` pick by kernel and wrapper,
+    by kernel name; the executions of the loops' bodies by the loops'
+    scopes; the program's host spans."""
     by_phase: Dict[str, float] = {}
     by_stage: Dict[str, float] = {}
+    by_scope: Dict[str, float] = {}
     by_pair: Dict[str, float] = {}
     by_kernel: Dict[str, float] = {}
+    by_kernel_name: Dict[str, float] = {}
     by_op: Dict[str, float] = {}
     rows = {"kernel": 0.0, "wrapper": 0.0}
     kernels_all = busy = 0.0
     passes: Dict[Tuple[str, str, str, str], int] = {}
     devices = sorted(ops)[:chips]
+    groups = groups_of(vocab)
+    names = tuple(g.name for g in groups)
+    nowhere = Scope([""] * len(names), names)
     for dev in devices:
         timed = trace_reduce.self_times(ops[dev])
         busy += trace_reduce.union_ns((s, s + d) for s, d, *_ in timed)
         for _, _, instr, mod, self_ns in timed:
             module = modules.get(mod)
-            scope = module.scopes.get(instr, Scope()) if module else Scope()
+            scope = module.scopes.get(instr, nowhere) if module else nowhere
             phase = scope.phase or UNPHASED
             by_phase[phase] = by_phase.get(phase, 0.0) + self_ns
             if scope.stage:
                 by_stage[scope.stage] = by_stage.get(
                     scope.stage, 0.0) + self_ns
+            for pick in scope:
+                if pick:
+                    by_scope[pick] = by_scope.get(pick, 0.0) + self_ns
             pair = f"{phase}/{scope.stage or '-'}"
             by_pair[pair] = by_pair.get(pair, 0.0) + self_ns
             op = f"{instr} [{pair}/{scope.rows or '-'}]"
@@ -217,17 +291,27 @@ def reduce_events(modules: Dict[str, Module], ops: Dict[str, List[Tuple]],
                 kernels_all += self_ns
                 label = f"{phase}/{scope.kernel or instr}"
                 by_kernel[label] = by_kernel.get(label, 0.0) + self_ns
+                if scope.kernel:
+                    by_kernel_name[scope.kernel] = by_kernel_name.get(
+                        scope.kernel, 0.0) + self_ns
             if scope.rows:
                 rows["kernel" if is_kernel else "wrapper"] += self_ns
-            if module and instr in module.probe_bodies:
-                key = (dev, mod, module.probe_bodies[instr], instr)
+            if module and instr in module.loop_bodies:
+                key = (dev, mod, module.loop_bodies[instr], instr)
                 passes[key] = passes.get(key, 0) + 1
     # a loop's passes: the executions of one instruction of its body (each
-    # direct member runs once a pass; the commonest count is taken)
+    # direct member runs once a pass; the commonest count is taken), booked
+    # to every scope the loop stands under
     per_loop: Dict[Tuple, List[int]] = {}
     for key, count in passes.items():
         per_loop.setdefault(key[:3], []).append(count)
     n = max(len(devices), 1)
+    loop_passes: Dict[str, float] = {}
+    for (_, mod, loop), counts in per_loop.items():
+        for pick in modules[mod].scopes.get(loop, ()):
+            if pick:
+                loop_passes[pick] = loop_passes.get(pick, 0.0) + max(
+                    set(counts), key=counts.count) / n
     sec = lambda d: {k: v / n * 1e-9 for k, v in sorted(  # noqa: E731
         d.items(), key=lambda kv: -kv[1])}
     spans: Dict[str, Dict] = {}
@@ -236,23 +320,28 @@ def reduce_events(modules: Dict[str, Module], ops: Dict[str, List[Tuple]],
         rec["count"] += 1
         rec["total_s"] += dur * 1e-9
     return {
-        "scoped": any(s.stage for m in modules.values()
-                      for s in m.scopes.values()),
+        # the groups the program speaks at all: a reading of a name of any
+        # other group is no reading (a program from before it wrote them)
+        "groups": groups,
+        "groups_seen": {g.name for g in groups if any(
+            getattr(s, g.name) for m in modules.values()
+            for s in m.scopes.values())},
         "busy_s": busy / n * 1e-9,
         "by_phase_s": sec(by_phase), "by_stage_s": sec(by_stage),
+        "by_scope_s": sec(by_scope),
         "by_phase_and_stage_s": sec(by_pair), "rows_s": sec(rows),
         "kernels_s": kernels_all / n * 1e-9, "by_kernel_s": sec(by_kernel),
+        "by_kernel_name_s": sec(by_kernel_name),
         "top_ops_s": dict(list(sec(by_op).items())[:24]),
-        "probe_passes": sum(max(set(c), key=c.count)
-                            for c in per_loop.values()) / n,
-        "host_spans": spans,
+        "loop_passes": loop_passes,
+        "host_spans": spans, "step_span": vocab["step_span"],
         "train_steps": sorted((num, start, dur) for name, start, dur, num
                               in host if name == vocab["step_span"]),
     }
 
 
-def reduce_file(path: str, chips: int) -> Dict:
-    vocab = load_vocabulary()
+def reduce_file(path: str, chips: int, data: str = HERE) -> Dict:
+    vocab = load_vocabulary(data)
     modules, ops, host = read_trace(path, vocab)
     return reduce_events(modules, ops, host, chips, vocab)
 
@@ -262,50 +351,78 @@ _MEMO: Dict[Tuple, Dict] = {}
 
 def for_run(ctx: Dict) -> Optional[Dict]:
     """The reduction of this run's trace, or None where there is none to
-    read: no file, a program without the engine's scopes, or a file whose
-    busy time is not the one the harness reduced."""
+    read: no file, a program without any scope, or a file whose busy time
+    is not the one the harness reduced."""
     if not ctx.get("trace") or not ctx.get("traced_steps"):
         return None
     try:
-        path = trace_reduce.find_xplane(TRACE_DIR)
+        path = trace_reduce.find_xplane(ctx.get("trace_dir") or TRACE_DIR)
     except FileNotFoundError:
         return None
     stat = os.stat(path)
-    key = (path, stat.st_mtime_ns, stat.st_size, ctx["chips"])
+    data = ctx.get("data", HERE)
+    key = (path, stat.st_mtime_ns, stat.st_size, ctx["chips"], data)
     if key not in _MEMO:
         _MEMO.clear()
-        _MEMO[key] = reduce_file(path, ctx["chips"])
+        _MEMO[key] = reduce_file(path, ctx["chips"], data)
     red = _MEMO[key]
     want = ctx["trace"]["busy_s"]
-    if not red["scoped"] or abs(red["busy_s"] - want) > 0.01 * want:
+    if not red["groups_seen"] or abs(red["busy_s"] - want) > 0.01 * want:
         return None
     return red
 
 
-def readings(red: Dict, steps: int, vocab: Dict) -> Dict[str, float]:
-    """The per-layer metrics, by name, of a reduction over `steps` steps;
-    which name each reads is the vocabulary's (`reads`)."""
+def read_as(red: Dict, steps: int, reads: Dict[str, str]) -> Optional[float]:
+    """What a metric's `READS` names, of a reduction over `steps` steps:
+    device ms a step, passes a step, or host ms a step."""
+    (kind, name), = reads.items()
     ms = 1e3 / steps
-    out = {}
-    for name, reads in vocab["reads"].items():
-        if "stage" in reads:
-            out[name] = red["by_stage_s"].get(reads["stage"], 0.0) * ms
-        elif "phase" in reads:
-            out[name] = red["by_phase_s"].get(reads["phase"], 0.0) * ms
-        elif "rows" in reads:
-            out[name] = red["rows_s"][reads["rows"]] * ms
-        elif "loop" in reads:
-            out[name] = red["probe_passes"] / steps
-        elif red["train_steps"]:   # the step span: none without the program's
-            out[name] = sum(
-                d for _, _, d in red["train_steps"]) * 1e-6 / steps
-    return out
+    if kind not in KINDS:
+        raise ValueError(f"READS of an unknown kind: {reads}; the kinds "
+                         f"are {KINDS}")
+    if kind != "span":
+        group = {"phase": "phase", "stage": "stage", "rows": "rows",
+                 "kernel": "kernel"}.get(kind) or next(
+            (g.name for g in red["groups"] if name in g.names
+             or (g.prefixes and name.startswith(g.prefixes))), None)
+        if group not in red["groups_seen"]:
+            return None
+    if kind == "phase":
+        return red["by_phase_s"].get(name, 0.0) * ms
+    if kind == "stage":
+        return red["by_stage_s"].get(name, 0.0) * ms
+    if kind == "scope":
+        return red["by_scope_s"].get(name, 0.0) * ms
+    if kind == "rows":
+        return red["rows_s"][name] * ms
+    if kind == "kernel":
+        return red["by_kernel_name_s"].get(name, 0.0) * ms
+    if kind == "loop":
+        return red["loop_passes"].get(name, 0.0) / steps
+    if name == red["step_span"] and red["train_steps"]:
+        return sum(d for _, _, d in red["train_steps"]) * 1e-6 / steps
+    if name != red["step_span"] and name in red["host_spans"]:
+        return red["host_spans"][name]["total_s"] * ms
+    return None   # no such span: a trace without the program's
 
 
-def reading(ctx: Dict, name: str) -> Optional[float]:
+def reading(ctx: Dict, reads: Dict[str, str]) -> Optional[float]:
     red = for_run(ctx)
-    return readings(red, ctx["traced_steps"],
-                    load_vocabulary()).get(name) if red else None
+    return read_as(red, ctx["traced_steps"], reads) if red else None
+
+
+def metric_reads() -> Dict[str, Dict[str, str]]:
+    """{metric: its READS} of the benchmark's readers that read through
+    this module (benchmark/layer_metrics/*.py)."""
+    out = {}
+    for path in sorted(glob.glob(os.path.join(HERE, "layer_metrics",
+                                              "[a-z]*.py"))):
+        name = os.path.basename(path)[:-3]
+        reads = getattr(importlib.import_module(
+            f"benchmark.layer_metrics.{name}"), "READS", {})
+        if len(reads) == 1 and next(iter(reads)) in KINDS:
+            out[name] = reads
+    return out
 
 
 def main(argv: List[str]) -> int:
@@ -319,8 +436,11 @@ def main(argv: List[str]) -> int:
     steps = max(len(red["train_steps"]), 1)
     ms = lambda d: {k: round(v * 1e3 / steps, 4)  # noqa: E731
                     for k, v in d.items()}
+    metrics = {name: read_as(red, steps, reads)
+               for name, reads in metric_reads().items()}
     print(json.dumps({
-        "file": path, "steps": steps, "scoped": red["scoped"],
+        "file": path, "steps": steps,
+        "groups_seen": sorted(red["groups_seen"]),
         "busy_ms_per_step": round(red["busy_s"] * 1e3 / steps, 4),
         "kernels_ms_per_step": round(red["kernels_s"] * 1e3 / steps, 4),
         "by_phase_ms_per_step": ms(red["by_phase_s"]),
@@ -329,8 +449,10 @@ def main(argv: List[str]) -> int:
         "rows_ms_per_step": ms(red["rows_s"]),
         "by_kernel_ms_per_step": ms(red["by_kernel_s"]),
         "top_ops_ms_per_step": ms(red["top_ops_s"]),
-        "metrics": {k: round(v, 4) for k, v in
-                    readings(red, steps, load_vocabulary()).items()},
+        "loop_passes_per_step": {k: round(v / steps, 4)
+                                 for k, v in red["loop_passes"].items()},
+        "metrics": {k: round(v, 4) for k, v in metrics.items()
+                    if v is not None},
         "host_spans": red["host_spans"],
     }, indent=1))
     return 0

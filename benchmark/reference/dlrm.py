@@ -31,6 +31,11 @@ import jax.numpy as jnp
 import numpy as np
 
 HIGHEST = jax.lax.Precision.HIGHEST
+# what tools/calibrate.py puts in the program's place, and with which
+# arguments of `run`: the control, the planted fault, the second witness
+CONTROLS = {"control_fp8": {"mode": "fp8"},
+            "fault_half_batch": {"half_batch": True},
+            "witness_bf16": {"mode": "bf16"}}
 
 
 # ------------------------------------------------------------ initializers
@@ -61,6 +66,16 @@ def init_rows(ids, salts, dim: int, mean: float, stddev: float):
     z = jnp.sqrt(2.0) * jax.scipy.special.erfinv(
         jnp.clip(2.0 * u - 1.0, -1.0 + 1e-6, 1.0 - 1e-6))
     return mean + stddev * z
+
+
+def row_init(config: Dict, fields: Sequence[str]):
+    """ids [T, n] -> rows [T, n, D] as the configuration's stated
+    initializer makes them, for the tables of `fields`, jitted: what the
+    comparison measures the program's rows from."""
+    salts = np.asarray([field_salt(f) for f in fields], np.uint32)
+    init = config["embedding_init"]
+    return jax.jit(lambda ids: init_rows(
+        ids, salts, config["emb_dim"], init["mean"], init["stddev"]))
 
 
 def _glorot(key, shape):
@@ -256,7 +271,8 @@ def run(config: Dict, batches: Sequence[Dict[str, np.ndarray]], seed: int, *,
 
     Returns {"loss": [per step], "grad": {leaf: norm of the first step's
     gradient}, "change": {leaf: norm of the parameters' change after the
-    last step}}; the table of field c is the leaf "table.C<c>".
+    last step}, "size": {leaf: its number of elements}}; the table of field
+    c is the leaf "table.C<c>".
     """
     fields = [f"C{c + 1}" for c in range(config["num_cat"])]
     dense_keys = [f"I{i + 1}" for i in range(config["num_dense"])]
@@ -277,7 +293,10 @@ def run(config: Dict, batches: Sequence[Dict[str, np.ndarray]], seed: int, *,
         if t == 1:
             first = (g_dense, g_rows)
     norms = jax.device_get(summarise(params0, rows0, params, rows, *first))
-    out = {"loss": [float(x) for x in losses]}
+    out = {"loss": [float(x) for x in losses],
+           "size": {k: int(v.size) for k, v in leaf_names(params0).items()}}
+    out["size"].update({f"table.{f}": int(rows0[c].size)
+                        for c, f in enumerate(fields)})
     for kind in ("grad", "change"):
         out[kind] = {k: float(v) for k, v in norms[kind].items()}
         out[kind].update({f"table.{f}": float(norms[kind + "_tables"][c])

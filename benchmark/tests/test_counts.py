@@ -1,4 +1,4 @@
-"""The FLOP and byte counts against numbers worked out by hand, at the widths
+"""The DLRM family's work counts (benchmark/work/dlrm.py) against numbers worked out by hand, at the widths
 of the benchmark's configuration (full-rank cross, dim 128) and at DeepRec's
 modelzoo DLRM widths (pairwise dot, dim 16: data/configs/mid-dlrm.json)."""
 import json
@@ -6,8 +6,8 @@ import os
 
 import pytest
 
-from benchmark import counts
 from benchmark.layer_metrics import dense_roofline
+from benchmark.work import dlrm as work
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 CONFIGS = {"dlrmdcn-fullrank-d128": os.path.join(os.path.dirname(HERE),
@@ -29,8 +29,8 @@ def config(name):
 # Sum 41,245,440; x 6 = 247,472,640 FLOPs.
 @pytest.mark.parametrize("name, flops", [
     ("mid-dlrm", 2_879_904), ("dlrmdcn-fullrank-d128", 247_472_640)])
-def test_dense_flops_per_example(name, flops):
-    assert counts.dense_flops_per_example(config(name)) == flops
+def test_flops_per_example(name, flops):
+    assert work.flops_per_example(config(name), {"batch": 1}) == flops
 
 
 # weights 12 B each: 474,368 (dot), 41,245,440 (cross). Activations
@@ -40,7 +40,8 @@ def test_dense_flops_per_example(name, flops):
     ("mid-dlrm", 2048, 12 * 474_368 + 4 * 2048 * 9_174),
     ("dlrmdcn-fullrank-d128", 8192, 12 * 41_245_440 + 4 * 8192 * 80_425)])
 def test_dense_min_bytes(name, batch, nbytes):
-    assert counts.dense_min_bytes_per_step(config(name), batch) == nbytes
+    assert work.dense_min_bytes_per_step(config(name),
+                                         {"batch": batch}) == nbytes
 
 
 # key gather + claim 8 B, row read + written 2 x 4 D, Adagrad accumulator
@@ -49,14 +50,14 @@ def test_dense_min_bytes(name, batch, nbytes):
     ("mid-dlrm", 8 + 128 + 128 + 24),
     ("dlrmdcn-fullrank-d128", 8 + 1024 + 1024 + 24)])
 def test_engine_bytes_per_unique(name, nbytes):
-    assert counts.engine_bytes_per_unique(config(name)) == nbytes
+    assert work.engine_bytes_per_unique(config(name)) == nbytes
 
 
 def test_which_bound_applies():
     peaks = {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
-    ms, bound = dense_roofline.least_ms(config("mid-dlrm"), 2048,
-                                        peaks)
+    ms, bound = dense_roofline.least_ms(work, config("mid-dlrm"),
+                                        {"batch": 2048}, 2048, peaks)
     assert bound == "bandwidth" and ms == pytest.approx(0.09871, rel=1e-3)
-    ms, bound = dense_roofline.least_ms(config("dlrmdcn-fullrank-d128"), 8192,
-                                        peaks)
+    ms, bound = dense_roofline.least_ms(
+        work, config("dlrmdcn-fullrank-d128"), {"batch": 8192}, 8192, peaks)
     assert bound == "compute" and ms == pytest.approx(10.291, rel=1e-3)

@@ -8,7 +8,7 @@ import os
 
 import pytest
 
-from benchmark import phase_reduce, trace_reduce
+from benchmark import harness, phase_reduce, trace_reduce
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 DATA = os.path.join(HERE, "data")
@@ -143,9 +143,11 @@ def test_kernels_and_the_probe_loops_body(module):
                               "closed_call.9"}
     # direct members of the body of the `while` under engine_probe, not of
     # the fusion inside it, and not of the loop round the row kernel
-    assert set(module.probe_bodies) == {"p", "gte.1", "scatter.1",
-                                        "fusion.9", "tuple.1"}
-    assert set(module.probe_bodies.values()) == {"while.1"}
+    probe = {k for k, v in module.loop_bodies.items() if v == "while.1"}
+    assert probe == {"p", "gte.1", "scatter.1", "fusion.9", "tuple.1"}
+    # every loop's body is known, each by its `while`
+    assert set(module.loop_bodies.values()) == {"while.1", "while.2"}
+    assert module.loop_bodies["closed_call.7"] == "while.2"
 
 
 def events(passes):
@@ -185,7 +187,7 @@ def test_reduce_events_splits_by_phase_stage_rows_and_counts_passes(module):
         {"jit_step(1)": module}, {"/device:TPU:0": events([3, 5])}, host, 1,
         VOCAB)
     ns = 1e-9
-    assert red["scoped"]
+    assert red["groups_seen"] == {"phase", "stage", "rows", "kernel"}
     # self times: the phases and the unphased rest are the busy time
     assert sum(red["by_phase_s"].values()) == pytest.approx(red["busy_s"])
     assert red["by_phase_s"]["unphased"] == pytest.approx(2 * 7 * ns)
@@ -194,7 +196,11 @@ def test_reduce_events_splits_by_phase_stage_rows_and_counts_passes(module):
     # the probe: 8 passes of 40 ns and the loops' own self time
     probe = red["by_stage_s"]["engine_probe"]
     assert 8 * 40 * ns <= probe <= (8 * 40 + 8 * 4 + 4) * ns
-    assert red["probe_passes"] == 8
+    assert red["loop_passes"]["engine_probe"] == 8
+    # the loop round the row kernel: 2 x 4 passes, booked to each scope it
+    # stands under
+    assert red["loop_passes"]["rows_gather"] == red["loop_passes"][
+        "engine_gather"] == 8
     # under rows_*: the Pallas calls, and everything else (the wrapper:
     # slices, update-slices, the zero-fill, the copy, the loop itself)
     assert red["rows_s"]["kernel"] == pytest.approx(
@@ -205,7 +211,24 @@ def test_reduce_events_splits_by_phase_stage_rows_and_counts_passes(module):
         "phase_lookup/gather_rows": pytest.approx(320 * ns)}
     assert red["host_spans"]["deeprec.stage_batch"]["count"] == 1
     assert [n for n, *_ in red["train_steps"]] == [0, 1]
-    got = phase_reduce.readings(red, 2, VOCAB)
+    got = {name: phase_reduce.read_as(red, 2, reads)
+           for name, reads in phase_reduce.metric_reads().items()}
+    # the two kinds a later metric may use: any scope by its name, and a
+    # kernel by its `name=` wherever it stands
+    for stage, seconds in red["by_stage_s"].items():
+        assert phase_reduce.read_as(red, 2, {"scope": stage}) \
+            == pytest.approx(seconds * 1e3 / 2)
+    assert phase_reduce.read_as(red, 2, {"scope": "rows_scatter"}) \
+        == pytest.approx(2 * 60 * ns * 1e3 / 2)
+    assert phase_reduce.read_as(red, 2, {"kernel": "gather_rows"}) \
+        == pytest.approx(red["kernels_s"] * 1e3 / 2)
+    assert phase_reduce.read_as(red, 2, {"kernel": "apply_rows_sr"}) == 0.0
+    assert phase_reduce.read_as(red, 2, {"loop": "rows_gather"}) == 4
+    assert phase_reduce.read_as(red, 2, {"span": "deeprec.stage_batch"}) \
+        == pytest.approx(300e-6 / 2)
+    assert phase_reduce.read_as(red, 2, {"span": "deeprec.maintain"}) is None
+    with pytest.raises(ValueError, match="unknown kind"):
+        phase_reduce.read_as(red, 2, {"file": "table.py"})
     assert got["probe_passes_per_step"] == 4
     assert got["train_step_host_ms_per_step"] == pytest.approx(2e-3)
     assert got["unphased_device_ms_per_step"] == pytest.approx(7e-6)
@@ -244,10 +267,10 @@ def test_the_recorded_trace_reduces_to_what_was_pinned(recorded):
     red = phase_reduce.reduce_file(recorded[1], 1)
     with open(os.path.join(DATA, "phases.expected.json")) as f:
         want = json.load(f)
-    assert red["scoped"] and red["busy_s"] == pytest.approx(want["busy_s"])
+    assert red["busy_s"] == pytest.approx(want["busy_s"])
     for key in ("by_phase_s", "by_stage_s", "rows_s"):
         assert red[key] == pytest.approx(want[key]), key
-    assert red["probe_passes"] == want["probe_passes"]
+    assert red["loop_passes"]["engine_probe"] == want["probe_passes"]
     assert [n for n, *_ in red["train_steps"]] == want["step_nums"]
     assert red["host_spans"]["deeprec.stage_batch"]["count"] == 3
 
@@ -259,16 +282,17 @@ def test_the_identities_hold_on_the_recorded_trace(recorded):
     # the phases and the unphased rest are the busy time the harness reads
     assert red["busy_s"] == pytest.approx(old["busy_s"])
     assert sum(red["by_phase_s"].values()) == pytest.approx(old["busy_s"])
-    # every Pallas call stands under a rows_* scope. The reduction by
-    # source file knows a row kernel by its target or by a name that begins
-    # `tpu_custom_call`; the gather's call, which the compiler fuses with
-    # the write of its result and (since the op_names hold the scopes)
-    # names `closed_call.N`, it gives to the engine's files instead
+    # every Pallas call stands under a rows_* scope, and the reduction by
+    # layer knows the same calls by their kernels' names: the gather's too,
+    # which the compiler fuses with the write of its result and names
+    # `closed_call.N` (a reader that went by the instruction's name gave it
+    # to the engine's files: PERF.md section 6, PR 26)
     assert red["rows_s"]["kernel"] == pytest.approx(red["kernels_s"])
-    gathers = sum(v for k, v in red["by_kernel_s"].items()
-                  if k.endswith("/gather_rows"))
-    assert gathers > 0
-    assert red["rows_s"]["kernel"] - gathers == pytest.approx(
+    gathers = red["by_kernel_name_s"]["gather_rows"]
+    assert gathers == pytest.approx(sum(
+        v for k, v in red["by_kernel_s"].items()
+        if k.endswith("/gather_rows"))) and gathers > 0
+    assert red["rows_s"]["kernel"] == pytest.approx(
         old["by_layer_s"]["row kernels"])
     assert red["rows_s"]["wrapper"] > 0
     # the program's step leaves nothing unphased but the few operations
@@ -293,8 +317,6 @@ def test_the_harness_gets_its_readings_from_one_parse(recorded, monkeypatch):
     busy = reduce_file(recorded[1], 1)["busy_s"]
     calls.clear()
     ctx = {"trace": {"busy_s": busy}, "traced_steps": 3, "chips": 1}
-    from benchmark import harness
-
     got = {name: harness.load_layer_metric(name).read(ctx)
            for name in NEW_METRICS}
     assert len(calls) == 1
@@ -309,8 +331,10 @@ def test_the_harness_gets_its_readings_from_one_parse(recorded, monkeypatch):
 
 
 def test_a_program_without_the_scopes_gives_no_reading(tmp_path, monkeypatch):
-    """The trace PR 25 recorded, of the program before it named its
-    stages: the readers return None and the line leaves the metrics out."""
+    """The trace PR 25 recorded, of the program before it named its stages
+    and row funnels and wrote its host spans: the readers of those return
+    None and the line leaves the metrics out; its phases and its kernels'
+    names it did write."""
     where = tmp_path / "plugins" / "profile" / "parent"
     where.mkdir(parents=True)
     os.symlink(os.path.join(DATA, "recorded.xplane.pb"),
@@ -321,9 +345,90 @@ def test_a_program_without_the_scopes_gives_no_reading(tmp_path, monkeypatch):
         busy = json.load(f)["busy_s"]
     ctx = {"trace": {"busy_s": busy}, "traced_steps": 4, "chips": 1}
     red = phase_reduce.reduce_file(str(where / "recorded.xplane.pb"), 1)
-    assert red["busy_s"] == pytest.approx(busy) and not red["scoped"]
+    assert red["busy_s"] == pytest.approx(busy)
+    assert red["groups_seen"] == {"phase", "kernel"}
+    spoken = {"sparse_apply_device_ms_per_step", "unphased_device_ms_per_step"}
     for name in NEW_METRICS:
-        assert phase_reduce.reading(ctx, name) is None
+        value = harness.load_layer_metric(name).read(ctx)
+        assert (value is not None) == (name in spoken), name
     # and with no trace on disk at all
     monkeypatch.setattr(phase_reduce, "TRACE_DIR", str(tmp_path / "none"))
     assert phase_reduce.for_run(ctx) is None
+
+
+# ------------------------------------------- the vocabulary is files, merged
+
+
+def test_the_vocabulary_is_the_first_file_and_every_file_of_phases(tmp_path):
+    with open(os.path.join(os.path.dirname(HERE), "phases.json")) as f:
+        first = json.load(f)
+    assert first.pop("reads") == {} and first.pop("note")
+    assert VOCAB == {**first, "groups": {}}
+    assert [g.name for g in phase_reduce.groups_of(VOCAB)] == [
+        "phase", "stage", "rows", "kernel"]
+    # a directory of data files beside the benchmark's adds a file that
+    # declares a group of its own; nothing else moves
+    (tmp_path / "phases").mkdir()
+    for name, body in [
+            ("50-mixer", {"note": "a group of its own", "groups": {
+                "mixer": {"pick": "innermost", "names": ["seq_mix"]}}}),
+            ("60-more", {"stages": ["engine_evict"], "groups": {
+                "mixer": {"pick": "innermost", "names": ["seq_mix2"]}}})]:
+        (tmp_path / "phases" / f"{name}.json").write_text(json.dumps(body))
+    more = phase_reduce.load_vocabulary(str(tmp_path))
+    assert more.pop("groups") == {"mixer": {
+        "pick": "innermost", "names": ["seq_mix", "seq_mix2"]}}
+    assert more.pop("stages") == first.pop("stages") + ["engine_evict"]
+    assert more == first
+    (tmp_path / "phases" / "70-clash.json").write_text(json.dumps(
+        {"groups": {"mixer": {"pick": "outermost", "names": ["x"]}}}))
+    with pytest.raises(ValueError, match="picked innermost elsewhere"):
+        phase_reduce.load_vocabulary(str(tmp_path))
+
+
+def test_a_further_group_is_picked_as_it_says():
+    vocab = dict(VOCAB, groups={
+        "mixer": {"pick": "innermost", "names": ["mix_a", "mix_b"]},
+        "block": {"pick": "outermost", "names": ["block_0", "block_1"]},
+        # a file may also add names to a group that has a key of its own
+        "stage": {"pick": "innermost", "names": ["engine_evict"]}})
+    scope = phase_reduce.scope_of(
+        "jit(s)/phase_dense_fwd_bwd/block_0/mix_a/block_1/jvp(mix_b)/add",
+        vocab)
+    assert tuple(scope) == ("phase_dense_fwd_bwd", "", "", "", "mix_b",
+                            "block_0")
+    assert (scope.mixer, scope.block, scope.phase) == (
+        "mix_b", "block_0", "phase_dense_fwd_bwd")
+    assert phase_reduce.scope_of("jit(s)/phase_lookup/engine_evict/x",
+                                 vocab).stage == "engine_evict"
+    with pytest.raises(AttributeError):
+        scope.nothing
+    with pytest.raises(ValueError, match="pick"):
+        phase_reduce.groups_of(dict(VOCAB, groups={
+            "g": {"pick": "first", "names": ["a"]}}))
+
+
+def test_a_scope_of_a_further_group_is_timed_and_inherited():
+    vocab = dict(VOCAB, groups={
+        "mixer": {"pick": "innermost", "names": ["seq_mix"]}})
+    hlo = """HloModule jit_step
+
+ENTRY %main (x: f32[8]) -> f32[8] {
+  %x = f32[8]{0} parameter(0)
+  %cumsum.1 = f32[8]{0} reduce-window(%x), metadata={op_name="jit(step)/phase_dense_fwd_bwd/jvp(seq_mix)/cumsum"}
+  %copy.2 = f32[8]{0} copy(%cumsum.1)
+  ROOT %dot.3 = f32[8]{0} multiply(%copy.2, %x), metadata={op_name="jit(step)/phase_dense_fwd_bwd/dot_general"}
+}
+"""
+    module = phase_reduce.module_scopes(hlo, vocab)
+    assert module.scopes["cumsum.1"].mixer == "seq_mix"
+    assert module.scopes["copy.2"].mixer == "seq_mix"   # inherited
+    assert module.scopes["dot.3"].mixer == ""
+    ops = {"/device:TPU:0": [(0, 30, "cumsum.1", "m"), (40, 5, "copy.2", "m"),
+                             (50, 100, "dot.3", "m")]}
+    red = phase_reduce.reduce_events({"m": module}, ops, [], 1, vocab)
+    assert red["by_scope_s"]["seq_mix"] == pytest.approx(35e-9)
+    assert phase_reduce.read_as(red, 1, {"scope": "seq_mix"}) \
+        == pytest.approx(35e-6)
+    assert red["by_phase_s"] == {"phase_dense_fwd_bwd":
+                                 pytest.approx(135e-9)}

@@ -1,5 +1,6 @@
 #!/usr/bin/env python3
-"""Reckon a traffic mix's unique budget on the CPU, from the generator alone.
+"""Reckon a traffic mix's unique budget on the CPU, from the generator alone
+(one that gives `draw_ids(mix, seed, k) -> [fields, n]`, as `criteo` does).
 
     python3 benchmark/tools/budget.py <mix> [--batches 400] [--seeds 5]
 
@@ -19,7 +20,7 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))))
-from benchmark import traffic  # noqa: E402
+from benchmark import harness  # noqa: E402
 
 
 def main() -> None:
@@ -28,9 +29,9 @@ def main() -> None:
     ap.add_argument("--batches", type=int, default=400)
     ap.add_argument("--seeds", type=int, default=5)
     args = ap.parse_args()
-    mix = traffic.load_mix(args.mix)
+    mix, generator = harness.load_mix(args.mix)
     counts = np.asarray([[len(np.unique(r))
-                          for r in traffic.draw_ids(mix, 1_000_003 * seed, k)]
+                          for r in generator.draw_ids(mix, 1_000_003 * seed, k)]
                          for seed in range(1, args.seeds + 1)
                          for k in range(args.batches)])
     top, sd = int(counts.max()), float(counts.std())

@@ -1,9 +1,10 @@
 #!/usr/bin/env python3
 """Read what `correct` compares, on the chip, at a cell's own size, over
 several seeds in one process: the program against the reference (the lower
-readings), and in the program's place the control (the reference with float8
-operands), the planted fault (half of the batch left out) and the second
-witness (the reference in the program's own bfloat16 arithmetic). The limits in
+readings), and in the program's place each of the reference's `CONTROLS`
+(for `reference/dlrm.py`: the control, the reference with float8 operands;
+the planted fault, half of the batch left out; the second witness, the
+reference in the program's own bfloat16 arithmetic). The limits in
 `limits/<cell>.json` are set from what this prints; PERF.md keeps the
 readings.
 
@@ -37,10 +38,11 @@ def main() -> int:
                          "device readings")
     args = ap.parse_args()
 
-    from benchmark import correct, harness, traffic
+    from benchmark import correct, harness
 
-    _, cell, config, mix, builder, reference = harness.load_cell(
-        args.workload, args.root, args.data)
+    _, cell, config, mix, builder, reference, _, generator = \
+        harness.load_cell(args.workload, args.root, args.data)
+    kinds = dict(reference.CONTROLS)
     if args.any_platform:
         cache = None
     else:
@@ -50,8 +52,7 @@ def main() -> int:
     device = harness.device_facts(cell["chips"], not args.any_platform)
     program = builder.Program(config, mix)
     record = {"workload": args.workload, "device": device, "cache": cache,
-              "program": {}, "control_fp8": {}, "fault_half_batch": {},
-              "witness_bf16": {}, "readings": {}}
+              "program": {}, **{kind: {} for kind in kinds}, "readings": {}}
 
     def numbers(a, b):
         return {k: [v["value"], v["leaf"]]
@@ -66,7 +67,7 @@ def main() -> int:
         k = iter(range(harness.CHECK_STEPS))
 
         def next_batch():
-            host = traffic.make_batch(mix, seed, next(k))
+            host = generator.make_batch(mix, seed, next(k))
             return host, program.put(host)
 
         state, prog, batches = harness.check_steps(
@@ -78,21 +79,19 @@ def main() -> int:
         full = record["readings"].setdefault(seed, {})
         full["program"], full["reference"] = prog, ref
         if seed in controls:
-            for kind, kw in (("control_fp8", {"mode": "fp8"}),
-                             ("fault_half_batch", {"half_batch": True}),
-                             ("witness_bf16", {"mode": "bf16"})):
+            for kind, kw in kinds.items():
                 full[kind] = reference.run(config, batches, pseed, **kw)
                 record[kind][seed] = numbers(full[kind], ref)
         harness.log(f"seed {seed}: {time.perf_counter() - t0:.1f} s "
                     + json.dumps(record["program"][seed]))
-        for kind in ("control_fp8", "fault_half_batch", "witness_bf16"):
+        for kind in kinds:
             if seed in record[kind]:
                 harness.log(f"   {kind}: " + json.dumps(record[kind][seed]))
     summary = {}
     for n in next(iter(record["program"].values())):
         prog_max = max(r[n][0] for r in record["program"].values())
         row = {"program_max": prog_max}
-        for kind in ("control_fp8", "fault_half_batch", "witness_bf16"):
+        for kind in kinds:
             if record[kind]:
                 row[kind + "_min"] = min(r[n][0]
                                          for r in record[kind].values())

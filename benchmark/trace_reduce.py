@@ -9,9 +9,12 @@ executed program); on the host plane, the `bench.<name>` annotations the
 harness wrote around its own calls. An operation's time is its SELF time:
 its duration less that of the operations nested inside it, so that the
 layers' times add up to the busy time. An operation is given to a layer by
-`layers.json`, from the source file and line of the Python frame that made
-it. The trace's events carry only the HLO instruction's text; its source is
-in the HLO module the profiler stores beside them (the `Hlo Proto` stat of
+the rules of `layers/*.json` (one file a layer): a Pallas call by the
+`name=` the program gave its kernel, which stands in the call's `op_name`
+whatever the compiler calls the instruction; any other operation, and a
+Pallas call no rule lists, by the source file and line of the Python frame
+that made it. The trace's events carry only the HLO instruction's text; its
+source is in the HLO module the profiler stores beside them (the `Hlo Proto` stat of
 the `/host:metadata` plane, which jax's ProfileData does not expose, so the
 few protobuf fields on the way to it are decoded here by hand). An
 instruction the compiler made itself has no source and inherits one from
@@ -31,17 +34,46 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 UNATTRIBUTED = "unattributed"
+KERNEL_TARGET = "tpu_custom_call"
+_TOKEN = re.compile(r"[A-Za-z_][A-Za-z0-9_.]*")
 
 
-def load_rules() -> List[Dict]:
-    with open(os.path.join(HERE, "layers.json")) as f:
-        return json.load(f)["rules"]
+def data_files(kind: str, data: str = HERE) -> List[str]:
+    """The `<kind>/*.json` files of the benchmark and, where a run is made
+    under another directory of data files (the tests'), of that one too,
+    in the order of their names."""
+    found = {path for base in (HERE, data)
+             for path in glob.glob(os.path.join(base, kind, "*.json"))}
+    return sorted(found, key=lambda p: (os.path.basename(p), p))
 
 
-def layer_of(name: str, source: str, rules: List[Dict]) -> str:
-    for rule in rules:
-        if any(name.startswith(p) for p in rule["names"]):
+def load_rules(data: str = HERE) -> List[Dict]:
+    """One rule a file of `layers/`, in the order of the files' names."""
+    rules = []
+    for path in data_files("layers", data):
+        with open(path) as f:
+            rules.append(json.load(f))
+    return rules
+
+
+def tokens_of(op_name: str) -> List[str]:
+    """The words of an `op_name`, outermost first. Scope and kernel names
+    hold no `/`, `(`, `)` or space, so they are found whatever transform
+    wraps them. Where the compiler joined the names of fused operations
+    with `;`, the first one speaks."""
+    return _TOKEN.findall(op_name.split(";", 1)[0])
+
+
+def layer_of(name: str, source: str, rules: List[Dict],
+             kernel: Iterable[str] = ()) -> str:
+    """The layer of one device operation. `kernel` holds the tokens of a
+    Pallas call's `op_name` (nothing for any other operation): the rule
+    that lists one of them takes the call; else the source decides."""
+    tokens = set(kernel)
+    for rule in rules if tokens else ():
+        if tokens.intersection(rule["kernels"]):
             return rule["layer"]
+    for rule in rules:
         if source and any(s in source for s in rule["sources"]):
             return rule["layer"]
     return UNATTRIBUTED
@@ -130,6 +162,7 @@ _META = re.compile(r"(?<![A-Za-z_])metadata=\{([^}]*)\}")
 _CALLED = re.compile(r"\b(?:calls|to_apply|body|condition|"
                      r"branch_computations)=(\{[^}]*\}|%?[\w.\-]+)")
 _TARGET = re.compile(r'custom_call_target="([^"]*)"')
+_BODY = re.compile(r"\bbody=%?([\w.\-]+)")
 _TABLE_ROW = re.compile(r"^(\d+)\s+(.*)$")
 _TABLES = ("FileNames", "FunctionNames", "FileLocations", "StackFrames")
 
@@ -162,7 +195,9 @@ def _split_call(rhs: str) -> Tuple[str, str]:
 def parse_hlo(hlo_text: str) -> Dict[str, Dict]:
     """{instruction name: {"source": "file:line" or "", "target": the
     custom call's target or "", "operands": [names], "calls": [computation
-    names], "computation": the one it stands in}} from a module's HLO text.
+    names], "computation": the one it stands in, "op": its opcode,
+    "op_name": the name jax or the compiler gave it, or "", "body": a
+    `while`'s body or ""}} from a module's HLO text.
     The source comes through the `source_file`/`source_line` of the
     instruction's metadata or, where the text has a stack-frame index
     instead, through `stack_frame_id`."""
@@ -191,7 +226,11 @@ def parse_hlo(hlo_text: str) -> Dict[str, Dict]:
         name, rhs = m.group(1), m.group(2)
         op, operands = _split_call(rhs)
         target = _TARGET.search(rhs) if op == "custom-call" else None
+        i = rhs.find('op_name="')
+        body = _BODY.search(rhs) if op == "while" else None
         instrs[name] = info = {
+            "op": op, "body": body.group(1) if body else "",
+            "op_name": rhs[i + 9:rhs.find('"', i + 9)] if i >= 0 else "",
             "source": "", "target": target.group(1) if target else "",
             "operands": re.findall(r"%([\w.\-]+)", operands),
             "calls": [c.strip().lstrip("%") for found in _CALLED.findall(rhs)
@@ -286,17 +325,47 @@ def inherit_sources(instrs: Dict[str, Dict]) -> Dict[str, Tuple[str, str]]:
                    info["target"]) for name, info in instrs.items()}
 
 
-def module_sources(path: str) -> Dict[str, Dict[str, Tuple[str, str]]]:
-    """{module name: {instruction name: (source "file:line", custom-call
-    target)}} for a trace file."""
+def pallas_calls(instrs: Dict[str, Dict],
+                 kernel_names=frozenset()) -> Dict[str, List[str]]:
+    """{instruction: the tokens of its `op_name`} of a module's Pallas
+    calls: the custom calls whose target is `tpu_custom_call` (or, should
+    the target's name change, any custom call that stands under one of
+    `kernel_names`), and each fusion the compiler wraps one in together
+    with the write of its result (`kind=kCustom`; the trace then times the
+    fusion, not the call), which speaks with the wrapped call's name."""
+    calls = {}
+    for name, info in instrs.items():
+        tokens = tokens_of(info["op_name"]) if info["target"] else ()
+        if info["target"] == KERNEL_TARGET or (
+                tokens and kernel_names.intersection(tokens)):
+            calls[name] = tokens
+    inside = {instrs[name]["computation"]: name for name in calls}
+    for name, info in instrs.items():
+        if info["op"] == "fusion":
+            held = [inside[c] for c in info["calls"] if c in inside]
+            if held:
+                calls[name] = calls[held[0]]
+    return calls
+
+
+def instruction_facts(hlo_text: str, kernel_names=frozenset()
+                      ) -> Dict[str, Tuple[str, str, Tuple[str, ...]]]:
+    """{instruction name: (source "file:line", custom-call target, the
+    `op_name`'s tokens if it is a Pallas call)} of one module's HLO text."""
+    instrs = parse_hlo(hlo_text)
+    calls = pallas_calls(instrs, kernel_names)
+    return {name: (source, target, tuple(calls.get(name, ())))
+            for name, (source, target) in inherit_sources(instrs).items()}
+
+
+def module_sources(path: str, kernel_names=frozenset()) -> Dict[str, Dict]:
+    """{module name: its `instruction_facts`} for a trace file."""
     from jax._src.lib import xla_client
 
-    out = {}
-    for name, proto in hlo_modules(path).items():
-        module = xla_client._xla.HloModule.from_serialized_hlo_module_proto(
-            proto)
-        out[name] = inherit_sources(parse_hlo(module.to_string()))
-    return out
+    return {name: instruction_facts(
+        xla_client._xla.HloModule.from_serialized_hlo_module_proto(
+            proto).to_string(), kernel_names)
+        for name, proto in hlo_modules(path).items()}
 
 
 def instruction_name(event_name: str) -> str:
@@ -305,20 +374,22 @@ def instruction_name(event_name: str) -> str:
     return head[1:] if head.startswith("%") else head
 
 
-def read_events(path: str):
+def read_events(path: str, data: str = HERE):
     """(device_ops, modules, host_spans): device_ops {device: [(start_ns,
     dur_ns, instruction name (a custom call's led by its target), source
-    "file:line")]} of the `XLA Ops` lines,
+    "file:line", the `op_name`'s tokens of a Pallas call)]} of the `XLA
+    Ops` lines,
     modules {device: [(start_ns, dur_ns, name)]}, host_spans [(start_ns,
     dur_ns, name)] of the harness's `bench.*` annotations."""
     import jax
 
-    sources = module_sources(path)
-    data = jax.profiler.ProfileData.from_file(path)
+    sources = module_sources(path, frozenset(
+        k for rule in load_rules(data) for k in rule["kernels"]))
+    profile = jax.profiler.ProfileData.from_file(path)
     ops: Dict[str, List] = {}
     modules: Dict[str, List] = {}
     host: List[Tuple[float, float, str]] = []
-    for plane in data.planes:
+    for plane in profile.planes:
         if plane.name.startswith("/device:TPU:"):
             lines = {line.name: line for line in plane.lines}
             mods = sorted((e.start_ns, e.duration_ns, e.name)
@@ -331,11 +402,12 @@ def read_events(path: str):
                 name = instruction_name(e.name)
                 k = bisect.bisect_right(starts, e.start_ns) - 1
                 by_instr = sources.get(mods[k][2], {}) if k >= 0 else {}
-                source, target = by_instr.get(name, ("", ""))
+                source, target, kernel = by_instr.get(name, ("", "", ()))
                 if target and not name.startswith(target):
                     # a kernel the compiler named after its call site
                     name = f"{target}:{name}"
-                found.append((e.start_ns, e.duration_ns, name, source))
+                found.append((e.start_ns, e.duration_ns, name, source,
+                              kernel))
             if found:
                 ops[plane.name] = found
         elif plane.name.startswith("/host:"):
@@ -347,20 +419,21 @@ def read_events(path: str):
 
 
 def self_times(events: Iterable[Tuple]) -> List[Tuple]:
-    """[(start, dur, name, source, self_ns)]: each event's duration less the
-    durations of the events nested directly inside it (one line of a trace:
-    events nest or follow each other, they do not partly overlap)."""
+    """Each event `(start, dur, ...)` with its self time appended: its
+    duration less the durations of the events nested directly inside it (one
+    line of a trace: events nest or follow each other, they do not partly
+    overlap)."""
     out: List[List] = []
     stack: List[int] = []  # indices into out
-    for start, dur, name, source in sorted(events,
-                                           key=lambda e: (e[0], -e[1])):
+    for event in sorted(events, key=lambda e: (e[0], -e[1])):
+        start, dur = event[0], event[1]
         while stack and start >= out[stack[-1]][0] + out[stack[-1]][1]:
             stack.pop()
         if stack:
-            out[stack[-1]][4] -= dur
-        out.append([start, dur, name, source, dur])
+            out[stack[-1]][-1] -= dur
+        out.append([*event, dur])
         stack.append(len(out) - 1)
-    return [tuple(e[:4]) + (max(e[4], 0.0),) for e in out]
+    return [tuple(e[:-1]) + (max(e[-1], 0.0),) for e in out]
 
 
 def union_ns(intervals: Iterable[Tuple[float, float]]) -> float:
@@ -400,12 +473,12 @@ def idle_gaps(intervals, host_spans, top: int = 10):
 
 def reduce_events(ops: Dict[str, List], modules: Dict[str, List],
                   host_spans: List, chips: int,
-                  window_s: Optional[float] = None) -> Dict:
+                  window_s: Optional[float] = None, data: str = HERE) -> Dict:
     if not ops:
         raise RuntimeError("the trace holds no `XLA Ops` line of a TPU "
                            "device: nothing ran on the device, or the "
                            "profiler's names changed")
-    rules = load_rules()
+    rules = load_rules(data)
     devices = sorted(ops)[:chips]
     by_layer: Dict[str, float] = {}
     by_file: Dict[str, float] = {}
@@ -414,8 +487,8 @@ def reduce_events(ops: Dict[str, List], modules: Dict[str, List],
     for dev in devices:
         timed = self_times(ops[dev])
         busy += union_ns((s, s + d) for s, d, *_ in timed)
-        for start, dur, name, source, self_ns in timed:
-            layer = layer_of(name, source, rules)
+        for _, _, name, source, *kernel, self_ns in timed:
+            layer = layer_of(name, source, rules, *kernel)
             by_layer[layer] = by_layer.get(layer, 0.0) + self_ns
             where = source.rsplit(":", 1)[0] if source else "(no source)"
             by_file[where] = by_file.get(where, 0.0) + self_ns
@@ -442,6 +515,6 @@ def reduce_events(ops: Dict[str, List], modules: Dict[str, List],
 
 
 def reduce_dir(trace_dir: str, chips: int,
-               window_s: Optional[float] = None) -> Dict:
-    ops, modules, host = read_events(find_xplane(trace_dir))
-    return reduce_events(ops, modules, host, chips, window_s)
+               window_s: Optional[float] = None, data: str = HERE) -> Dict:
+    ops, modules, host = read_events(find_xplane(trace_dir), data)
+    return reduce_events(ops, modules, host, chips, window_s, data)

@@ -1,8 +1,14 @@
-"""The operations and bytes a step needs, from the configuration's widths.
+"""Work counts of the DLRM family (`"work": "dlrm"` in a configuration's
+file): the operations and bytes a step needs, from the configuration's
+widths and the mix's batch.
 
 These count the work, not the implementation: they read the same whichever
 kernel does it, and a later PR cannot change them. Checked by
-tests/test_counts.py against numbers worked out by hand.
+tests/test_counts.py against numbers worked out by hand. The contract of a
+work module (PERF.md section 3): `flops_per_example(config, mix)`,
+`dense_min_bytes_per_step(config, mix)`, `engine_bytes_per_unique(config)`;
+a function a family does not have is absent and its reader returns None. It
+imports nothing of the program.
 """
 from __future__ import annotations
 
@@ -29,8 +35,9 @@ def dense_layers(config: Dict) -> List[Tuple[int, int]]:
     return layers
 
 
-def dense_flops_per_example(config: Dict) -> float:
-    """Forward and backward of the MLPs, the cross layers and the pairwise
+def flops_per_example(config: Dict, mix: Dict) -> float:
+    """An example is one row of a Criteo batch. Forward and backward of the
+    whole model: the MLPs, the cross layers and the pairwise
     interaction: 2 FLOPs a multiply-add, the backward twice the forward
     (one product for the inputs' gradient, one for the weights'). The
     interaction counts the F(F-1)/2 pairs the model uses, not the F x F the
@@ -42,7 +49,7 @@ def dense_flops_per_example(config: Dict) -> float:
     return 6.0 * macs
 
 
-def dense_min_bytes_per_step(config: Dict, batch: int) -> float:
+def dense_min_bytes_per_step(config: Dict, mix: Dict) -> float:
     """The least HBM traffic of the dense forward and backward in float32:
     each weight read in the forward, read in the backward and its gradient
     written (12 B); each layer's input read in the forward and in the
@@ -50,7 +57,7 @@ def dense_min_bytes_per_step(config: Dict, batch: int) -> float:
     gradient read (4 B x (3 in + 2 out) an example)."""
     layers = dense_layers(config)
     weights = 12.0 * sum(i * o for i, o in layers)
-    acts = 4.0 * batch * sum(3 * i + 2 * o for i, o in layers)
+    acts = 4.0 * mix["batch"] * sum(3 * i + 2 * o for i, o in layers)
     return weights + acts
 
 
