@@ -319,3 +319,48 @@ def apply_grouped(fn, inputs, group_ids, num_groups: int):
         return jnp.where(mask, rows, jnp.nan)
 
     return jax.tree.map(broadcast, out)
+
+
+# ------------------------------------------- token-model building blocks
+
+
+def rms_norm(x, w, eps: float = 1e-6, zero_centred: bool = False):
+    """RMS normalisation over the last axis in f32. `zero_centred` scales by
+    `1 + w` (a weight initialised at 0), else by `w` (initialised at 1)."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return y * ((1.0 + w) if zero_centred else w)
+
+
+def rotary_partial(x, positions, rot_dim: int, theta: float):
+    """Rotary position embedding on the first `rot_dim` dims of the last
+    axis, half-split form (`rot(x) = concat(-x[half:], x[:half])`,
+    `x cos + rot(x) sin`, `inv_freq_j = theta^(-2j / rot_dim)`); the other
+    dims pass through. x [..., L, D] f32, positions [L]."""
+    half = rot_dim // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rot_dim)
+    ang = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)               # [L, half]
+    x1, x2, rest = x[..., :half], x[..., half:rot_dim], x[..., rot_dim:]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
+
+
+def swiglu_apply(x, w_gate, w_up, w_down, compute_dtype=jnp.bfloat16):
+    """`(silu(x W_g) * (x W_u)) W_d`, operands in `compute_dtype`, every
+    product accumulated in f32."""
+    xc = x.astype(compute_dtype)
+    g = matmul(xc, w_gate.astype(compute_dtype))
+    u = matmul(xc, w_up.astype(compute_dtype))
+    h = (jax.nn.silu(g) * u).astype(compute_dtype)
+    return matmul(h, w_down.astype(compute_dtype))
+
+
+def causal_conv1d(x, w):
+    """Depthwise causal convolution over time without bias: x [B, L, C]
+    (any float dtype; the sum is f32), w [K, C];
+    `y_t = sum_j w[j] x[t - (K - 1) + j]` (left padding K - 1, the
+    cross-correlation a `[C, 1, K]` conv1d weight computes)."""
+    K, L = w.shape[0], x.shape[1]
+    xp = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
+    return sum(xp[:, j:j + L].astype(jnp.float32) * w[j] for j in range(K))

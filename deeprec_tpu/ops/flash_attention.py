@@ -11,6 +11,14 @@ blocks in VMEM scratch, and a dQ kernel with the forward's access pattern —
 no atomics, no [L, S] materialization, causal blocks skipped on both sides
 of the diagonal.
 
+Grouped-query attention: k and v may carry fewer heads than q (`H` a
+multiple of `Hkv`); query head `h` reads key/value head `h // (H / Hkv)`
+through the kernels' index maps (no repeated copy of k or v is made), and
+the dK/dV kernel walks the Q blocks of all the query heads of its group.
+The products take their operands in the dtype they are handed (bf16
+operands run the MXU at its bf16 rate; f32 operands as before), always
+accumulating in f32; softmax statistics are f32.
+
 On non-TPU backends the kernels run in interpreter mode (tests) or fall
 back to a blockwise lax.scan implementation with the same memory shape.
 """
@@ -22,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deeprec_tpu.utils import backend
+from deeprec_tpu.utils import backend, scopes
 
 NEG_INF = -1e30
 
@@ -31,9 +39,11 @@ NEG_INF = -1e30
 
 
 def attention_reference(q, k, v, mask=None, causal=False, sm_scale=None):
-    """Plain jnp attention (oracle + CPU fallback). q,k,v: [B, H, L, D]."""
+    """Plain jnp attention (oracle + CPU fallback). q: [B, H, L, D];
+    k, v: [B, Hkv, S, D] with H a multiple of Hkv."""
     B, H, Lq, D = q.shape
     S = k.shape[2]
+    k, v = _repeat_kv(k, H), _repeat_kv(v, H)
     scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(D)
     logits = jnp.einsum("bhld,bhsd->bhls", q, k) * scale
     if mask is not None:
@@ -46,6 +56,13 @@ def attention_reference(q, k, v, mask=None, causal=False, sm_scale=None):
     return jnp.einsum("bhls,bhsd->bhld", p, v)
 
 
+def _repeat_kv(x, heads: int):
+    """[B, Hkv, S, D] -> [B, heads, S, D], each key/value head serving
+    `heads / Hkv` consecutive query heads (the non-kernel paths' form)."""
+    g = heads // x.shape[1]
+    return x if g == 1 else jnp.repeat(x, g, axis=1)
+
+
 # ------------------------------------------------------------- pallas forward
 
 
@@ -53,9 +70,10 @@ def _masked_scores(q, k, mk, qb, kb, block_q, block_k, sm_scale, causal):
     """Scaled QK^T with padding + causal masking — the one definition all
     three kernels (fwd, dKdV, dQ) share; a drift here would silently
     desynchronize forward and backward. Inlines at trace time.
-    q [block_q, D] f32, k [block_k, D] f32, mk [1, block_k] int; qb/kb
-    are the Q/K *block* indices."""
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
+    q [block_q, D], k [block_k, D] (their own dtype, f32 accumulation),
+    mk [1, block_k] int; qb/kb are the Q/K *block* indices."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * sm_scale
     s = jnp.where(mk > 0, s, NEG_INF)
     if causal:
         qpos = qb * block_q + jax.lax.broadcasted_iota(
@@ -71,7 +89,8 @@ def _masked_scores(q, k, mk, qb, kb, block_q, block_k, sm_scale, causal):
 def _ds_from_p(p, do, v, delta, sm_scale):
     """dS = P ∘ (dO·Vᵀ − Δ)·scale — shared by both backward kernels.
     delta is a [block_q, 1] column."""
-    dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
+    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
     return p * (dp - delta) * sm_scale
 
 
@@ -109,9 +128,9 @@ def _fa_fwd_kernel(
 
     @pl.when(run)
     def _step():
-        q = q_ref[0].astype(jnp.float32)  # [block_q, D]
-        k = k_ref[0].astype(jnp.float32)  # [block_k, D]
-        v = v_ref[0].astype(jnp.float32)
+        q = q_ref[0]  # [block_q, D]
+        k = k_ref[0]  # [block_k, D]
+        v = v_ref[0]
         mk = mask_ref[0]  # [1, block_k]
         s = _masked_scores(q, k, mk, qb, kb, block_q, block_k, sm_scale,
                            causal)
@@ -122,7 +141,7 @@ def _fa_fwd_kernel(
         m_scr[:] = m_new
         l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
         acc_scr[:] = acc_scr[:] * corr + jnp.dot(
-            p, v, preferred_element_type=jnp.float32
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32
         )
 
     @pl.when(kb == num_kb - 1)
@@ -137,16 +156,17 @@ def _pallas_forward(q, k, v, mask, causal, sm_scale, block_q, block_k, interpret
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, Lq, D = q.shape
-    S = k.shape[2]
+    Hkv, S = k.shape[1], k.shape[2]
+    G = H // Hkv  # query heads a key/value head
     BH = B * H
     qr = q.reshape(BH, Lq, D)
-    kr = k.reshape(BH, S, D)
-    vr = v.reshape(BH, S, D)
+    kr = k.reshape(B * Hkv, S, D)
+    vr = v.reshape(B * Hkv, S, D)
     # Mosaic needs the last two dims of a block (8, 128)-aligned or whole,
     # which a (1, block) block over a 2-D array is not: the key mask rides
-    # as [BH, 1, S] rows and the LSE as [BH, Lq, 1] columns — also the
+    # as [B*Hkv, 1, S] rows and the LSE as [BH, Lq, 1] columns — also the
     # shapes the kernels consume them in.
-    maskr = jnp.repeat(mask.astype(jnp.int32), H, axis=0)[:, None, :]
+    maskr = jnp.repeat(mask.astype(jnp.int32), Hkv, axis=0)[:, None, :]
 
     num_kb = S // block_k
     grid = (BH, Lq // block_q, num_kb)
@@ -159,9 +179,9 @@ def _pallas_forward(q, k, v, mask, causal, sm_scale, block_q, block_k, interpret
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, kb: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, kb: (b, kb, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, kb: (b, kb, 0)),
-            pl.BlockSpec((1, 1, block_k), lambda b, i, kb: (b, 0, kb)),
+            pl.BlockSpec((1, block_k, D), lambda b, i, kb: (b // G, kb, 0)),
+            pl.BlockSpec((1, block_k, D), lambda b, i, kb: (b // G, kb, 0)),
+            pl.BlockSpec((1, 1, block_k), lambda b, i, kb: (b // G, 0, kb)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, kb: (b, i, 0)),
@@ -177,6 +197,7 @@ def _pallas_forward(q, k, v, mask, causal, sm_scale, block_q, block_k, interpret
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
+        name=scopes.KERNEL_FLASH_FWD,
     )(qr, kr, vr, maskr)
     return o.reshape(B, H, Lq, D), lse.reshape(B, H, Lq)
 
@@ -189,6 +210,7 @@ def _blockwise_forward(q, k, v, mask, causal, sm_scale, block_k):
     as the recompute inside the backward."""
     B, H, Lq, D = q.shape
     S = k.shape[2]
+    k, v = _repeat_kv(k, H), _repeat_kv(v, H)
     nb = S // block_k
     qpos = jax.lax.broadcasted_iota(jnp.int32, (Lq, block_k), 0)
 
@@ -228,17 +250,20 @@ def _fa_bwd_dkdv_kernel(
     q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
     dk_ref, dv_ref, dk_scr, dv_scr, *,
     block_q: int, block_k: int, sm_scale: float, causal: bool, num_qb: int,
+    group: int,
 ):
-    """dK/dV: grid = (BH, S/block_k, Lq/block_q). One K/V block owns the
-    kernel instance; Q blocks stream through the sequential minor grid
-    axis, accumulating dk/dv in VMEM scratch (flash-2 structure: no
-    atomics, no [L, S] materialization)."""
+    """dK/dV: grid = (B*Hkv, S/block_k, group * Lq/block_q). One K/V block
+    owns the kernel instance; the Q blocks of every query head of its group
+    stream through the sequential minor grid axis, accumulating dk/dv in
+    VMEM scratch (flash-2 structure: no atomics, no [L, S]
+    materialization)."""
     from jax.experimental import pallas as pl
 
-    qb = pl.program_id(2)
+    t = pl.program_id(2)
+    qb = t % num_qb
     kb = pl.program_id(1)
 
-    @pl.when(qb == 0)
+    @pl.when(t == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
@@ -250,21 +275,25 @@ def _fa_bwd_dkdv_kernel(
 
     @pl.when(run)
     def _step():
-        q = q_ref[0].astype(jnp.float32)       # [block_q, D]
-        k = k_ref[0].astype(jnp.float32)       # [block_k, D]
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)     # [block_q, D]
+        q = q_ref[0]                           # [block_q, D]
+        k = k_ref[0]                           # [block_k, D]
+        v = v_ref[0]
+        do = do_ref[0]                         # [block_q, D]
         lse = lse_ref[0]                       # [block_q, 1]
         delta = delta_ref[0]
         mk = mask_ref[0]                       # [1, block_k]
         s = _masked_scores(q, k, mk, qb, kb, block_q, block_k, sm_scale,
                            causal)
         p = _probs_from_lse(s, lse)            # exact probs from saved LSE
-        dv_scr[:] += jnp.dot(p.T, do, preferred_element_type=jnp.float32)
+        dv_scr[:] += jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
         ds = _ds_from_p(p, do, v, delta, sm_scale)
-        dk_scr[:] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
+        dk_scr[:] += jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    @pl.when(qb == num_qb - 1)
+    @pl.when(t == group * num_qb - 1)
     def _finish():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -291,10 +320,10 @@ def _fa_bwd_dq_kernel(
 
     @pl.when(run)
     def _step():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
+        q = q_ref[0]
+        k = k_ref[0]
+        v = v_ref[0]
+        do = do_ref[0]
         lse = lse_ref[0]
         delta = delta_ref[0]
         mk = mask_ref[0]
@@ -302,7 +331,8 @@ def _fa_bwd_dq_kernel(
                            causal)
         p = _probs_from_lse(s, lse)
         ds = _ds_from_p(p, do, v, delta, sm_scale)
-        dq_scr[:] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
+        dq_scr[:] += jnp.dot(ds.astype(k.dtype), k,
+                             preferred_element_type=jnp.float32)
 
     @pl.when(kb == num_kb - 1)
     def _finish():
@@ -315,14 +345,15 @@ def _pallas_backward(q, k, v, mask, causal, sm_scale, block_q, block_k,
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, Lq, D = q.shape
-    S = k.shape[2]
-    BH = B * H
+    Hkv, S = k.shape[1], k.shape[2]
+    G = H // Hkv
+    BH, BK = B * H, B * Hkv
     qr = q.reshape(BH, Lq, D)
-    kr = k.reshape(BH, S, D)
-    vr = v.reshape(BH, S, D)
-    dor = do.reshape(BH, Lq, D)
+    kr = k.reshape(BK, S, D)
+    vr = v.reshape(BK, S, D)
+    dor = do.astype(q.dtype).reshape(BH, Lq, D)
     lser = lse.astype(jnp.float32).reshape(BH, Lq, 1)
-    maskr = jnp.repeat(mask.astype(jnp.int32), H, axis=0)[:, None, :]
+    maskr = jnp.repeat(mask.astype(jnp.int32), Hkv, axis=0)[:, None, :]
     # delta = rowsum(do * o): cheap elementwise+reduce, XLA fuses it; the
     # kernels read it per Q block.
     delta = jnp.sum(
@@ -335,32 +366,37 @@ def _pallas_backward(q, k, v, mask, causal, sm_scale, block_q, block_k,
 
     dkdv_kernel = functools.partial(
         _fa_bwd_dkdv_kernel, block_q=block_q, block_k=block_k,
-        sm_scale=sm_scale, causal=causal, num_qb=num_qb,
+        sm_scale=sm_scale, causal=causal, num_qb=num_qb, group=G,
     )
+
+    def q_side(b, kb, t):  # the Q block of query head t // num_qb of group b
+        return (b * G + t // num_qb, t % num_qb, 0)
+
     dk, dv = pl.pallas_call(
         dkdv_kernel,
-        grid=(BH, num_kb, num_qb),
+        grid=(BK, num_kb, G * num_qb),
         in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, kb, qb: (b, qb, 0)),  # q
-            pl.BlockSpec((1, block_k, D), lambda b, kb, qb: (b, kb, 0)),  # k
-            pl.BlockSpec((1, block_k, D), lambda b, kb, qb: (b, kb, 0)),  # v
-            pl.BlockSpec((1, 1, block_k), lambda b, kb, qb: (b, 0, kb)),  # mask
-            pl.BlockSpec((1, block_q, D), lambda b, kb, qb: (b, qb, 0)),  # do
-            pl.BlockSpec((1, block_q, 1), lambda b, kb, qb: (b, qb, 0)),  # lse
-            pl.BlockSpec((1, block_q, 1), lambda b, kb, qb: (b, qb, 0)),  # delta
+            pl.BlockSpec((1, block_q, D), q_side),                        # q
+            pl.BlockSpec((1, block_k, D), lambda b, kb, t: (b, kb, 0)),   # k
+            pl.BlockSpec((1, block_k, D), lambda b, kb, t: (b, kb, 0)),   # v
+            pl.BlockSpec((1, 1, block_k), lambda b, kb, t: (b, 0, kb)),   # mask
+            pl.BlockSpec((1, block_q, D), q_side),                        # do
+            pl.BlockSpec((1, block_q, 1), q_side),                        # lse
+            pl.BlockSpec((1, block_q, 1), q_side),                        # delta
         ],
         out_specs=[
-            pl.BlockSpec((1, block_k, D), lambda b, kb, qb: (b, kb, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, kb, qb: (b, kb, 0)),
+            pl.BlockSpec((1, block_k, D), lambda b, kb, t: (b, kb, 0)),
+            pl.BlockSpec((1, block_k, D), lambda b, kb, t: (b, kb, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, S, D), k.dtype),
-            jax.ShapeDtypeStruct((BH, S, D), v.dtype),
+            jax.ShapeDtypeStruct((BK, S, D), k.dtype),
+            jax.ShapeDtypeStruct((BK, S, D), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
+        name=scopes.KERNEL_FLASH_BWD_DKDV,
         **common,
     )(qr, kr, vr, maskr, dor, lser, delta)
 
@@ -373,9 +409,9 @@ def _pallas_backward(q, k, v, mask, causal, sm_scale, block_q, block_k,
         grid=(BH, num_qb, num_kb),
         in_specs=[
             qspec,                                                        # q
-            pl.BlockSpec((1, block_k, D), lambda b, i, kb: (b, kb, 0)),   # k
-            pl.BlockSpec((1, block_k, D), lambda b, i, kb: (b, kb, 0)),   # v
-            pl.BlockSpec((1, 1, block_k), lambda b, i, kb: (b, 0, kb)),   # mask
+            pl.BlockSpec((1, block_k, D), lambda b, i, kb: (b // G, kb, 0)),  # k
+            pl.BlockSpec((1, block_k, D), lambda b, i, kb: (b // G, kb, 0)),  # v
+            pl.BlockSpec((1, 1, block_k), lambda b, i, kb: (b // G, 0, kb)),  # mask
             qspec,                                                        # do
             pl.BlockSpec((1, block_q, 1), lambda b, i, kb: (b, i, 0)),    # lse
             pl.BlockSpec((1, block_q, 1), lambda b, i, kb: (b, i, 0)),    # delta
@@ -383,13 +419,14 @@ def _pallas_backward(q, k, v, mask, causal, sm_scale, block_q, block_k,
         out_specs=[qspec],
         out_shape=[jax.ShapeDtypeStruct((BH, Lq, D), q.dtype)],
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        name=scopes.KERNEL_FLASH_BWD_DQ,
         **common,
     )(qr, kr, vr, maskr, dor, lser, delta)
 
     return (
         dq.reshape(B, H, Lq, D),
-        dk.reshape(B, H, S, D),
-        dv.reshape(B, H, S, D),
+        dk.reshape(B, Hkv, S, D),
+        dv.reshape(B, Hkv, S, D),
     )
 
 
@@ -399,7 +436,8 @@ def _pallas_backward(q, k, v, mask, causal, sm_scale, block_q, block_k,
 def _blockwise_backward(q, k, v, mask, causal, sm_scale, block_k, o, lse, do):
     """Flash-style exact backward from the saved LSE; scans K blocks."""
     B, H, Lq, D = q.shape
-    S = k.shape[2]
+    Hkv, S = k.shape[1], k.shape[2]
+    k, v = _repeat_kv(k, H), _repeat_kv(v, H)
     nb = S // block_k
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)  # [B,H,L]
     qf = q.astype(jnp.float32)
@@ -428,6 +466,9 @@ def _blockwise_backward(q, k, v, mask, causal, sm_scale, block_k, o, lse, do):
     # scan stacks blocks on axis 0: [nb, B, H, block_k, D] -> [B, H, S, D]
     dk = jnp.moveaxis(dk_blocks, 0, 2).reshape(B, H, S, D)
     dv = jnp.moveaxis(dv_blocks, 0, 2).reshape(B, H, S, D)
+    if Hkv != H:  # a key/value head's gradient: the sum over its group
+        dk = dk.reshape(B, Hkv, H // Hkv, S, D).sum(axis=2)
+        dv = dv.reshape(B, Hkv, H // Hkv, S, D).sum(axis=2)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
@@ -443,7 +484,8 @@ def flash_attention(
 ):
     """Masked multi-head attention, O(L·block) memory.
 
-    q: [B, H, Lq, D]; k, v: [B, H, S, D]; mask: [B, S] bool (True = real).
+    q: [B, H, Lq, D]; k, v: [B, Hkv, S, D] with H a multiple of Hkv (grouped
+    queries; Hkv = H is plain multi-head); mask: [B, S] bool (True = real).
     Lq/S must be multiples of the block sizes (pad outside; padded KV rows
     are masked, padded Q rows produce zeros-safe outputs).
     """

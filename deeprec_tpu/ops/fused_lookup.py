@@ -73,18 +73,26 @@ _SMEM_INDEX_BYTES = 256 * 1024
 
 
 def _dma_ok(dim: int, dtype) -> bool:
-    """Single-row DMA eligibility: f32 tables with dim % 128 == 0 —
+    """Single-row DMA eligibility: f32 tables with dim == 128 —
     Mosaic requires HBM slices aligned to the tiling (measured on v5e:
     misaligned widths are a compile error, not a slowdown — dim 64 fails
     "must be aligned to tiling (128)"; bf16 tiles pack 2 sublanes per
     32-bit word so a dynamic single-row slice fails "index in dimension 0
-    is a multiple of 2"). bf16 tables with dim % 128 == 0 have their own
+    is a multiple of 2"). A row WIDER than one lane tile fails too (dim
+    256 and 2048, compiled for a described v5e: "Slice shape along
+    dimension 1 must be aligned to tiling (8), but is 1"): under the
+    (8, 128) HBM tiling one row of a [C, 128] table is one contiguous run
+    of a tile, one row of a [C, 2048] table is sixteen strided runs, and
+    Mosaic takes no such slice whatever VMEM buffer it lands in. Wide rows
+    take the XLA gather and scatter (8 KiB rows are what those are good
+    at); a Pallas path for them needs the table STORED as [C, D/128, 128]
+    (docs/kernels.md). bf16 tables with dim % 128 == 0 have their own
     PAIR-granule kernels (gather_rows / apply_rows_sr /
     fused_gather_combine route them via _dma_pair_ok); narrower tables
     take the XLA path (a D<128 row
     underfills even one DMA granule — beating XLA there needs a packed
     storage layout, not a better kernel; see docs/perf.md)."""
-    return dim % _LANES == 0 and jnp.dtype(dtype).itemsize == 4
+    return dim == _LANES and jnp.dtype(dtype).itemsize == 4
 
 
 def _dma_pair_ok(shape, dtype) -> bool:
@@ -148,6 +156,8 @@ def _row_reason(dim: int, dtype) -> str:
         return "not_tpu"
     if dim % _LANES != 0:
         return "dim_unaligned"
+    if dim != _LANES and jnp.dtype(dtype).itemsize == 4:
+        return "dim_wide"
     return "dtype"
 
 

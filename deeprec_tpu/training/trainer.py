@@ -616,6 +616,26 @@ class Trainer:
             return sum(losses.values()), out
         return M.bce_loss(out, batch["label"]), out
 
+    def _forward_loss(self, dense, embs, views, batch):
+        """(loss, the step's metrics besides the loss) of one batch. A
+        model with a `loss(params, inputs, batch)` of its own owns its loss
+        (and its remat: it knows its layers); every other model is scored
+        by `_loss_from_logits` on what its `apply` returns."""
+        inputs = self._build_inputs(embs, views, batch)
+        own = getattr(self.model, "loss", None)
+        if own is not None:
+            return own(dense, inputs, batch)
+        apply = (
+            jax.checkpoint(self.model.apply, static_argnums=(2,))
+            if self.remat
+            else self.model.apply
+        )
+        loss, out = self._loss_from_logits(apply(dense, inputs, True), batch)
+        if isinstance(out, dict):
+            return loss, {"accuracy": jnp.zeros(())}
+        return loss, {"accuracy": M.accuracy(jax.nn.sigmoid(out),
+                                             batch["label"])}
+
     def _micro_step(self, tables, dense, batch, step, lr):
         """Forward + backward + SPARSE applies for one (micro-)batch; returns
         updated tables, the dense-grad pytree (NOT applied) and metrics.
@@ -628,28 +648,12 @@ class Trainer:
                 tables, batch, step, True
             )
 
-        def loss_fn(dense, embs):
-            inputs = self._build_inputs(embs, views, batch)
-            apply = (
-                jax.checkpoint(self.model.apply, static_argnums=(2,))
-                if self.remat
-                else self.model.apply
-            )
-            out = apply(dense, inputs, True)
-            loss, out = self._loss_from_logits(out, batch)
-            return loss, out
-
         with scopes.scope(scopes.PHASE_DENSE_FWD_BWD):
             embs = {n: v[0].astype(jnp.float32) for n, v in views.items()}
-            (loss, out), (g_dense, g_embs) = jax.value_and_grad(
-                loss_fn, argnums=(0, 1), has_aux=True
-            )(dense, embs)
-            mets = {"loss": loss}
-            if not isinstance(out, dict):
-                probs = jax.nn.sigmoid(out)
-                mets["accuracy"] = M.accuracy(probs, batch["label"])
-            else:
-                mets["accuracy"] = jnp.zeros(())
+            (loss, mets), (g_dense, g_embs) = jax.value_and_grad(
+                self._forward_loss, argnums=(0, 1), has_aux=True
+            )(dense, embs, views, batch)
+            mets = {"loss": loss, **mets}
         with scopes.scope(scopes.PHASE_SPARSE_APPLY):
             tables = self._apply_all(tables, bundle_res, g_embs, step, lr)
         if self.sentinel is not None:
@@ -884,28 +888,12 @@ class Trainer:
         views = carry.views
         prev_batch = carry.batch
 
-        def loss_fn(dense, embs):
-            inputs = self._build_inputs(embs, views, prev_batch)
-            apply = (
-                jax.checkpoint(self.model.apply, static_argnums=(2,))
-                if self.remat
-                else self.model.apply
-            )
-            out = apply(dense, inputs, True)
-            loss, out = self._loss_from_logits(out, prev_batch)
-            return loss, out
-
         with scopes.scope(scopes.PHASE_DENSE_FWD_BWD):
             embs = {n: v[0].astype(jnp.float32) for n, v in views.items()}
-            (loss, out), (g_dense, g_embs) = jax.value_and_grad(
-                loss_fn, argnums=(0, 1), has_aux=True
-            )(state.dense, embs)
-            mets = {"loss": loss}
-            if not isinstance(out, dict):
-                probs = jax.nn.sigmoid(out)
-                mets["accuracy"] = M.accuracy(probs, prev_batch["label"])
-            else:
-                mets["accuracy"] = jnp.zeros(())
+            (loss, mets), (g_dense, g_embs) = jax.value_and_grad(
+                self._forward_loss, argnums=(0, 1), has_aux=True
+            )(state.dense, embs, views, prev_batch)
+            mets = {"loss": loss, **mets}
         with scopes.scope(scopes.PHASE_SPARSE_APPLY):
             tables = self._apply_all(tables, carry.bundle_res, g_embs, step, lr)
         guard = carry.guard

@@ -4,7 +4,7 @@ spans on the host, written where the work happens.
 Device scopes are `jax.named_scope`s. A scope is metadata of the compiled
 program (the `op_name` of every HLO instruction traced under it, which a
 device trace carries beside the instruction's time) and costs nothing at
-run time. Three kinds nest, as written:
+run time. Three kinds nest in every step, as written:
 
   * `phase_*`  — what the trainers' step is made of; every instruction of
     a train step stands under exactly one outermost phase;
@@ -12,6 +12,14 @@ run time. Three kinds nest, as written:
     engine (route, probe, insert, gather);
   * `rows_*`   — tight round a row read or a row write, so that under it
     everything that is not the row kernel itself is wrapper.
+
+A token model's stack (models/hybrid_stack.py) names two more inside
+`phase_dense_fwd_bwd`: `block_*`, the parts of a layer (the two mixers, the
+expert block, the head with its loss), and inside a block the part a
+roofline is read for (`gdn_rule`, tight round the delta rule as `rows_*`
+stands round a row kernel; `moe_dispatch`, `moe_experts`). They are two
+groups so that a block's time holds its parts' (a reader picks the
+innermost name of EACH group).
 
 jax wraps a scope's name in the transforms it is traced under
 (`vmap(engine_probe)`, `transpose(jvp(phase_dense_fwd_bwd))`); a reader
@@ -61,6 +69,25 @@ ROWS_GATHER = "rows_gather"
 ROWS_SCATTER = "rows_scatter"
 ROWS = (ROWS_GATHER, ROWS_SCATTER)
 
+# The token model's layer parts, and what a roofline is read for inside them.
+BLOCK_GDN = "block_gdn"
+BLOCK_ATTN = "block_attn"
+BLOCK_MOE = "block_moe"
+BLOCK_HEAD_LOSS = "block_head_loss"
+BLOCKS = (BLOCK_GDN, BLOCK_ATTN, BLOCK_MOE, BLOCK_HEAD_LOSS)
+GDN_RULE = "gdn_rule"
+MOE_DISPATCH = "moe_dispatch"
+MOE_EXPERTS = "moe_experts"
+BLOCK_PARTS = (GDN_RULE, MOE_DISPATCH, MOE_EXPERTS)
+
+# What a layer's remat keeps instead of making again (`checkpoint_name`s; no
+# trace shows them): the experts each token chose and the rows they were
+# dispatched to (a few int32 arrays a layer), the delta rule's states at its
+# segments' starts (8 MB a layer at 32 heads of 128 x 128 and four segments).
+KEPT_MOE_ROUTE = "kept_moe_route"
+KEPT_GDN_STATES = "kept_gdn_states"
+REMAT_KEPT = (KEPT_MOE_ROUTE, KEPT_GDN_STATES)
+
 # The sharded exchange's scopes, nested under the phase that issues them.
 # They keep the names they had (`phase_` + what parallel/sharded.py called
 # them); no metric reads them until a multi-chip cell exists.
@@ -98,6 +125,18 @@ KERNELS = (
     KERNEL_GATHER_ROWS, KERNEL_GATHER_ROWS_PAIR, KERNEL_APPLY_ROWS_SR,
     KERNEL_APPLY_ROWS_SR_PAIR, KERNEL_FUSED_GATHER_COMBINE,
     KERNEL_FUSED_SPARSE_FORWARD, KERNEL_FUSED_SPARSE_BACKWARD,
+)
+# The `name=` of the dense side's Pallas kernels (ops/flash_attention.py,
+# ops/moe.py).
+KERNEL_FLASH_FWD = "flash_attention_fwd"
+KERNEL_FLASH_BWD_DKDV = "flash_attention_bwd_dkdv"
+KERNEL_FLASH_BWD_DQ = "flash_attention_bwd_dq"
+KERNEL_GROUPED_MATMUL = "grouped_matmul"
+KERNEL_GROUPED_MATMUL_DX = "grouped_matmul_dx"
+KERNEL_GROUPED_MATMUL_DW = "grouped_matmul_dw"
+DENSE_KERNELS = (
+    KERNEL_FLASH_FWD, KERNEL_FLASH_BWD_DKDV, KERNEL_FLASH_BWD_DQ,
+    KERNEL_GROUPED_MATMUL, KERNEL_GROUPED_MATMUL_DX, KERNEL_GROUPED_MATMUL_DW,
 )
 
 # -------------------------------------------------------------- host spans
@@ -151,9 +190,15 @@ def step_span(n: int):
 
 
 def vocabulary() -> dict:
-    """The names as data; benchmark/phases.json holds the same."""
+    """The names as data; benchmark/phases.json and benchmark/phases/*.json
+    hold the same (benchmark/phase_reduce.py::load_vocabulary merges them)."""
     return {
         "phases": list(PHASES), "stages": list(STAGES), "rows": list(ROWS),
-        "exchange": list(EXCHANGE), "kernels": list(KERNELS),
+        "exchange": list(EXCHANGE),
+        "kernels": list(KERNELS + DENSE_KERNELS),
         "step_span": TRAIN_STEP, "host_spans": list(HOST_SPANS),
+        "groups": {
+            "block": {"pick": "innermost", "names": list(BLOCKS)},
+            "block_part": {"pick": "innermost", "names": list(BLOCK_PARTS)},
+        },
     }

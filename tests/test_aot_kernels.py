@@ -96,3 +96,87 @@ def test_mosaic_refuses_indices_past_a_cores_smem(one_chip, monkeypatch):
     _compile(one_chip, monkeypatch, 10000)
     with pytest.raises(Exception, match="prefetched SMEM operand"):
         _compile(one_chip, monkeypatch, 12000)
+
+
+# ------------------------------------ the token stack's kernels, real widths
+
+
+def _sd(one_chip):
+    return lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                  sharding=one_chip)
+
+
+def test_flash_kernels_compile_at_grouped_queries_and_head_dim_256(
+        one_chip, monkeypatch):
+    """16 query heads over 2 key/value heads, head dim 256, L = 8192,
+    causal, bf16 operands, blocks of 512: forward and both backward
+    kernels, and no copy of k or v repeated to the query heads."""
+    from deeprec_tpu.ops.flash_attention import flash_attention
+    from deeprec_tpu.utils import scopes
+
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    sd = _sd(one_chip)
+
+    def step(q, k, v, mask):
+        o, vjp = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, mask, True, 256 ** -0.5, 512, 512), q, k, v)
+        return o, vjp(o)
+
+    compiled = jax.jit(step).lower(
+        sd((1, 16, 8192, 256), jnp.bfloat16),
+        sd((1, 2, 8192, 256), jnp.bfloat16),
+        sd((1, 2, 8192, 256), jnp.bfloat16), sd((1, 8192), jnp.bool_)
+    ).compile()
+    hlo = compiled.as_text()
+    for name in (scopes.KERNEL_FLASH_FWD, scopes.KERNEL_FLASH_BWD_DKDV,
+                 scopes.KERNEL_FLASH_BWD_DQ):
+        assert name in hlo, name
+    assert "bf16[1,16,8192,256]" in hlo
+    assert "bf16[16,8192,256]{2,1,0} broadcast" not in hlo
+
+
+def test_grouped_products_compile_at_the_cells_widths(one_chip, monkeypatch):
+    """The expert layer's three kernels at 32 held experts of width 512 on
+    a hidden size of 2048 and a budget of 24,576 pairs: both products'
+    shapes, forward and backward."""
+    from deeprec_tpu.ops import moe
+
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    sd = _sd(one_chip)
+    held, block = 32, 128
+    nb = moe.num_blocks(24576, held, block)
+    for K, N in ((2048, 512), (512, 2048)):
+        def step(x, w, block_expert):
+            y, vjp = jax.vjp(lambda x, w: moe.grouped_matmul(
+                x, w, block_expert, block), x, w)
+            return y, vjp(y)
+
+        compiled = jax.jit(step).lower(
+            sd((nb * block, K), jnp.bfloat16), sd((held, K, N), jnp.float32),
+            sd((nb,), jnp.int32)).compile()
+        assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_a_row_wider_than_a_lane_tile_takes_the_xla_path(one_chip,
+                                                         monkeypatch):
+    """Mosaic takes no single-row DMA of a [C, 2048] table (its refusal is
+    pinned here), so the funnels must not ask for one: at dim 2048 the
+    gather and the scatter compile, without a Pallas call."""
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    sd = _sd(one_chip)
+    c, d, n = 1 << 16, 2048, 2544
+
+    def funnels(vals, ix, rows):
+        return (packed.gather_rows_any(vals, jnp.maximum(ix, 0), c,
+                                       use_pallas=True),
+                packed.scatter_rows_any(vals, ix, rows, c, use_pallas=True))
+
+    hlo = jax.jit(funnels, donate_argnums=0).lower(
+        sd((c, d), jnp.float32), sd((n,), jnp.int32),
+        sd((n, d), jnp.float32)).compile().as_text()
+    assert "tpu_custom_call" not in hlo
+    assert not fl._dma_ok(d, jnp.float32) and fl._dma_ok(128, jnp.float32)
+    with pytest.raises(Exception, match="aligned to tiling"):
+        jax.jit(lambda v, i: fl._gather_rows_op(8, False)(
+            v[None], i[None])).lower(
+            sd((c, d), jnp.float32), sd((n,), jnp.int32)).compile()

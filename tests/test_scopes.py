@@ -1,6 +1,7 @@
 """The vocabulary of trace names (utils/scopes.py): what reaches the compiled
 step's `op_name`s, what reaches the host plane of a trace, and that it is
-the one the benchmark's reader holds (benchmark/phases.json)."""
+the one the benchmark's reader holds (benchmark/phases.json merged with
+every benchmark/phases/*.json)."""
 import glob
 import json
 import os
@@ -76,8 +77,9 @@ def assert_every_instruction_has_a_phase(names):
 
 
 def test_names_are_plain_and_distinct():
-    names = [n for group in VOCAB.values()
+    names = [n for key, group in VOCAB.items() if key != "groups"
              for n in ([group] if isinstance(group, str) else group)]
+    names += [n for group in VOCAB["groups"].values() for n in group["names"]]
     names += [scopes.exchange_chunk(3), scopes.hier_intra_chunk(0),
               scopes.hier_inter_chunk(12)]
     assert len(set(names)) == len(names)
@@ -86,24 +88,34 @@ def test_names_are_plain_and_distinct():
     assert all(n.startswith("phase_") for n in scopes.PHASES)
     assert all(n.startswith("engine_") for n in scopes.STAGES)
     assert all(n.startswith("rows_") for n in scopes.ROWS)
+    assert all(n.startswith("block_") for n in scopes.BLOCKS)
     assert all(n.startswith("deeprec.") for n in scopes.HOST_SPANS)
 
 
 def test_the_benchmark_holds_the_same_vocabulary():
+    """The union of benchmark/phases.json and benchmark/phases/*.json (a
+    later scope is a new file there; phases.json itself stays as it is) is
+    what the program writes."""
+    merged = phase_reduce.load_vocabulary()
+    assert merged == VOCAB
+    # the first file alone is the engine's and the trainers' part of it
     with open(os.path.join(ROOT, "benchmark", "phases.json")) as f:
-        data = json.load(f)
-    data.pop("note")
-    reads = data.pop("reads")
-    assert data == VOCAB
-    # what each per-layer metric reads is a name of the vocabulary, and a
-    # metric of the manifest (BENCHMARK.json) with a reader of its own
+        first = json.load(f)
+    for key in ("phases", "stages", "rows", "exchange", "host_spans"):
+        assert first[key] == VOCAB[key], key
+    assert first["kernels"] == list(scopes.KERNELS)
+    # what each per-layer metric reads by scope is a name of the vocabulary,
+    # and a metric of the manifest (BENCHMARK.json) with a reader of its own
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         listed = {m["name"] for m in json.load(f)["per_layer"]}
-    for metric, what in reads.items():
+    groups = {g.name: g.names for g in phase_reduce.groups_of(VOCAB)}
+    every = set().union(*groups.values())
+    for metric, what in phase_reduce.metric_reads().items():
         (kind, name), = what.items()
         assert name in {"stage": scopes.STAGES, "loop": scopes.STAGES,
                         "phase": scopes.PHASES + (phase_reduce.UNPHASED,),
-                        "rows": ("wrapper", "kernel"),
+                        "rows": ("wrapper", "kernel"), "scope": every,
+                        "kernel": groups["kernel"],
                         "span": (scopes.TRAIN_STEP,)}[kind], metric
         assert metric in listed
         assert os.path.exists(os.path.join(
@@ -156,6 +168,43 @@ def test_train_step_names_every_phase_stage_and_row_funnel():
         phase_reduce.scope_of(n, VOCAB).phase == scopes.PHASE_DENSE_FWD_BWD
         for n in back)
     assert any(f"transpose({scopes.PHASE_DENSE_FWD_BWD})" in n for n in back)
+
+
+def test_the_token_stack_names_its_blocks_and_parts():
+    """Every operation of the hybrid stack's dense forward and backward
+    stands under one of the `block_*` scopes (what is left is the residual
+    adds and the embedding's cast), the delta rule and the expert layer's
+    parts under theirs, through remat and the backward's wrappers."""
+    from deeprec_tpu.models import HybridStackLM
+
+    m = HybridStackLM(
+        vocab=48, seq_len=32, capacity=128, pair_budget=256, hidden=32,
+        gdn_key_heads=2, gdn_value_heads=4, gdn_key_dim=8, gdn_value_dim=8,
+        attn_heads=4, attn_kv_heads=2, head_dim=16, num_experts=16,
+        experts_per_token=4, expert_width=16, shared_expert_width=16,
+        held_experts=(4, 4), chunk=8, segment=16, flash_block=16,
+        moe_block=8, loss_block=16)
+    tr = Trainer(m, Adagrad(lr=0.05), optax.adam(1e-3), unique_budget=40)
+    state = tr.init(0)
+    tok = jnp.arange(2 * 33, dtype=jnp.int32).reshape(2, 33) % 48
+    batch = {"tok": tok[:, :-1], "label": tok[:, 1:]}
+    names = op_names(tr._train_step.lower(state, batch, None).compile())
+    assert_every_instruction_has_a_phase(names)
+    got = [phase_reduce.scope_of(n, VOCAB) for _, n in names]
+    assert {s.block for s in got} - {""} == set(scopes.BLOCKS)
+    assert {s.block_part for s in got} - {""} == set(scopes.BLOCK_PARTS)
+    # a part stands inside its block
+    inside = {"gdn_rule": "block_gdn", "moe_dispatch": "block_moe",
+              "moe_experts": "block_moe"}
+    assert all(s.block == inside[s.block_part] for s in got if s.block_part)
+    dense = [(op, s) for (op, _), s in zip(names, got)
+             if s.phase == scopes.PHASE_DENSE_FWD_BWD and op not in FREE]
+    bare = [op for op, s in dense if not s.block]
+    assert len(bare) < 0.1 * len(dense), (len(bare), len(dense))
+    # the backward of every block is named too
+    for block in scopes.BLOCKS:
+        assert any(s.block == block and "transpose(" in n
+                   for (_, n), s in zip(names, got)), block
 
 
 def test_a_read_only_lookup_shows_no_insert():
