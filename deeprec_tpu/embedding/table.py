@@ -453,7 +453,7 @@ class EmbeddingTable:
         uids: jnp.ndarray,
         want_create: jnp.ndarray,
     ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-        """Vectorized open-addressing lookup-or-create.
+        """Vectorized open-addressing lookup-or-create: find, then claim.
 
         Args:
           keys: [C] current key array.
@@ -462,49 +462,78 @@ class EmbeddingTable:
 
         Returns: (new_keys, slot_ix [U] (-1 = not found/placed), created [U],
         failed [U]).
+
+        Two loops. The FIND loop writes nothing and carries [U]-sized
+        arrays only (under the table vmap its per-pass selects are [T, U],
+        never [T, C]): every id walks its chain, one gather a pass, to its
+        own key or to the first empty slot. That settles residency exactly:
+        linear probing without tombstones (rebuild re-hashes) keeps a
+        resident key before the first empty slot of its chain, and uids are
+        unique within a call, so nothing another id claims later can be
+        this id's key. The CLAIM loop starts every absent, creatable id at
+        the empty slot the find loop left it at and races them (scatter,
+        re-gather, the winner keeps the slot, losers move on); with nothing
+        to create it runs no pass. `max_probes` bounds the slots an id sees
+        over both loops together.
         """
         cfg = self.cfg
         C = keys.shape[0]
         mask_c = jnp.uint32(C - 1)
         h = hashing.mix32(hashing.fold64(uids))
         sentinel = jnp.asarray(empty_key(cfg), keys.dtype)
-        valid = uids != sentinel
 
-        slot_ix0 = jnp.full(uids.shape, -1, jnp.int32)
-        created0 = jnp.zeros(uids.shape, bool)
-        pending0 = valid
+        def position(off):
+            return ((h + off.astype(jnp.uint32)) & mask_c).astype(jnp.int32)
 
-        def cond(carry):
+        def find_cond(carry):
             step, pending, *_ = carry
             return jnp.logical_and(step < cfg.max_probes, jnp.any(pending))
 
-        def body(carry):
-            step, pending, slot_ix, created, keys = carry
-            pos = ((h + jnp.uint32(step)) & mask_c).astype(jnp.int32)  # [U]
+        def find_body(carry):
+            step, pending, slot_ix, empty_at = carry
+            pos = position(step)  # [U]
             k = keys[pos]
             found = pending & (k == uids)
             slot_ix = jnp.where(found, pos, slot_ix)
-            pending = pending & ~found
-            is_empty = k == sentinel
-            want = pending & is_empty & want_create
+            # the first empty slot of the chain: the key is definitively
+            # absent, and this is where a creatable id starts its claim
+            at_empty = pending & (k == sentinel)
+            empty_at = jnp.where(at_empty, step, empty_at)
+            return step + 1, pending & ~(found | at_empty), slot_ix, empty_at
+
+        def claim_cond(carry):
+            pending, *_ = carry
+            return jnp.any(pending)
+
+        def claim_body(carry):
+            pending, off, slot_ix, keys = carry
+            pos = position(off)
+            want = pending & (keys[pos] == sentinel)
             # Claim race: scatter all claimants; duplicates resolve to one
             # winner, which the re-gather below reveals. Losers keep probing.
             claim_pos = jnp.where(want, pos, C)  # C = out of bounds -> dropped
             keys = keys.at[claim_pos].set(uids, mode="drop")
             won = want & (keys[pos] == uids)
             slot_ix = jnp.where(won, pos, slot_ix)
-            created = created | won
-            pending = pending & ~won
-            # ids at a *non*-creatable empty slot stop probing: the key is
-            # definitively absent (linear probing invariant).
-            give_up = pending & is_empty & ~want_create
-            pending = pending & ~give_up
-            return step + 1, pending, slot_ix, created, keys
+            off = off + 1
+            pending = pending & ~won & (off < cfg.max_probes)
+            return pending, off, slot_ix, keys
 
-        step, pending, slot_ix, created, keys = jax.lax.while_loop(
-            cond, body, (jnp.int32(0), pending0, slot_ix0, created0, keys)
-        )
-        failed = pending  # ran out of probes: table (region) is full
+        none = jnp.full(uids.shape, -1, jnp.int32)
+        with scopes.scope(scopes.PROBE_FIND):
+            _, unresolved, slot_ix, empty_at = jax.lax.while_loop(
+                find_cond, find_body,
+                (jnp.int32(0), uids != sentinel, none, none),
+            )
+        with scopes.scope(scopes.PROBE_CLAIM):
+            # absent and not creatable: given up (slot_ix -1, not failed)
+            claiming = (empty_at >= 0) & want_create
+            _, _, slot_ix, keys = jax.lax.while_loop(
+                claim_cond, claim_body, (claiming, empty_at, slot_ix, keys)
+            )
+        created = claiming & (slot_ix >= 0)
+        # ran out of probes, finding or claiming: table (region) is full
+        failed = unresolved | (claiming & ~created)
         return keys, slot_ix, created, failed
 
     # ----------------------------------------------------------------- lookup

@@ -9,7 +9,7 @@ zipf-skewed recsys batches that is a multi-x waste (docs/perf.md charges
 
 This module replaces the sort with the same vectorized open-addressing
 claim-race probe the embedding table already uses for its own slots
-(`EmbeddingTable._probe`): every position gathers its scratch-slot
+(the claim loop of `EmbeddingTable._probe`): every position gathers its scratch-slot
 candidate, first-comers claim empty slots via a batched scatter, losers of
 a claim race advance one probe offset. The loop is a `lax.while_loop` of
 pure gathers/scatters — O(N · expected-probes) with expected-probes ~1-2

@@ -13,6 +13,10 @@ run time. Three kinds nest in every step, as written:
   * `rows_*`   — tight round a row read or a row write, so that under it
     everything that is not the row kernel itself is wrapper.
 
+Inside `engine_probe` the probe's two loops stand under `probe_find` and
+`probe_claim` (a group of their own, so that the stage's time and passes
+hold both loops').
+
 A token model's stack (models/hybrid_stack.py) names two more inside
 `phase_dense_fwd_bwd`: `block_*`, the parts of a layer (the two mixers, the
 expert block, the head with its loss), and inside a block the part a
@@ -64,6 +68,13 @@ ENGINE_PROBE = "engine_probe"
 ENGINE_INSERT = "engine_insert"
 ENGINE_GATHER = "engine_gather"
 STAGES = (ENGINE_ROUTE, ENGINE_PROBE, ENGINE_INSERT, ENGINE_GATHER)
+
+# The two loops of `engine_probe` (EmbeddingTable._probe): the read-only walk
+# to an id's key or its chain's first empty slot, and the race for the empty
+# slots, which runs no pass when no row is to be created.
+PROBE_FIND = "probe_find"
+PROBE_CLAIM = "probe_claim"
+PROBE_PARTS = (PROBE_FIND, PROBE_CLAIM)
 
 ROWS_GATHER = "rows_gather"
 ROWS_SCATTER = "rows_scatter"
@@ -200,5 +211,6 @@ def vocabulary() -> dict:
         "groups": {
             "block": {"pick": "innermost", "names": list(BLOCKS)},
             "block_part": {"pick": "innermost", "names": list(BLOCK_PARTS)},
+            "probe_part": {"pick": "innermost", "names": list(PROBE_PARTS)},
         },
     }

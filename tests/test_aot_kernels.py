@@ -180,3 +180,57 @@ def test_a_row_wider_than_a_lane_tile_takes_the_xla_path(one_chip,
         jax.jit(lambda v, i: fl._gather_rows_op(8, False)(
             v[None], i[None])).lower(
             sd((c, d), jnp.float32), sd((n,), jnp.int32)).compile()
+
+
+# ------------------------------------------ the probe's two loops, real shape
+
+
+def _body_of(hlo, loop):
+    """Every instruction the body of the `while` under the scope `loop`
+    runs, through the fusions and reductions it calls: [(result, opcode)]
+    (the structure by the benchmark's own reader of HLO text)."""
+    from benchmark import trace_reduce
+
+    instrs = trace_reduce.parse_hlo(hlo)
+    (todo,) = [[i["body"]] for i in instrs.values() if i["op"] == "while"
+               and i["op_name"].endswith(f"/{loop}/while")]
+    inside = set()
+    while todo:
+        name = todo.pop()
+        if name not in inside:
+            inside.add(name)
+            todo += [c for i in instrs.values() if i["computation"] == name
+                     for c in i["calls"]]
+    lines = {m.group(1): m.group(2) for m in map(
+        trace_reduce._INSTR.match, hlo.splitlines()) if m}
+    # what stands before an instruction's opcode is its result's type
+    return [(lines[n].partition(f" {i['op']}(")[0], i["op"])
+            for n, i in instrs.items() if i["computation"] in inside]
+
+
+@pytest.mark.parametrize("n", [2304, 8200])   # `.zipf`, `.uniform`: U + 8
+def test_the_find_loop_gathers_once_a_pass_and_moves_no_keys(
+        one_chip, monkeypatch, n):
+    """`jax.vmap(EmbeddingTable._probe)` over the bundle's 26 key arrays:
+    the body of the find loop holds a gather and `[26, n]` elementwise
+    work, with no sort, no scatter and nothing that makes a value the key
+    arrays' size (the loop passes the keys through untouched; the `select`
+    that `vmap` makes of a `while` whose predicate differs by table is over
+    the carry, and the keys are no carry). The claim loop, which runs no
+    pass when no row is to be created, is where those stand."""
+    from deeprec_tpu import EmbeddingTable, TableConfig
+
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    sd = _sd(one_chip)
+    table = EmbeddingTable(TableConfig(name="t", dim=D, capacity=C))
+    hlo = jax.jit(jax.vmap(table._probe), donate_argnums=0).lower(
+        sd((T, C), jnp.int32), sd((T, n), jnp.int32),
+        sd((T, n), jnp.bool_)).compile().as_text()
+    passed_through = {"parameter", "get-tuple-element", "tuple"}
+    find = _body_of(hlo, "probe_find")
+    ops = {op for _, op in find}
+    assert "gather" in ops and not {"sort", "scatter"} & ops, ops
+    assert not [(r, op) for r, op in find if op not in passed_through
+                and f"s32[{T},{C}]" in r]
+    claim = {op for _, op in _body_of(hlo, "probe_claim")}
+    assert {"sort", "scatter"} & claim, claim

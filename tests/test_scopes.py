@@ -112,7 +112,8 @@ def test_the_benchmark_holds_the_same_vocabulary():
     every = set().union(*groups.values())
     for metric, what in phase_reduce.metric_reads().items():
         (kind, name), = what.items()
-        assert name in {"stage": scopes.STAGES, "loop": scopes.STAGES,
+        assert name in {"stage": scopes.STAGES,
+                        "loop": scopes.STAGES + scopes.PROBE_PARTS,
                         "phase": scopes.PHASES + (phase_reduce.UNPHASED,),
                         "rows": ("wrapper", "kernel"), "scope": every,
                         "kernel": groups["kernel"],
@@ -159,6 +160,11 @@ def test_train_step_names_every_phase_stage_and_row_funnel():
     assert any("/while/body/" in n for n in probe)
     assert all(phase_reduce.scope_of(n, VOCAB).stage == scopes.ENGINE_PROBE
                for n in probe)
+    # the probe's two loops, each `while` under its own name inside the
+    # stage's: the find loop first, the claim loop after it
+    loops = [phase_reduce.scope_of(n, VOCAB).probe_part
+             for op, n in names if op == "while" and scopes.ENGINE_PROBE in n]
+    assert loops == list(scopes.PROBE_PARTS)
     # the optimizer's slot rows take the same two funnels, under the apply
     assert f"/{scopes.PHASE_SPARSE_APPLY}/vmap({scopes.ROWS_SCATTER})/" in text
     assert f"/{scopes.PHASE_SPARSE_APPLY}/vmap({scopes.ROWS_GATHER})/" in text
