@@ -28,15 +28,10 @@ Semantics (documented staleness, matching the reference):
 """
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
-import optax
-from jax.sharding import PartitionSpec as P
 
 from deeprec_tpu.parallel.trainer import ShardedTrainer
-from deeprec_tpu.training import metrics as M
 from deeprec_tpu.training.trainer import PipelineCarry, TrainState
 from deeprec_tpu.utils import scopes
 
@@ -63,9 +58,10 @@ class AsyncShardedTrainer(ShardedTrainer):
 
     def _make_jits(self):
         super()._make_jits()
-        self._bootstrap_jit = jax.jit(self._bootstrap_impl)  # noqa: DRT001 — deliberate rebuild-on-budget/plan-change; one wrapper serves all steps
-        self._async_step = jax.jit(self._async_impl, donate_argnums=0)  # noqa: DRT001 — deliberate rebuild-on-budget/plan-change; one wrapper serves all steps
-        self._async_steps = jax.jit(self._async_steps_impl, donate_argnums=0)  # noqa: DRT001 — deliberate rebuild-on-budget/plan-change; one wrapper serves all steps
+        on = self._on_mesh
+        self._bootstrap_jit = jax.jit(on(self._bootstrap_body, fills_carry=True))  # noqa: DRT001 — deliberate rebuild-on-budget/plan-change; one wrapper serves all steps
+        self._async_step = jax.jit(on(self._async_body), donate_argnums=0)  # noqa: DRT001 — deliberate rebuild-on-budget/plan-change; one wrapper serves all steps
+        self._async_steps = jax.jit(on(self._async_scan, stacked=True), donate_argnums=0)  # noqa: DRT001 — deliberate rebuild-on-budget/plan-change; one wrapper serves all steps
 
     def _apply_one(self, b, state, res, grad, step, lr):
         # The stale-by-one apply consumes batch t-1's lookup result AFTER
@@ -86,49 +82,15 @@ class AsyncShardedTrainer(ShardedTrainer):
     def bootstrap(self, state: TrainState, first_batch) -> AsyncState:
         """Fill the pipeline: lookup/exchange first_batch with no dense
         compute. The first train_step_async then consumes it."""
-        return self._bootstrap_jit(state, first_batch)
+        return self._bootstrap_jit(state, first_batch)[0]
 
-    def _bootstrap_impl(self, state: TrainState, batch):
-        state_spec, batch_spec = self._specs_for(state, batch)
-        views_spec, res_spec, _ = self._carry_specs()
-
-        @partial(
-            jax.shard_map,
-            mesh=self.mesh,
-            in_specs=(state_spec, batch_spec),
-            out_specs=(state_spec, views_spec, res_spec),
-            check_vma=False,
-        )
-        def run(state, batch):
-            tables = self._squeeze_all(state.tables)
-            # Split-phase lookup (route -> resolve -> finish) with
-            # keep_rows=False: the stale apply never reuses the forward
-            # residual (reuse_rows=False above), so the carried results
-            # drop the owner-side [O, D] row buffer instead of hauling it
-            # across dispatches and through the K-step scan carry.
-            with scopes.scope(scopes.PHASE_LOOKUP_EXCHANGE):
-                routes = self._route_all(batch, True)
-                tables, pending = self._resolve_all(
-                    tables, routes, state.step, True
-                )
-                views, bundle_res = self._finish_all(
-                    tables, pending, batch, True, keep_rows=False
-                )
-            new_state = TrainState(
-                step=state.step,
-                tables={
-                    bname: self._unsqueeze(bname, ts)
-                    for bname, ts in tables.items()
-                },
-                dense=state.dense,
-                opt_state=state.opt_state,
-            )
-            return new_state, views, bundle_res
-
-        new_state, views, bundle_res = run(state, batch)
-        return AsyncState(
-            inner=new_state, batch=batch, views=views, bundle_res=bundle_res
-        )
+    def _bootstrap_body(self, state: TrainState, batch):
+        """Per shard (inside `_on_mesh`): (the filled carry, no metrics).
+        keep_rows=False: the stale apply never reuses the forward residual
+        (reuse_rows=False above), so the carried results drop the
+        owner-side [O, D] row buffer instead of hauling it across
+        dispatches and through the K-step scan carry."""
+        return self._pipe_prologue(state, batch, keep_rows=False), {}
 
     # ------------------------------------------------------------- step
 
@@ -160,34 +122,15 @@ class AsyncShardedTrainer(ShardedTrainer):
             return self._async_steps(astate, batches, lr)
 
     def _async_body(self, astate: AsyncState, batch_t, lr):
-        """One async step on per-shard values (runs INSIDE shard_map).
+        """One async step on per-shard values (runs inside `_on_mesh`).
         Shared by the single-step path and the K-step scan."""
         state = astate.inner
         step = state.step
-        views = astate.views
-        prev_batch = astate.batch
 
         # (1) dense fwd/bwd on the STALE embeddings (batch t-1)
-        def loss_fn(dense, embs):
-            inputs = self._build_inputs(embs, views, prev_batch)
-            out = self.model.apply(dense, inputs, train=True)
-            loss, out = self._loss_from_logits(out, prev_batch)
-            return loss, out
-
-        with scopes.scope(scopes.PHASE_DENSE_FWD_BWD):
-            embs = {n: v[0].astype(jnp.float32) for n, v in views.items()}
-            (loss, out), (g_dense, g_embs) = jax.value_and_grad(
-                loss_fn, argnums=(0, 1), has_aux=True
-            )(state.dense, embs)
-            g_dense = jax.lax.pmean(g_dense, self.axis)
-            mets = {"loss": jax.lax.pmean(loss, self.axis)}
-            if not isinstance(out, dict):
-                probs = jax.nn.sigmoid(out)
-                mets["accuracy"] = jax.lax.pmean(
-                    M.accuracy(probs, prev_batch["label"]), self.axis
-                )
-            else:
-                mets["accuracy"] = jnp.zeros(())
+        g_dense, g_embs, mets = self._fwd_bwd(
+            state.dense, astate.views, astate.batch
+        )
 
         # (2) exchange/lookup for batch t — reads the step-start tables,
         # no data dependency on (1): XLA overlaps it with the matmuls.
@@ -195,8 +138,8 @@ class AsyncShardedTrainer(ShardedTrainer):
         # stale apply below (that pre-apply gather IS the documented
         # staleness — the exact pipelined scan moves it after the apply).
         # keep_rows=False: the carried results never reuse the residual.
-        tables = self._squeeze_all(state.tables)
-        with scopes.scope(scopes.PHASE_LOOKUP_EXCHANGE):
+        tables = self._tables_in(state.tables)
+        with scopes.scope(self._lookup_phase):
             routes_t = self._route_all(batch_t, True)
             tables, pending_t = self._resolve_all(
                 tables, routes_t, step, True
@@ -212,75 +155,21 @@ class AsyncShardedTrainer(ShardedTrainer):
             )
 
         # (4) dense update
-        with scopes.scope(scopes.PHASE_DENSE_APPLY):
-            updates, opt_state = self.dense_opt.update(
-                g_dense, state.opt_state, state.dense
-            )
-            dense = optax.apply_updates(state.dense, updates)
-            step = step + 1
-
-        new_inner = TrainState(
-            step=step,
-            tables={
-                bname: self._unsqueeze(bname, ts)
-                for bname, ts in tables.items()
-            },
-            dense=dense,
-            opt_state=opt_state,
-        )
         return (
-            AsyncState(inner=new_inner, batch=batch_t, views=views_t,
-                       bundle_res=res_t),
+            AsyncState(inner=self._dense_update(state, g_dense, tables),
+                       batch=batch_t, views=views_t, bundle_res=res_t),
             mets,
         )
 
-    def _astate_spec(self, state_spec):
-        views_spec, res_spec, prev_batch_spec = self._carry_specs()
-        return AsyncState(
-            inner=state_spec, batch=prev_batch_spec, views=views_spec,
-            bundle_res=res_spec,
-        )
-
-    def _async_impl(self, astate: AsyncState, batch_t, lr):
-        state_spec, batch_spec = self._specs_for(astate.inner, batch_t)
-        astate_spec = self._astate_spec(state_spec)
-        out_metric_spec = {"loss": P(), "accuracy": P()}
-
-        @partial(
-            jax.shard_map,
-            mesh=self.mesh,
-            in_specs=(astate_spec, batch_spec, P()),
-            out_specs=(astate_spec, out_metric_spec),
-            check_vma=False,
-        )
-        def run(astate, batch_t, lr):
-            return self._async_body(astate, batch_t, lr)
-
-        return run(astate, batch_t, lr)
-
-    def _async_steps_impl(self, astate: AsyncState, batches, lr):
+    def _async_scan(self, astate: AsyncState, batches, lr):
         """K async steps per dispatch: lax.scan of `_async_body` inside one
-        shard_map, threading the pipelined AsyncState (carried batch, views
-        and lookup results of step t-1) through the scan carry — the
+        mapped region, threading the pipelined AsyncState (carried batch,
+        views and lookup results of step t-1) through the scan carry — the
         stale-by-one semantics of every inner step are exactly those of K
         sequential `train_step_async` calls. Batches carry a leading
         unsharded [K] axis (`shard_batch(..., stacked=True)`)."""
-        state_spec, _ = self._specs_for(astate.inner, {})
-        astate_spec = self._astate_spec(state_spec)
-        batch_spec = jax.tree.map(lambda _: P(None, self.axis), batches)
-        out_metric_spec = {"loss": P(), "accuracy": P()}
 
-        @partial(
-            jax.shard_map,
-            mesh=self.mesh,
-            in_specs=(astate_spec, batch_spec, P()),
-            out_specs=(astate_spec, out_metric_spec),
-            check_vma=False,
-        )
-        def run(astate, batches, lr):
-            def body(astate, batch_t):
-                return self._async_body(astate, batch_t, lr)
+        def body(astate, batch_t):
+            return self._async_body(astate, batch_t, lr)
 
-            return jax.lax.scan(body, astate, batches)
-
-        return run(astate, batches, lr)
+        return jax.lax.scan(body, astate, batches)

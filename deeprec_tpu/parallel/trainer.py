@@ -19,7 +19,6 @@ distributed behavior with in-process fake clusters the same way (SURVEY §4).
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
 from typing import Dict, Optional
 
 import jax
@@ -33,8 +32,8 @@ from deeprec_tpu.parallel import placement as placement_lib
 from deeprec_tpu.parallel.mesh import DATA_AXIS
 from deeprec_tpu.parallel.placement import BundlePlan
 from deeprec_tpu.parallel.sharded import ShardedTable
-from deeprec_tpu.training import metrics as M
 from deeprec_tpu.training.trainer import (
+    PipelineCarry,
     Trainer,
     TrainState,
     stack_batches,
@@ -149,14 +148,6 @@ class ShardedTrainer(Trainer):
             for bname, b in self.bundles.items()
         }
 
-    def _make_jits(self):
-        # Called by Trainer.__init__ (before self.sharded exists — jit
-        # wrapping is lazy) and by update_budgets on a budget change.
-        self._train_step = jax.jit(self._sharded_step, donate_argnums=0)  # noqa: DRT001 — deliberate rebuild-on-budget/plan-change; one wrapper serves all steps
-        self._train_step_accum = jax.jit(self._sharded_accum, donate_argnums=0)  # noqa: DRT001 — deliberate rebuild-on-budget/plan-change; one wrapper serves all steps
-        self._train_steps = jax.jit(self._sharded_steps, donate_argnums=0)  # noqa: DRT001 — deliberate rebuild-on-budget/plan-change; one wrapper serves all steps
-        self._eval_step = jax.jit(self._sharded_eval)  # noqa: DRT001 — deliberate rebuild-on-budget/plan-change; one wrapper serves all steps
-
     def _stage_put(self, batch):
         # auto-stage (Trainer.stage) places batches with mesh sharding so
         # the staged transfer already lands split across devices
@@ -204,9 +195,8 @@ class ShardedTrainer(Trainer):
         b = self.bundles[bname]
         return P(None, self.axis) if b.stacked else P(self.axis)
 
-    def _specs_for(self, state: TrainState, batch):
-        ax = self.axis
-        state_spec = TrainState(
+    def _state_spec(self, state: TrainState) -> TrainState:
+        return TrainState(
             step=P(),
             tables={
                 bname: jax.tree.map(lambda _: self._table_spec(bname), ts)
@@ -215,23 +205,67 @@ class ShardedTrainer(Trainer):
             dense=jax.tree.map(lambda _: P(), state.dense),
             opt_state=jax.tree.map(lambda _: P(), state.opt_state),
         )
-        batch_spec = jax.tree.map(lambda _: P(ax), batch)
-        return state_spec, batch_spec
 
-    def _squeeze(self, bname, ts):
-        ax = 1 if self.bundles[bname].stacked else 0
-        return jax.tree.map(lambda a: jnp.squeeze(a, axis=ax), ts)
+    # The four hooks of the base trainer's step bodies (training/trainer.py):
+    # the bodies run per shard inside `_on_mesh`'s one mapped region.
 
-    def _squeeze_all(self, tables):
+    _lookup_phase = scopes.PHASE_LOOKUP_EXCHANGE
+
+    def _on_shard_axis(self, fn, tables):
+        """`fn(leaf, axis)` over every table leaf, at its shard axis
+        ([T?, N, C, ...]: right before capacity)."""
+        return {
+            bname: jax.tree.map(
+                lambda a, ax=1 if self.bundles[bname].stacked else 0: fn(a, ax),
+                ts,
+            )
+            for bname, ts in tables.items()
+        }
+
+    def _tables_in(self, tables):
         """Every bundle's per-shard view (the shard axis off): the first
         thing a step body does with the tables, so part of its lookup."""
         with scopes.scope(scopes.PHASE_LOOKUP_EXCHANGE):
-            return {bname: self._squeeze(bname, ts)
-                    for bname, ts in tables.items()}
+            return self._on_shard_axis(jnp.squeeze, tables)
 
-    def _unsqueeze(self, bname, ts):
-        ax = 1 if self.bundles[bname].stacked else 0
-        return jax.tree.map(lambda a: jnp.expand_dims(a, axis=ax), ts)
+    def _tables_out(self, tables):
+        return self._on_shard_axis(jnp.expand_dims, tables)
+
+    def _replica_mean(self, tree):
+        # Data-parallel dense grads and metrics: mean over replicas via
+        # ICI allreduce.
+        return jax.lax.pmean(tree, self.axis)
+
+    def _on_mesh(self, body, stacked=False, evaluate=False,
+                 fills_carry=False):
+        """`body` on per-shard values inside ONE mapped region over the
+        mesh: the state by `_state_spec`, the batch axis split (behind an
+        unsharded [K]/[A] axis when `stacked`), lr and the metrics
+        replicated (a prefix spec: a model-owned loss names its own metric
+        keys). A state that is a PipelineCarry crosses the boundary by
+        `_carry_spec` (parallel/async_stage.py); `fills_carry`: the body
+        takes a TrainState and returns such a carry."""
+        ax = self.axis
+        batch_spec = P(None, ax) if stacked else P(ax)
+
+        def run(state, batch, *lr):
+            carried = isinstance(state, PipelineCarry)
+            spec_in = self._state_spec(state.inner if carried else state)
+            spec_out = spec_in
+            if carried:
+                spec_in = spec_out = self._carry_spec(spec_in)
+            elif fills_carry:
+                spec_out = self._carry_spec(spec_in)
+            return jax.shard_map(
+                body,
+                mesh=self.mesh,
+                in_specs=(spec_in, batch_spec) + (P(),) * len(lr),
+                out_specs=(P(), P(ax)) if evaluate else (spec_out, P()),
+                check_vma=False,
+            )(state, batch, *lr)
+
+        run.__name__ = body.__name__  # the program is named for its body
+        return run
 
     def _evict_bundle(self, b, ts, step):
         # leading dims: [T?, N, C]; evict each shard's local table
@@ -286,21 +320,20 @@ class ShardedTrainer(Trainer):
             state, pending, train=train, keep_rows=keep_rows
         )
 
-    def _carry_specs(self):
-        """Prefix spec trees for a PipelineCarry's lookahead halves
-        (shard_map broadcasts a spec over a subtree): views/batch leaves
-        shard the leading local axis; stacked bundles carry their table
-        axis first. Used where a carry crosses the shard_map boundary —
-        the async stale-by-one stage (parallel/async_stage.py); the exact
-        pipelined scan keeps its carry inside one shard_map region."""
+    def _carry_spec(self, state_spec) -> PipelineCarry:
+        """Spec of a PipelineCarry that crosses the mapped region's
+        boundary — the async stale-by-one stage (parallel/async_stage.py);
+        the exact pipelined scan keeps its carry inside the region. Prefix
+        specs (broadcast over a subtree): views/batch leaves shard the
+        leading local axis; stacked bundles carry their table axis first."""
         ax = self.axis
-        views_spec = P(ax)
-        res_spec = {
-            bname: P(None, ax) if b.stacked else P(ax)
-            for bname, b in self.bundles.items()
-        }
-        batch_spec = P(ax)
-        return views_spec, res_spec, batch_spec
+        return PipelineCarry(
+            inner=state_spec, batch=P(ax), views=P(ax),
+            bundle_res={
+                bname: P(None, ax) if b.stacked else P(ax)
+                for bname, b in self.bundles.items()
+            },
+        )
 
     # --------------------------------------------------------- placement
 
@@ -909,310 +942,6 @@ class ShardedTrainer(Trainer):
 
     # ------------------------------------------------------------------ steps
 
-    def _sharded_micro(self, tables, dense, batch, step, lr):
-        """One (micro-)batch inside shard_map: lookups, fwd/bwd, sparse
-        applies; returns tables, pmean'd dense grads (unapplied), metrics."""
-        with scopes.scope(scopes.PHASE_LOOKUP_EXCHANGE):
-            tables, views, bundle_res = self._lookup_all(
-                tables, batch, step, True
-            )
-
-        def loss_fn(dense, embs):
-            inputs = self._build_inputs(embs, views, batch)
-            out = self.model.apply(dense, inputs, train=True)
-            loss, out = self._loss_from_logits(out, batch)
-            return loss, out
-
-        with scopes.scope(scopes.PHASE_DENSE_FWD_BWD):
-            embs = {n: v[0].astype(jnp.float32) for n, v in views.items()}
-            (loss, out), (g_dense, g_embs) = jax.value_and_grad(
-                loss_fn, argnums=(0, 1), has_aux=True
-            )(dense, embs)
-            # Data-parallel dense grads: mean over replicas via ICI
-            # allreduce.
-            g_dense = jax.lax.pmean(g_dense, self.axis)
-            mets = {"loss": jax.lax.pmean(loss, self.axis)}
-            if not isinstance(out, dict):
-                probs = jax.nn.sigmoid(out)
-                mets["accuracy"] = jax.lax.pmean(
-                    M.accuracy(probs, batch["label"]), self.axis
-                )
-            else:
-                mets["accuracy"] = jnp.zeros(())
-        with scopes.scope(scopes.PHASE_SPARSE_APPLY):
-            tables = self._apply_all(tables, bundle_res, g_embs, step, lr)
-        return tables, g_dense, mets
-
-    def _sharded_body(self, state: TrainState, batch, lr):
-        """One full train step on per-shard values (runs INSIDE shard_map):
-        squeeze the shard axis off the tables, micro-step, dense update,
-        re-wrap. Shared by the single-step path and the K-step scan."""
-        step = state.step
-        tables = self._squeeze_all(state.tables)
-        tables, g_dense, mets = self._sharded_micro(
-            tables, state.dense, batch, step, lr
-        )
-        with scopes.scope(scopes.PHASE_DENSE_APPLY):
-            updates, opt_state = self.dense_opt.update(
-                g_dense, state.opt_state, state.dense
-            )
-            dense = optax.apply_updates(state.dense, updates)
-            step = step + 1
-        new_state = TrainState(
-            step=step,
-            tables={
-                bname: self._unsqueeze(bname, ts)
-                for bname, ts in tables.items()
-            },
-            dense=dense,
-            opt_state=opt_state,
-        )
-        return new_state, mets
-
-    def _sharded_step(self, state: TrainState, batch, lr):
-        state_spec, batch_spec = self._specs_for(state, batch)
-        out_metric_spec = {"loss": P(), "accuracy": P()}
-
-        @partial(
-            jax.shard_map,
-            mesh=self.mesh,
-            in_specs=(state_spec, batch_spec, P()),
-            out_specs=(state_spec, out_metric_spec),
-            check_vma=False,
-        )
-        def run(state, batch, lr):
-            return self._sharded_body(state, batch, lr)
-
-        return run(state, batch, lr)
-
-    def _sharded_steps(self, state: TrainState, batches, lr):
-        """K-step device loop (Trainer._steps_impl mirror): one shard_map
-        whose body scans `_sharded_body` over the K-stacked batch — the
-        a2a/allgather exchange of every inner step stays inside the single
-        compiled program, so K steps cost one host dispatch. Batch leaves
-        are [K, B, ...] with the K axis unsharded and the batch axis split
-        over the mesh (`shard_batch(..., stacked=True)`).
-
-        pipeline_mode != "off" routes to the rotated scan
-        (`_sharded_steps_pipelined`): same semantics, bit-exact, with the
-        id exchange + owner probe of batch t+1 hoisted over batch t's
-        dense compute."""
-        if self.pipeline_mode != "off":
-            return self._sharded_steps_pipelined(state, batches, lr)
-        state_spec, _ = self._specs_for(state, {})
-        batch_spec = jax.tree.map(lambda _: P(None, self.axis), batches)
-        out_metric_spec = {"loss": P(), "accuracy": P()}
-
-        @partial(
-            jax.shard_map,
-            mesh=self.mesh,
-            in_specs=(state_spec, batch_spec, P()),
-            out_specs=(state_spec, out_metric_spec),
-            check_vma=False,
-        )
-        def run(state, batches, lr):
-            def body(state, batch):
-                return self._sharded_body(state, batch, lr)
-
-            return jax.lax.scan(body, state, batches)
-
-        return run(state, batches, lr)
-
-    # -------------------------------------------- pipelined K-step scan
-
-    def _sharded_pipe_prologue(self, state: TrainState, batch0):
-        """Fill the pipeline inside shard_map: split-phase lookup of the
-        window's first batch (same program as the sequential lookup)."""
-        from deeprec_tpu.training.trainer import PipelineCarry
-
-        tables = self._squeeze_all(state.tables)
-        with scopes.scope(scopes.PHASE_LOOKUP_EXCHANGE):
-            routes = self._route_all(batch0, True)
-            tables, pending = self._resolve_all(
-                tables, routes, state.step, True
-            )
-            views, res = self._finish_all(tables, pending, batch0, True)
-        new_state = TrainState(
-            step=state.step,
-            tables={
-                bname: self._unsqueeze(bname, ts)
-                for bname, ts in tables.items()
-            },
-            dense=state.dense,
-            opt_state=state.opt_state,
-        )
-        return PipelineCarry(inner=new_state, batch=batch0, views=views,
-                             bundle_res=res)
-
-    def _sharded_pipe_step(self, carry, batch_next, lr):
-        """One pipelined sharded step on per-shard values (inside
-        shard_map) — `Trainer._pipe_step` with the collective split
-        phases and pmean'd dense grads/metrics:
-
-          1. route(t+1): id dedup + id a2a/allgather + owner dedup —
-             ids-only, issued before the dense compute so the async
-             collective hides behind the matmuls. Under
-             pipeline_mode="nested" with comm="hier" this is where the
-             nesting lands: route contains BOTH tiers' id hops, so the
-             expensive inter-tier exchange of t+1 (phase
-             "hier_inter_ids") is issued a full dense fwd/bwd ahead and
-             its DCN latency hides behind t's intra-host work AND
-             matmuls;
-          2. resolve(t+1): owner probe/insert + fused metadata + init —
-             keys/meta only, commutes bit-exactly with apply(t);
-          3. dense fwd/bwd on the carried lookup of batch t;
-          4. grad exchange + sparse apply of batch t;
-          5. finish(t+1): owner value gather + embedding exchange, AFTER
-             the apply — batch t+1 sees post-apply tables, zero staleness.
-
-        batch_next=None: window epilogue, only `.inner` of the returned
-        carry is meaningful."""
-        from deeprec_tpu.training.trainer import PipelineCarry
-
-        state = carry.inner
-        step = state.step
-        tables = self._squeeze_all(state.tables)
-        if batch_next is not None:
-            with scopes.scope(scopes.PHASE_ROUTE_NEXT):
-                routes = self._route_all(batch_next, True)
-                tables, pending = self._resolve_all(
-                    tables, routes, step + 1, True
-                )
-        views = carry.views
-        prev_batch = carry.batch
-
-        def loss_fn(dense, embs):
-            inputs = self._build_inputs(embs, views, prev_batch)
-            out = self.model.apply(dense, inputs, train=True)
-            loss, out = self._loss_from_logits(out, prev_batch)
-            return loss, out
-
-        with scopes.scope(scopes.PHASE_DENSE_FWD_BWD):
-            embs = {n: v[0].astype(jnp.float32) for n, v in views.items()}
-            (loss, out), (g_dense, g_embs) = jax.value_and_grad(
-                loss_fn, argnums=(0, 1), has_aux=True
-            )(state.dense, embs)
-            g_dense = jax.lax.pmean(g_dense, self.axis)
-            mets = {"loss": jax.lax.pmean(loss, self.axis)}
-            if not isinstance(out, dict):
-                probs = jax.nn.sigmoid(out)
-                mets["accuracy"] = jax.lax.pmean(
-                    M.accuracy(probs, prev_batch["label"]), self.axis
-                )
-            else:
-                mets["accuracy"] = jnp.zeros(())
-        with scopes.scope(scopes.PHASE_SPARSE_APPLY):
-            tables = self._apply_all(tables, carry.bundle_res, g_embs, step, lr)
-        if batch_next is not None:
-            with scopes.scope(scopes.PHASE_FINISH_EXCHANGE):
-                views_n, res_n = self._finish_all(
-                    tables, pending, batch_next, True
-                )
-        else:
-            batch_next, views_n, res_n = prev_batch, views, carry.bundle_res
-        with scopes.scope(scopes.PHASE_DENSE_APPLY):
-            updates, opt_state = self.dense_opt.update(
-                g_dense, state.opt_state, state.dense
-            )
-            dense = optax.apply_updates(state.dense, updates)
-            step = step + 1
-        new_state = TrainState(
-            step=step,
-            tables={
-                bname: self._unsqueeze(bname, ts)
-                for bname, ts in tables.items()
-            },
-            dense=dense,
-            opt_state=opt_state,
-        )
-        return PipelineCarry(
-            inner=new_state, batch=batch_next, views=views_n,
-            bundle_res=res_n,
-        ), mets
-
-    def _sharded_steps_pipelined(self, state: TrainState, batches, lr):
-        """The rotated K-step scan: prologue lookup of batch 0, a scan
-        whose carry threads the one-batch lookahead (PipelineCarry — it
-        never crosses the shard_map boundary, so it needs no specs), and a
-        peeled epilogue for the last batch (which has nothing to
-        prefetch; peeling keeps the final table state bit-identical — a
-        masked dummy resolve would insert phantom keys)."""
-        state_spec, _ = self._specs_for(state, {})
-        batch_spec = jax.tree.map(lambda _: P(None, self.axis), batches)
-        out_metric_spec = {"loss": P(), "accuracy": P()}
-
-        @partial(
-            jax.shard_map,
-            mesh=self.mesh,
-            in_specs=(state_spec, batch_spec, P()),
-            out_specs=(state_spec, out_metric_spec),
-            check_vma=False,
-        )
-        def run(state, batches, lr):
-            batch0 = jax.tree.map(lambda x: x[0], batches)
-            rest = jax.tree.map(lambda x: x[1:], batches)
-            carry = self._sharded_pipe_prologue(state, batch0)
-
-            def body(carry, batch_next):
-                return self._sharded_pipe_step(carry, batch_next, lr)
-
-            carry, mets = jax.lax.scan(body, carry, rest)
-            carry, tail = self._sharded_pipe_step(carry, None, lr)
-            mets = jax.tree.map(
-                lambda a, b: jnp.concatenate([a, b[None]]), mets, tail
-            )
-            return carry.inner, mets
-
-        return run(state, batches, lr)
-
-    def _sharded_accum(self, state: TrainState, batch, lr):
-        """Micro-batched sharded step: batch leaves [A, B_local*N, ...] — the
-        accumulation axis is unsharded, the batch axis splits across the
-        mesh; lax.scan over micro-batches inside the shard_map."""
-        state_spec, _ = self._specs_for(state, {})
-        batch_spec = jax.tree.map(lambda _: P(None, self.axis), batch)
-        out_metric_spec = {"loss": P(), "accuracy": P()}
-
-        @partial(
-            jax.shard_map,
-            mesh=self.mesh,
-            in_specs=(state_spec, batch_spec, P()),
-            out_specs=(state_spec, out_metric_spec),
-            check_vma=False,
-        )
-        def run(state, batch, lr):
-            step = state.step
-            A = next(iter(batch.values())).shape[0]
-            tables0 = self._squeeze_all(state.tables)
-
-            def micro(carry, mb):
-                tables, g_acc = carry
-                tables, g_dense, mets = self._sharded_micro(
-                    tables, state.dense, mb, step, lr
-                )
-                return (tables, jax.tree.map(jnp.add, g_acc, g_dense)), mets
-
-            g0 = jax.tree.map(jnp.zeros_like, state.dense)
-            (tables, g_acc), mets = jax.lax.scan(micro, (tables0, g0), batch)
-            with scopes.scope(scopes.PHASE_DENSE_APPLY):
-                g_mean = jax.tree.map(lambda g: g / jnp.float32(A), g_acc)
-                updates, opt_state = self.dense_opt.update(
-                    g_mean, state.opt_state, state.dense
-                )
-                dense = optax.apply_updates(state.dense, updates)
-            new_state = TrainState(
-                step=step + 1,
-                tables={
-                    bname: self._unsqueeze(bname, ts)
-                    for bname, ts in tables.items()
-                },
-                dense=dense,
-                opt_state=opt_state,
-            )
-            return new_state, jax.tree.map(jnp.mean, mets)
-
-        return run(state, batch, lr)
-
     def train_steps(self, state: TrainState, batches, lr=None):
         """K steps per dispatch on the mesh. A list/tuple of batch dicts is
         stacked and placed with the K axis unsharded and the batch axis
@@ -1226,31 +955,3 @@ class ShardedTrainer(Trainer):
                 stacked=True,
             )
         return super().train_steps(state, batches, lr)
-
-    def _sharded_eval(self, state: TrainState, batch):
-        state_spec, batch_spec = self._specs_for(state, batch)
-
-        @partial(
-            jax.shard_map,
-            mesh=self.mesh,
-            in_specs=(state_spec, batch_spec),
-            out_specs=(P(), P(self.axis)),
-            check_vma=False,
-        )
-        def run(state, batch):
-            tables = self._squeeze_all(state.tables)
-            tables, views, _ = self._lookup_all(
-                tables, batch, state.step, False
-            )
-            embs = {n: v[0].astype(jnp.float32) for n, v in views.items()}
-            inputs = self._build_inputs(embs, views, batch)
-            out = self.model.apply(state.dense, inputs, train=False)
-            loss, out = self._loss_from_logits(out, batch)
-            probs = (
-                {k: jax.nn.sigmoid(v) for k, v in out.items()}
-                if isinstance(out, dict)
-                else jax.nn.sigmoid(out)
-            )
-            return jax.lax.pmean(loss, self.axis), probs
-
-        return run(state, batch)

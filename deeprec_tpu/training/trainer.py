@@ -215,9 +215,9 @@ class Trainer:
         # Step sentinel (guard/sentinel.py SentinelConfig): per-dispatch
         # model-quality flags fused into the jitted step and the K-step
         # scan body — one int32 scalar out per step, bit-exact no-op on
-        # the update math while untripped. Base Trainer only: the
-        # sharded step impls are separate programs (ShardedTrainer never
-        # forwards the kwarg).
+        # the update math while untripped. Base Trainer only so far:
+        # ShardedTrainer runs these same step bodies on its mesh but does
+        # not forward the kwarg yet.
         if sentinel is not None:
             from deeprec_tpu.guard.sentinel import SentinelConfig
 
@@ -266,13 +266,14 @@ class Trainer:
         resolved budget (update_budgets moving an "auto" bucket) must
         rebuild these — an already-cached executable for the same input
         avals would silently keep its old unique sizes otherwise."""
-        self._train_step = jax.jit(self._step_impl, donate_argnums=0)  # noqa: DRT001 — deliberate rebuild-on-budget/plan-change; one wrapper serves all steps
-        self._train_step_accum = jax.jit(self._accum_impl, donate_argnums=0)  # noqa: DRT001 — deliberate rebuild-on-budget/plan-change; one wrapper serves all steps
+        on = self._on_mesh
+        self._train_step = jax.jit(on(self._step_impl), donate_argnums=0)  # noqa: DRT001 — deliberate rebuild-on-budget/plan-change; one wrapper serves all steps
+        self._train_step_accum = jax.jit(on(self._accum_impl, stacked=True), donate_argnums=0)  # noqa: DRT001 — deliberate rebuild-on-budget/plan-change; one wrapper serves all steps
         # K-step device loop: jit caches one executable per K (the stacked
         # batch's leading dim is part of the trace signature), so sweeping
         # or changing K recompiles once per value and then amortizes.
-        self._train_steps = jax.jit(self._steps_impl, donate_argnums=0)  # noqa: DRT001 — deliberate rebuild-on-budget/plan-change; one wrapper serves all steps
-        self._eval_step = jax.jit(self._eval_impl)  # noqa: DRT001 — deliberate rebuild-on-budget/plan-change; one wrapper serves all steps
+        self._train_steps = jax.jit(on(self._steps_impl, stacked=True), donate_argnums=0)  # noqa: DRT001 — deliberate rebuild-on-budget/plan-change; one wrapper serves all steps
+        self._eval_step = jax.jit(on(self._eval_impl, evaluate=True))  # noqa: DRT001 — deliberate rebuild-on-budget/plan-change; one wrapper serves all steps
 
     # Back-compat/introspection: table object + state accessor per table name.
     @property
@@ -316,6 +317,34 @@ class Trainer:
     # _lookup_one/_apply_one are the per-bundle primitives; ShardedTrainer
     # overrides just these two to swap in the collective path, so the
     # bundling/stacking control flow below exists exactly once.
+    #
+    # The step bodies (_micro_step ... _eval_impl) exist once too. What a
+    # mesh changes of them is these four hooks, each the identity here.
+
+    # The phase scope a step's lookup stands under (utils/scopes.py).
+    _lookup_phase = scopes.PHASE_LOOKUP
+
+    def _tables_in(self, tables):
+        """The tables as a step body works on them (a mesh: the shard
+        axis off). A fresh dict: the bodies assign into it."""
+        return dict(tables)
+
+    def _tables_out(self, tables):
+        """Inverse of `_tables_in`, for the TrainState a body returns."""
+        return tables
+
+    def _replica_mean(self, tree):
+        """Mean over the replicas of the dense gradients and the step's
+        metrics: one replica here."""
+        return tree
+
+    def _on_mesh(self, body, stacked=False, evaluate=False):
+        """A step body `(state, batch, lr) -> (state, metrics)`, or the
+        eval body `(state, batch) -> (loss, probs)`, as the program a
+        dispatch runs. `stacked`: the batch leaves carry a leading [K]
+        (scan) or [A] (accumulation) axis before the batch axis. One
+        device runs the body as it is."""
+        return body
 
     # ----------------------------------------------------- unique budgets
 
@@ -636,6 +665,35 @@ class Trainer:
         return loss, {"accuracy": M.accuracy(jax.nn.sigmoid(out),
                                              batch["label"])}
 
+    def _fwd_bwd(self, dense, views, batch):
+        """The dense forward and backward over one batch's finished lookup.
+        Returns (dense grads, per-feature embedding grads, metrics with the
+        loss among them); the dense grads and the metrics are the mean over
+        the replicas."""
+        with scopes.scope(scopes.PHASE_DENSE_FWD_BWD):
+            embs = {n: v[0].astype(jnp.float32) for n, v in views.items()}
+            (loss, mets), (g_dense, g_embs) = jax.value_and_grad(
+                self._forward_loss, argnums=(0, 1), has_aux=True
+            )(dense, embs, views, batch)
+            g_dense, mets = self._replica_mean(
+                (g_dense, {"loss": loss, **mets})
+            )
+        return g_dense, g_embs, mets
+
+    def _dense_update(self, state: TrainState, g_dense, tables) -> TrainState:
+        """The dense optimizer's update and the step count: the state
+        after a step whose sparse side left `tables`."""
+        with scopes.scope(scopes.PHASE_DENSE_APPLY):
+            updates, opt_state = self.dense_opt.update(
+                g_dense, state.opt_state, state.dense
+            )
+            dense = optax.apply_updates(state.dense, updates)
+            step = state.step + 1
+        return TrainState(
+            step=step, tables=self._tables_out(tables), dense=dense,
+            opt_state=opt_state,
+        )
+
     def _micro_step(self, tables, dense, batch, step, lr):
         """Forward + backward + SPARSE applies for one (micro-)batch; returns
         updated tables, the dense-grad pytree (NOT applied) and metrics.
@@ -643,23 +701,17 @@ class Trainer:
         Every operation stands under one phase scope (utils/scopes.py),
         so a device trace splits the step by lookup / dense fwd-bwd /
         sparse apply."""
-        with scopes.scope(scopes.PHASE_LOOKUP):
+        with scopes.scope(self._lookup_phase):
             tables, views, bundle_res = self._lookup_all(
                 tables, batch, step, True
             )
-
-        with scopes.scope(scopes.PHASE_DENSE_FWD_BWD):
-            embs = {n: v[0].astype(jnp.float32) for n, v in views.items()}
-            (loss, mets), (g_dense, g_embs) = jax.value_and_grad(
-                self._forward_loss, argnums=(0, 1), has_aux=True
-            )(dense, embs, views, batch)
-            mets = {"loss": loss, **mets}
+        g_dense, g_embs, mets = self._fwd_bwd(dense, views, batch)
         with scopes.scope(scopes.PHASE_SPARSE_APPLY):
             tables = self._apply_all(tables, bundle_res, g_embs, step, lr)
         if self.sentinel is not None:
             with scopes.scope(scopes.PHASE_SENTINEL):
                 tables, mets["_sentinel"] = self._sentinel_observe(
-                    tables, bundle_res, loss, g_dense, g_embs, step
+                    tables, bundle_res, mets["loss"], g_dense, g_embs, step
                 )
         return tables, g_dense, mets
 
@@ -740,22 +792,13 @@ class Trainer:
         return mets, guard
 
     def _step_impl(self, state: TrainState, batch, lr, guard=None):
-        step = state.step
         tables, g_dense, mets = self._micro_step(
-            dict(state.tables), state.dense, batch, step, lr
+            self._tables_in(state.tables), state.dense, batch, state.step, lr
         )
         if self.sentinel is not None:
             with scopes.scope(scopes.PHASE_SENTINEL):
                 mets, guard = self._sentinel_fold(mets, guard)
-        with scopes.scope(scopes.PHASE_DENSE_APPLY):
-            updates, opt_state = self.dense_opt.update(
-                g_dense, state.opt_state, state.dense
-            )
-            dense = optax.apply_updates(state.dense, updates)
-            step = step + 1
-        return TrainState(
-            step=step, tables=tables, dense=dense, opt_state=opt_state
-        ), mets
+        return self._dense_update(state, g_dense, tables), mets
 
     def _accum_impl(self, state: TrainState, batch, lr, guard=None):
         """Gradient micro-batching — the Auto-Micro-Batch analog
@@ -776,14 +819,11 @@ class Trainer:
 
         g0 = jax.tree.map(jnp.zeros_like, state.dense)
         (tables, g_acc), mets = jax.lax.scan(
-            micro, (dict(state.tables), g0), batch
+            micro, (self._tables_in(state.tables), g0), batch
         )
         with scopes.scope(scopes.PHASE_DENSE_APPLY):
             g_mean = jax.tree.map(lambda g: g / jnp.float32(A), g_acc)
-            updates, opt_state = self.dense_opt.update(
-                g_mean, state.opt_state, state.dense
-            )
-            dense = optax.apply_updates(state.dense, updates)
+        new_state = self._dense_update(state, g_mean, tables)
         sen = mets.pop("_sentinel", None)  # [A]-stacked micro observations
         mets = jax.tree.map(jnp.mean, mets)
         if self.sentinel is not None and sen is not None:
@@ -798,9 +838,7 @@ class Trainer:
             if "row_max" in sen:
                 mets["_sentinel"]["row_max"] = jnp.max(sen["row_max"])
             mets, guard = self._sentinel_fold(mets, guard)
-        return TrainState(
-            step=step + 1, tables=tables, dense=dense, opt_state=opt_state
-        ), mets
+        return new_state, mets
 
     def _steps_impl(self, state: TrainState, batches, lr, guard=None):
         """Multi-step device loop — K full train steps per dispatch.
@@ -841,20 +879,21 @@ class Trainer:
 
     # ------------------------------------------------- pipelined K-step scan
 
-    def _pipe_prologue(self, state: TrainState, batch0,
-                       guard=None) -> PipelineCarry:
+    def _pipe_prologue(self, state: TrainState, batch0, guard=None,
+                       keep_rows=True) -> PipelineCarry:
         """Fill the pipeline: full split-phase lookup of the window's
         first batch (identical program to the sequential lookup)."""
-        tables = dict(state.tables)
-        with scopes.scope(scopes.PHASE_LOOKUP):
+        tables = self._tables_in(state.tables)
+        with scopes.scope(self._lookup_phase):
             routes = self._route_all(batch0, True)
             tables, pending = self._resolve_all(
                 tables, routes, state.step, True
             )
-            views, res = self._finish_all(tables, pending, batch0, True)
+            views, res = self._finish_all(
+                tables, pending, batch0, True, keep_rows=keep_rows
+            )
         return PipelineCarry(
-            inner=TrainState(step=state.step, tables=tables,
-                             dense=state.dense, opt_state=state.opt_state),
+            inner=state.replace(tables=self._tables_out(tables)),
             batch=batch0, views=views, bundle_res=res, guard=guard,
         )
 
@@ -878,7 +917,7 @@ class Trainer:
         only `.inner` is meaningful."""
         state = carry.inner
         step = state.step
-        tables = dict(state.tables)
+        tables = self._tables_in(state.tables)
         if batch_next is not None:
             with scopes.scope(scopes.PHASE_ROUTE_NEXT):
                 routes = self._route_all(batch_next, True)
@@ -887,13 +926,7 @@ class Trainer:
                 )
         views = carry.views
         prev_batch = carry.batch
-
-        with scopes.scope(scopes.PHASE_DENSE_FWD_BWD):
-            embs = {n: v[0].astype(jnp.float32) for n, v in views.items()}
-            (loss, mets), (g_dense, g_embs) = jax.value_and_grad(
-                self._forward_loss, argnums=(0, 1), has_aux=True
-            )(state.dense, embs, views, prev_batch)
-            mets = {"loss": loss, **mets}
+        g_dense, g_embs, mets = self._fwd_bwd(state.dense, views, prev_batch)
         with scopes.scope(scopes.PHASE_SPARSE_APPLY):
             tables = self._apply_all(tables, carry.bundle_res, g_embs, step, lr)
         guard = carry.guard
@@ -902,7 +935,8 @@ class Trainer:
             # so the row pass reads them BEFORE finish(t+1)'s gather.
             with scopes.scope(scopes.PHASE_SENTINEL):
                 tables, mets["_sentinel"] = self._sentinel_observe(
-                    tables, carry.bundle_res, loss, g_dense, g_embs, step
+                    tables, carry.bundle_res, mets["loss"], g_dense, g_embs,
+                    step,
                 )
                 mets, guard = self._sentinel_fold(mets, guard)
         if batch_next is not None:
@@ -912,18 +946,9 @@ class Trainer:
                 )
         else:
             batch_next, views_n, res_n = prev_batch, views, carry.bundle_res
-        with scopes.scope(scopes.PHASE_DENSE_APPLY):
-            updates, opt_state = self.dense_opt.update(
-                g_dense, state.opt_state, state.dense
-            )
-            dense = optax.apply_updates(state.dense, updates)
-            step = step + 1
-        new_state = TrainState(
-            step=step, tables=tables, dense=dense, opt_state=opt_state
-        )
         return PipelineCarry(
-            inner=new_state, batch=batch_next, views=views_n,
-            bundle_res=res_n, guard=guard,
+            inner=self._dense_update(state, g_dense, tables),
+            batch=batch_next, views=views_n, bundle_res=res_n, guard=guard,
         ), mets
 
     def _steps_pipelined(self, state: TrainState, batches, lr, guard=None):
@@ -954,9 +979,8 @@ class Trainer:
     def forward_views(self, state: TrainState, batch):
         """Readonly lookup pass (no inserts/counters): per-feature views
         plus per-bundle results. Shared by eval and the serving predictor."""
-        tables = dict(state.tables)
         _, views, bundle_res = self._lookup_all(
-            tables, batch, state.step, False
+            self._tables_in(state.tables), batch, state.step, False
         )
         return views, bundle_res
 
@@ -976,7 +1000,7 @@ class Trainer:
         views, _ = self.forward_views(state, batch)
         out, probs = self.probs_from_views(state, views, batch)
         loss, _ = self._loss_from_logits(out, batch)
-        return loss, probs
+        return self._replica_mean(loss), probs
 
     # ----------------------------------------------------------- auto-stage
 

@@ -130,6 +130,11 @@ def test_lease_stamper_picks_up_drain_and_exit_codes(tmp_path):
         assert r.members()[0].status == "up"
         r.request_drain("127.0.0.1:7030", respawn=True)
         assert st.draining.wait(timeout=5.0)
+        # the event is set BEFORE the lease is stamped: wait for the stamp
+        deadline = time.monotonic() + 5.0
+        while r.members()[0].status != "draining" \
+                and time.monotonic() < deadline:
+            time.sleep(0.01)
         (m,) = r.members()                      # still a member...
         assert m.status == "draining"           # ...but marked leaving
         assert r.members(include_draining=False) == []

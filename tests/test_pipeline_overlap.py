@@ -47,31 +47,38 @@ def window_batches(K=4, batch_size=64, seed=7, fresh_ids=True):
     return batches
 
 
-def assert_states_bitwise(s_a, s_b):
-    """Full exactness: table ints AND values bitwise, dense/opt bitwise."""
+def _ordered(x):
+    """float32 -> int64 that counts representable values in order."""
+    i = np.asarray(x, np.float32).view(np.int32).astype(np.int64)
+    return np.where(i < 0, -(i & 0x7FFFFFFF), i)
+
+
+def assert_states_bitwise(s_a, s_b, ulps=0):
+    """Full exactness: table ints AND values bitwise, dense/opt bitwise.
+    `ulps`: how many representable values a FLOAT leaf may differ by
+    (0: bitwise); int leaves (keys, meta, counters, step) are always
+    exact."""
+    def same(x, y):
+        x, y = np.asarray(x), np.asarray(y)
+        if ulps and x.dtype == np.float32:
+            assert np.abs(_ordered(x) - _ordered(y)).max(initial=0) <= ulps
+        else:
+            np.testing.assert_array_equal(x, y)
+
+    assert int(s_a.step) == int(s_b.step)
     for bname in s_a.tables:
         a, b = s_a.tables[bname], s_b.tables[bname]
-        np.testing.assert_array_equal(np.asarray(a.keys), np.asarray(b.keys))
-        np.testing.assert_array_equal(np.asarray(a.meta), np.asarray(b.meta))
-        np.testing.assert_array_equal(
-            np.asarray(a.insert_fails), np.asarray(b.insert_fails)
-        )
-        np.testing.assert_array_equal(
-            np.asarray(a.dedup_unique), np.asarray(b.dedup_unique)
-        )
-        np.testing.assert_array_equal(
-            np.asarray(a.values), np.asarray(b.values)
-        )
+        for name in ("keys", "meta", "insert_fails", "dedup_unique",
+                     "values"):
+            same(getattr(a, name), getattr(b, name))
         for k in a.slots:
-            np.testing.assert_array_equal(
-                np.asarray(a.slots[k]), np.asarray(b.slots[k])
-            )
+            same(a.slots[k], b.slots[k])
     for x, y in zip(jax.tree.leaves(s_a.dense), jax.tree.leaves(s_b.dense)):
-        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+        same(x, y)
     for x, y in zip(
         jax.tree.leaves(s_a.opt_state), jax.tree.leaves(s_b.opt_state)
     ):
-        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+        same(x, y)
 
 
 # --------------------------------------------------------------- single dev
@@ -252,7 +259,18 @@ def test_shared_table_pipelined_single_device():
     re-gathering applies) under the pipelined scan: the resolve of both
     features chains inserts exactly as the sequential path, both finishes
     read post-apply values, and the second apply still sees the first's
-    writes."""
+    writes.
+
+    Floats to 2 ulps, everything else exact. Found (PR 31, XLA:CPU): keys,
+    meta, counters, the Adagrad accumulators and the three losses agree
+    bit for bit; 2 of 8,192 value elements differ by ONE ulp, first at
+    K = 3 (the first window whose scan body runs twice); K sequential
+    steps and the unpipelined scan agree exactly, and with
+    `--xla_backend_optimization_level=0` the difference is gone. The two
+    programs order their arithmetic alike; the compiler's optimised code
+    rounds one operation of the row update differently in the differently
+    shaped loop, and bitwise floats between two differently compiled
+    programs is not a contract it offers."""
     batches = _shared_batches()
     t_off = Trainer(_shared_model(), Adagrad(lr=0.2))
     t_la = Trainer(_shared_model(), Adagrad(lr=0.2), pipeline_mode="lookahead")
@@ -261,10 +279,15 @@ def test_shared_table_pipelined_single_device():
     s0, m0 = t_off.train_steps(t_off.init(0), batches)
     s1, m1 = t_la.train_steps(t_la.init(0), batches)
     np.testing.assert_array_equal(np.asarray(m0["loss"]), np.asarray(m1["loss"]))
-    assert_states_bitwise(s0, s1)
+    assert_states_bitwise(s0, s1, ulps=2)
 
 
 def test_shared_table_pipelined_sharded(mesh):
+    """The same on the mesh, which runs the same pipelined step. Found the
+    same (PR 31): ints and losses exact, 2 of 8,192 value elements one ulp
+    apart at K = 3 (at K = 2, 4 of 16 elements of Adam's second moment
+    instead), nothing at `--xla_backend_optimization_level=0`; the
+    unpipelined scan and K sequential steps agree exactly."""
     from deeprec_tpu.parallel import ShardedTrainer, shard_batch
 
     batches = [shard_batch(mesh, b) for b in _shared_batches(K=3, n=64)]
@@ -274,7 +297,7 @@ def test_shared_table_pipelined_sharded(mesh):
     s0, m0 = t_off.train_steps(t_off.init(0), batches)
     s1, m1 = t_la.train_steps(t_la.init(0), batches)
     np.testing.assert_array_equal(np.asarray(m0["loss"]), np.asarray(m1["loss"]))
-    assert_states_bitwise(s0, s1)
+    assert_states_bitwise(s0, s1, ulps=2)
 
 
 # ------------------------------------------------------- async via split-phase

@@ -251,23 +251,40 @@ def test_sentinel_and_pipelined_steps_name_their_phases():
     assert scopes.ENGINE_GATHER not in hoisted
 
 
+@pytest.mark.parametrize("program", ["train_step", "train_steps"])
 @pytest.mark.parametrize("comm, chunks", [("allgather", 1), ("a2a", 3)])
-def test_sharded_train_step_names_the_same_vocabulary(mesh, comm, chunks):
+def test_sharded_train_step_names_the_same_vocabulary(mesh, comm, chunks,
+                                                      program):
+    """The mesh runs the base trainer's step bodies, so the one step and
+    the pipelined K-step scan write the base's phases, with the exchange
+    phase where the base writes the lookup."""
     from deeprec_tpu.parallel import ShardedTrainer, shard_batch
+    from deeprec_tpu.training import stack_batches
 
     kw = dict(pipeline_mode="chunked", pipeline_chunks=chunks) \
-        if chunks > 1 else {}
+        if chunks > 1 else \
+        dict(pipeline_mode="lookahead") if program == "train_steps" else {}
     tr = ShardedTrainer(model(), Adagrad(lr=0.1), optax.adam(1e-3),
                         mesh=mesh, comm=comm, unique_budget=48, **kw)
-    batch = shard_batch(mesh, batches(1)[0])
-    names = op_names(tr._train_step.lower(
-        tr.init(0), batch, jnp.float32(0.1)).compile())
-    phases, stages, rows = found(names)
     want = {scopes.PHASE_LOOKUP_EXCHANGE, scopes.PHASE_DENSE_FWD_BWD,
             scopes.PHASE_SPARSE_APPLY, scopes.PHASE_DENSE_APPLY}
-    assert phases == want, phases
+    if program == "train_step":
+        lowered = tr._train_step.lower(
+            tr.init(0), shard_batch(mesh, batches(1)[0]), jnp.float32(0.1))
+    else:
+        want |= {scopes.PHASE_ROUTE_NEXT, scopes.PHASE_FINISH_EXCHANGE}
+        lowered = tr._train_steps.lower(
+            tr.init(0),
+            shard_batch(mesh, stack_batches(batches(3)), stacked=True),
+            jnp.float32(0.1))
+    names = op_names(lowered.compile())
+    phases, stages, rows = found(names)
     assert set(scopes.STAGES) <= stages and set(scopes.ROWS) <= rows
-    assert_every_instruction_has_a_phase(names)
+    if program == "train_step":
+        assert phases == want, phases
+        assert_every_instruction_has_a_phase(names)
+    else:  # the scan's own slicing and stacking stand under no phase
+        assert phases - {""} == want, phases
     text = "\n".join(n for _, n in names)
     for i in range(chunks if chunks > 1 else 0):
         assert scopes.exchange_chunk(i) in text

@@ -128,8 +128,8 @@ def test_sharded_train_steps_matches_sequential():
 
 
 def test_sharded_train_steps_a2a_comm():
-    """The scan body reuses _sharded_step's exchange — including the
-    budgeted all2all path."""
+    """The scan body is the single step's (`Trainer._step_impl` on the
+    mesh), exchange included — also on the budgeted all2all path."""
     from deeprec_tpu.parallel import ShardedTrainer, make_mesh, shard_batch
 
     mesh = make_mesh(8)
