@@ -5,8 +5,7 @@ Why these exist: the reference spends 5.5k LoC of CUDA on fused embedding
 lookups (core/ops/fused_embedding_ops.cc:65, core/kernels/group_embedding/
 group_embedding_lookup_sparse_forward_base_ops.cu.h) because op-composed
 sparse gathers leave bandwidth on the table. The TPU analog is a Pallas
-kernel that streams random table rows HBM->VMEM through a double-buffered
-DMA pipeline, so the next row's fetch overlaps the current row's compute:
+kernel that moves random table rows by DMA, many in flight at once:
 
   * ``gather_rows``          — values[ix] for [U] unique slots (the hot
     [U, D] gather inside every lookup).
@@ -19,8 +18,7 @@ DMA pipeline, so the next row's fetch overlaps the current row's compute:
     small gradient updates once |update| < ulp(value)/2).
 
 Eligibility: the single-row DMA kernels require **f32 tables with
-dim % 128 == 0** (Mosaic's HBM tiling constraint, ``_dma_ok``; measured
-winners on v5e — gather 494 vs 362 GB/s, scatter 1117 vs 726). **bf16
+dim == 128** (Mosaic's HBM tiling constraint, ``_dma_ok``). **bf16
 tables with dim % 128 == 0** ride the PAIR-granule variants
 (``gather_rows_pair`` / ``apply_rows_sr_pair`` / the pair branch of
 ``fused_gather_combine``): 2-row even-aligned DMAs with the half-select
@@ -45,6 +43,14 @@ onto the stacked array) and sit behind a ``custom_vmap`` hook that folds
 any vmap — and a second one on top, shards x tables — into that axis by a
 reshape (``_fold``). The unbatched call is T = 1. docs/kernels.md has the
 SMEM budget and the mixed-batching cases.
+
+Schedule. The two row kernels only copy: a row goes HBM to HBM, from the
+table to its row of the result or from its row of the updates to the
+table, with no block in VMEM and no vector load or store; a grid step
+carries all of a table's rows and keeps _GROUP * _AHEAD row DMAs in
+flight (``_row_window``); a skipped slot starts nothing and is waited for
+never. docs/kernels.md, "How the row kernels keep DMAs in flight", has the
+sweep on the chip that set the two constants and what a row costs.
 """
 from __future__ import annotations
 
@@ -56,8 +62,14 @@ import jax.numpy as jnp
 
 from deeprec_tpu.utils import backend, scopes
 
-_BLOCK = 8  # rows per grid step; sublane-aligned for f32
+_BLOCK = 8  # rows per grid step of the pair kernels; one f32 sublane tile
 _LANES = 128  # Mosaic HBM tiling: DMA row slices must be lane-aligned
+# The row kernels' schedule (docs/kernels.md, "How the row kernels keep DMAs
+# in flight"): rows start in groups of _GROUP, and _AHEAD groups are in
+# flight before the first is waited for. Both come from a sweep on the chip,
+# not from a caller: there is no option that sets them.
+_GROUP = 16
+_AHEAD = 4
 # Row indices ride SMEM as a scalar prefetch, all tables of a call in one
 # operand; past this many bytes of them a stacked call splits into calls
 # over table ranges (_table_ranges). Two limits stand behind the number.
@@ -109,12 +121,19 @@ def _dma_pair_ok(shape, dtype) -> bool:
 
 
 # Which (kernel, shape-class) combos "auto" trusts. The policy is that
-# auto only resolves to Pallas where a live-hardware bench crowned it
-# (tools/bench_lookup.py, docs/perf.md); the bf16 pair kernels are
-# implemented + oracle-tested but NOT yet measured on hardware, so auto
-# keeps XLA for them until a measurement flips these flags. Both flags
-# are consulted by EmbeddingTable.use_pallas / .pair_kernels.
-AUTO_TRUSTS_F32_ROW = True     # measured round 2: +37% gather, +54% scatter
+# auto only resolves to Pallas where a live-hardware bench crowned it; the
+# bf16 pair kernels are implemented + oracle-tested but NOT yet measured on
+# hardware, so auto keeps XLA for them until a measurement flips these
+# flags. Both flags are consulted by EmbeddingTable.use_pallas /
+# .pair_kernels. What stands behind the first (one v5e, PR 32;
+# docs/kernels.md, PERF.md 5-6): the kernels alone at [26, 262144, 128]
+# read 11.2 ns a gathered row and 12.4 a scattered one where XLA's gather
+# and scatter read 11.4 and 74.9, and the benchmark's `.zipf` step 97.0k
+# examples/s where the XLA arm reads 86.9k (the XLA arm halts the chip in
+# `.uniform`, ROADMAP D0). Before PR 32 the XLA arm was ahead in `.zipf`
+# (71.3k against 55.4k, PR 27); the "+37 % gather, +54 % scatter" this
+# line used to cite came from a window no artifact records.
+AUTO_TRUSTS_F32_ROW = True
 AUTO_TRUSTS_BF16_PAIR = False  # pending hardware window
 AUTO_TRUSTS_FUSED_STEP = False  # single-pass step kernels: pending hardware
 
@@ -222,6 +241,89 @@ def _table_ranges(tables: int, rows: int):
     return [(t0, min(per, tables - t0)) for t0 in range(0, tables, per)]
 
 
+def _note_schedule(kernel: str, shape, rows: int, block: int) -> None:
+    """What a (kernel, shape) rides: the rows a grid step carries and the
+    window its call was built with, at TRACE time like _note_fallback, as
+    deeprec_pallas_row_schedule{kernel,shape,rows,block,window} 1 (a gauge:
+    noting it again on a retrace changes nothing)."""
+    from deeprec_tpu.obs.metrics import default_registry
+
+    default_registry().gauge(  # noqa: DRT007 — bounded: static shapes, one series a compiled (kernel, shape), each a compile of its own
+        "deeprec_pallas_row_schedule",
+        help="Row-kernel calls by the rows a grid step carries and the row "
+             "DMAs it keeps in flight",
+        labels={"kernel": kernel, "shape": "x".join(map(str, shape)),
+                "rows": str(rows), "block": str(block),
+                "window": str(_GROUP * _AHEAD)},
+    ).set(1)
+
+
+def _wait_rows(table_ref, k: int, sem) -> None:
+    """Wait on `sem` for k row DMAs of table_ref's row size: ONE wait whose
+    descriptor spans k rows of the table (it is never started: a wait only
+    counts the bytes), or k waits of a row where the table has fewer."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    span = k if k <= table_ref.shape[0] else 1
+    done = table_ref.at[pl.ds(0, span)]
+    for _ in range(k // span):
+        pltpu.make_async_copy(done, done, sem).wait()
+
+
+def _row_window(rows: int, start, wait, live=None) -> None:
+    """One grid step's `rows` row DMAs, a window of them in flight: rows
+    start in groups of _GROUP (unrolled: one loop branch a group), a group
+    is waited for as the group _AHEAD after it starts, and what is left in
+    flight is waited for at the end: every DMA the body starts is waited
+    for before the body ends.
+
+    `start(i)` starts row i's DMA and `wait(k)` waits for any k (static)
+    rows: all rows are one size and signal ONE semaphore, so a wait is for
+    that many of the rows in flight, whichever, and the last wait is for
+    all of them. `live(i)`, where given, says whether row i moves at all (a
+    skipped slot, a row past the call's last): a row that does not starts
+    nothing and is waited for never, because a group counts the rows it
+    started and is waited for by that count."""
+    from jax.experimental import pallas as pl
+
+    groups, rest = divmod(rows, _GROUP)
+
+    def start_group(first, size):
+        """Start rows [first, first + size); how many of them moved."""
+        if live is None:
+            for u in range(size):
+                start(first + u)
+            return jnp.int32(size)
+        ok = [live(first + u) for u in range(size)]
+        moved = sum((o.astype(jnp.int32) for o in ok), jnp.int32(0))
+
+        @pl.when(moved > 0)  # one branch for a group that starts nothing
+        def _():
+            for u in range(size):
+                pl.when(ok[u])(functools.partial(start, first + u))
+
+        return moved
+
+    def wait_rows(moved):
+        """A whole group in ONE wait, else a row at a time."""
+        whole = moved == _GROUP
+        pl.when(whole)(lambda: wait(_GROUP))
+        jax.lax.fori_loop(0, jnp.where(whole, 0, moved),
+                          lambda j, _: wait(1) or 0, 0)
+
+    def step(g, in_flight):
+        # the rows the last _AHEAD groups started, oldest first: none yet
+        # for the first of them, so the first waits are for nothing
+        wait_rows(in_flight[0])
+        return in_flight[1:] + (start_group(g * _GROUP, _GROUP),)
+
+    in_flight = (jnp.int32(0),) * _AHEAD
+    if groups:
+        in_flight = jax.lax.fori_loop(0, groups, step, in_flight)
+    wait_rows(sum(in_flight) + start_group(groups * _GROUP, rest))
+
+
 def _sr_bits(seed, shape):
     """The one seed-derivation for stochastic-rounding bits: every SR
     path (XLA fallback, row kernel, pair kernel) must use this so their
@@ -233,7 +335,8 @@ def _sr_bits(seed, shape):
 def _sr_round_in_kernel(row_f32, bits_u32):
     """In-kernel stochastic rounding f32 -> bf16-representable f32
     (same bit-twiddle as stochastic_round): add uniform noise below the
-    mantissa cut, truncate. Shared by both scatter kernels."""
+    mantissa cut, truncate. Shared by the pair scatter and the fused
+    backward (the row scatter's rows are rounded before the kernel)."""
     from jax.experimental.pallas import tpu as pltpu
 
     u = pltpu.bitcast(row_f32, jnp.uint32)
@@ -411,17 +514,20 @@ def apply_rows_sr_pair(values: jnp.ndarray, slot_ix: jnp.ndarray,
 
 
 def gather_rows(values: jnp.ndarray, ix: jnp.ndarray, *,
-                block: int = _BLOCK, interpret: bool = False,
+                block: int | None = None, interpret: bool = False,
                 pair_kernels: bool = False) -> jnp.ndarray:
     """values [C, D], ix [n] int32 -> [n, D]; out-of-range ix clamp (the
-    'clip' semantics of the jnp fallback). Rows ride a 2-deep DMA pipeline.
+    'clip' semantics of the jnp fallback). Rows go by DMA, HBM to HBM, a
+    window of them in flight (_row_window); `block=` splits a table's rows
+    into grid steps of that many (default: one step carries them all).
     pair_kernels=True additionally routes eligible bf16 tables through the
     pair-granule kernel (explicit kernel="pallas" or a measured-winners
     flag — see AUTO_TRUSTS_BF16_PAIR)."""
     if pair_kernels and _dma_pair_ok(values.shape, values.dtype) and (
         interpret or backend.on_tpu()
     ):
-        return gather_rows_pair(values, ix, block=block, interpret=interpret)
+        return gather_rows_pair(values, ix, block=block or _BLOCK,
+                                interpret=interpret)
     if not interpret and not (
         backend.on_tpu() and _dma_ok(values.shape[1], values.dtype)
     ):
@@ -436,69 +542,68 @@ def gather_rows(values: jnp.ndarray, ix: jnp.ndarray, *,
 def _gather_rows_stacked(values, ix, *, block, interpret):
     """The gather kernel, with its table axis: values [T, C, D], ix [T, n]
     -> [T, n, D]. One call for a range of tables (_table_ranges; all of
-    them while their indices fit the budget): the grid is (table, row
-    block), the range's indices are one flat scalar prefetch, and a row's
-    DMA reads values_ref[t0 + t, idx] from the whole stacked array in HBM."""
+    them while their indices fit the budget). A grid step carries all of a
+    table's rows unless `block=` splits them; indices are padded only
+    then, and a padded row starts no DMA."""
+    n = ix.shape[1]
+    block = block or max(n, 1)
+    _note_schedule(scopes.KERNEL_GATHER_ROWS, values.shape, n, block)
+    ixp = _pad_rows(ix.astype(jnp.int32), block)
+    outs = [
+        _gather_call(jnp.full((1,), t0, jnp.int32), ixp[t0:t0 + tables],
+                     values, n=n, block=block, interpret=interpret)
+        for t0, tables in _table_ranges(values.shape[0], ixp.shape[1])
+    ]
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs)
+
+
+@functools.partial(jax.jit, static_argnames=("n", "block", "interpret"))
+def _gather_call(t0, ixp, values, *, n, block, interpret):
+    """One Pallas call of the gather: the tables [t0, t0 + len(ixp)) of
+    values [T, C, D] at the (padded) indices ixp -> [len(ixp), n, D]. The
+    grid is (table, row block), the indices are one flat scalar prefetch,
+    and a row's DMA goes from values_ref[t0 + t, idx] in the whole stacked
+    array straight to its row of the result, HBM to HBM with no block in
+    VMEM, a window of them in flight (_row_window). Jitted, so that a
+    step's calls of one shape are traced and lowered once."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    T, C, D = values.shape
-    n = ix.shape[1]
-    ixp = _pad_rows(ix.astype(jnp.int32), block)
-    np_ = ixp.shape[1]
+    _, C, D = values.shape
+    tables, np_ = ixp.shape
 
-    def kernel(t0_ref, ix_ref, values_ref, out_ref, scratch, sems):
+    def kernel(t0_ref, ix_ref, values_ref, out_ref, sem):
         t = pl.program_id(0)
-        base = t * np_ + pl.program_id(1) * block
+        row0 = pl.program_id(1) * block
+        base = t * np_ + row0
 
-        def row_dma(slot, i):
+        def start(i):
             idx = jnp.clip(ix_ref[base + i], 0, C - 1)
-            return pltpu.make_async_copy(
-                values_ref.at[t0_ref[0] + t, idx], scratch.at[slot],
-                sems.at[slot],
-            )
+            pltpu.make_async_copy(
+                values_ref.at[t0_ref[0] + t, idx], out_ref.at[t, row0 + i],
+                sem,
+            ).start()
 
-        row_dma(0, 0).start()
+        def wait(k):
+            _wait_rows(values_ref.at[t0_ref[0] + t], k, sem)
 
-        def body(i, _):
-            cur = i % 2
+        _row_window(block, start, wait,
+                    None if np_ == n else lambda i: row0 + i < n)
 
-            @pl.when(i + 1 < block)
-            def _():
-                row_dma((i + 1) % 2, i + 1).start()
-
-            row_dma(cur, i).wait()
-            out_ref[i, :] = scratch[cur]
-            return 0
-
-        jax.lax.fori_loop(0, block, body, 0)
-
-    def call(t0, tables):
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(tables, np_ // block),
-            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec(
-                (None, block, D), lambda t, i, *_: (t, i, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            scratch_shapes=[
-                pltpu.VMEM((2, D), values.dtype),
-                pltpu.SemaphoreType.DMA((2,)),
-            ],
-        )
-        return pl.pallas_call(
-            kernel,
-            name=scopes.KERNEL_GATHER_ROWS,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((tables, np_, D), values.dtype),
-            interpret=interpret,
-        )(jnp.full((1,), t0, jnp.int32),
-          ixp[t0:t0 + tables].reshape(-1), values)
-
-    outs = [call(*r) for r in _table_ranges(T, np_)]
-    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
-    return out[:, :n]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(tables, np_ // block),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
+    )
+    return pl.pallas_call(
+        kernel,
+        name=scopes.KERNEL_GATHER_ROWS,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((tables, n, D), values.dtype),
+        interpret=interpret,
+    )(t0, ixp.reshape(-1), values)
 
 
 @functools.lru_cache(maxsize=None)
@@ -651,7 +756,7 @@ def stochastic_round(x: jnp.ndarray, key: jnp.ndarray,
 
 def apply_rows_sr(values: jnp.ndarray, slot_ix: jnp.ndarray,
                   new_rows: jnp.ndarray, seed: jnp.ndarray, *,
-                  block: int = _BLOCK, interpret: bool = False,
+                  block: int | None = None, interpret: bool = False,
                   use_pallas: bool = True,
                   pair_kernels: bool = False) -> jnp.ndarray:
     """Scatter new_rows [U, D] f32 into values [C, D] at slot_ix [U]
@@ -693,84 +798,77 @@ def _apply_rows_stacked(values, slot_ix, new_rows, seed, *, block,
                         interpret):
     """The scatter kernel, with its table axis: values [T, C, D],
     slot_ix [T, U], new_rows [T, U, D], seed [T] -> the updated [T, C, D],
-    aliased onto `values`: in place on a donated stacked table state."""
+    aliased onto `values`: in place on a donated stacked table state. The
+    kernel only copies: a bf16 table's rows are rounded before it, a table
+    at a time from its own seed, as its unbatched call and the XLA scatter
+    round them."""
+    U = slot_ix.shape[1]
+    block = block or max(U, 1)
+    _note_schedule(scopes.KERNEL_APPLY_ROWS_SR, values.shape, U, block)
+    # Pad with -1 (skip): a padded slot starts no DMA.
+    ixp = _pad_rows(
+        jnp.where(slot_ix >= 0, slot_ix, -1).astype(jnp.int32), block,
+        fill=-1,
+    )
+    if values.dtype == jnp.bfloat16:
+        new_rows = jax.vmap(
+            lambda r, s: _sr_round_bits(r, _sr_bits(s, r.shape))
+        )(new_rows, seed)
+    new_rows = new_rows.astype(values.dtype)
+    for t0, tables in _table_ranges(values.shape[0], ixp.shape[1]):
+        values = _apply_call(jnp.full((1,), t0, jnp.int32),
+                             ixp[t0:t0 + tables], new_rows, values,
+                             block=block, interpret=interpret)
+    return values
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def _apply_call(t0, ixp, new_rows, values, *, block, interpret):
+    """One Pallas call of the scatter: rows of new_rows [T, U, D] into the
+    tables [t0, t0 + len(ixp)) of values [T, C, D] at the (padded) slots
+    ixp, -1 = skip. A row's DMA goes from new_rows[t0 + t, i] straight to
+    vout_ref[t0 + t, idx], HBM to HBM, a window of them in flight
+    (_row_window: valid slots of a call are unique, so the writes do not
+    meet). Jitted for the reason _gather_call is."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    T, _, D = values.shape
-    # Pad with -1 (skip): a 0-fill would scatter garbage rows into slot 0.
-    ixp, new_rows = _pad_updates(slot_ix, new_rows, block)
-    Up = ixp.shape[1]
-    sr = values.dtype == jnp.bfloat16
-    # Random bits come in as a tensor (not in-kernel PRNG): identical
-    # numerics across compiled TPU and interpret mode, at the cost of
-    # U*D*4 extra bytes of traffic — negligible next to the row writes.
-    if sr:
-        # a table's bits are those of its own unbatched call
-        bits = jax.vmap(lambda s: _sr_bits(s, (Up, D)))(seed)
-        bits_dim = D
-    else:
-        # f32 path never reads the bits: ship a 1-wide dummy, not U*D zeros.
-        bits = jnp.zeros((T, Up, 1), jnp.uint32)  # noqa: DRT003 — deliberate 1-wide dummy: f32 path never reads it, padding beats shipping U*D zeros
-        bits_dim = 1
+    tables, Up = ixp.shape
 
-    def kernel(t0_ref, ix_ref, rows_ref, bits_ref, vin_ref, vout_ref,
-               scratch, sems):
+    def kernel(t0_ref, ix_ref, rows_ref, vin_ref, vout_ref, sem):
         del vin_ref  # aliased with vout_ref
-        t = pl.program_id(0)
-        base = t * Up + pl.program_id(1) * block
+        t = t0_ref[0] + pl.program_id(0)
+        row0 = pl.program_id(1) * block
+        base = pl.program_id(0) * Up + row0
 
-        def body(i, _):
-            slot = i % 2
-            row = rows_ref[pl.ds(i, 1), :].astype(jnp.float32)  # (1, D)
-            if sr:
-                row = _sr_round_in_kernel(row, bits_ref[pl.ds(i, 1), :])
-            scratch[pl.ds(slot, 1), :] = row.astype(scratch.dtype)
-            idx = ix_ref[base + i]
+        def start(i):
+            pltpu.make_async_copy(
+                rows_ref.at[t, row0 + i], vout_ref.at[t, ix_ref[base + i]],
+                sem,
+            ).start()
 
-            @pl.when(idx >= 0)
-            def _():
-                dma = pltpu.make_async_copy(
-                    scratch.at[slot], vout_ref.at[t0_ref[0] + t, idx],
-                    sems.at[slot],
-                )
-                dma.start()
-                dma.wait()
+        def wait(k):
+            _wait_rows(vout_ref.at[t], k, sem)
 
-            return 0
+        _row_window(block, start, wait, lambda i: ix_ref[base + i] >= 0)
 
-        jax.lax.fori_loop(0, block, body, 0)
-
-    def rows_block(width):
-        return pl.BlockSpec(
-            (None, block, width),
-            lambda t, i, t0_ref, ix_ref: (t0_ref[0] + t, i, 0),
-            memory_space=pltpu.VMEM,
-        )
-
-    for t0, tables in _table_ranges(T, Up):
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(tables, Up // block),
-            in_specs=[rows_block(D), rows_block(bits_dim),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec(memory_space=pl.ANY),
-            scratch_shapes=[
-                pltpu.VMEM((2, D), values.dtype),
-                pltpu.SemaphoreType.DMA((2,)),
-            ],
-        )
-        values = pl.pallas_call(
-            kernel,
-            name=scopes.KERNEL_APPLY_ROWS_SR,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(values.shape, values.dtype),
-            input_output_aliases={4: 0},
-            compiler_params=pltpu.CompilerParams(has_side_effects=True),
-            interpret=interpret,
-        )(jnp.full((1,), t0, jnp.int32),
-          ixp[t0:t0 + tables].reshape(-1), new_rows, bits, values)
-    return values
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(tables, Up // block),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
+    )
+    return pl.pallas_call(
+        kernel,
+        name=scopes.KERNEL_APPLY_ROWS_SR,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(values.shape, values.dtype),
+        input_output_aliases={3: 0},
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
+        interpret=interpret,
+    )(t0, ixp.reshape(-1), new_rows, values)
 
 
 @functools.lru_cache(maxsize=None)

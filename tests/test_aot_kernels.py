@@ -69,7 +69,8 @@ def _table_sized(hlo):
     return found
 
 
-@pytest.mark.parametrize("n", [2304, 8200, 16384])  # `.zipf`, `.uniform`: U + 8
+# `.zipf`, `.uniform`: U + 8; 8 and 64: a serving-sized call
+@pytest.mark.parametrize("n", [2304, 8200, 16384, 8, 64])
 def test_vmapped_row_kernels_compile_with_no_table_sized_slice(
         one_chip, monkeypatch, n):
     """One Mosaic call an operation and a table range (`.zipf`: one call
@@ -77,7 +78,7 @@ def test_vmapped_row_kernels_compile_with_no_table_sized_slice(
     whole array), no loop round them, nothing that slices a table out of
     the stack or writes one back, and no second values-sized buffer."""
     calls = len(fl._table_ranges(T, n))
-    assert (calls == 1) == (n == 2304)
+    assert (calls == 1) == (n <= 2304) and (n != 8200 or calls == 4)
     compiled = _compile(one_chip, monkeypatch, n)
     hlo = compiled.as_text()
     assert hlo.count('custom_call_target="tpu_custom_call"') == 2 * calls
@@ -86,6 +87,31 @@ def test_vmapped_row_kernels_compile_with_no_table_sized_slice(
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < T * C * D * 4 // 4
     assert mem.alias_size_in_bytes >= T * C * D * 4  # in place
+
+
+def test_the_row_kernels_ask_for_no_vmem():
+    """Rows go HBM to HBM: no operand is a block in VMEM and the only
+    scratch is the DMA semaphore, so no count of rows a grid step carries
+    can pass the compiler's limit on a kernel's VMEM (16 MiB by default on
+    a v5e; a [256, 128] f32 block, double-buffered, would be 256 KiB)."""
+    t0 = jnp.zeros((1,), jnp.int32)
+    vals = jnp.zeros((2, 64, D), jnp.float32)
+    ix = jnp.zeros((2, 16), jnp.int32)
+    rows = jnp.zeros((2, 16, D), jnp.float32)
+    # the calls' own functions, without their jit: the kernels stand at the
+    # top of the jaxpr
+    jaxpr = jax.make_jaxpr(lambda t0, v, i, r: (
+        fl._gather_call.__wrapped__(t0, i, v, n=16, block=16,
+                                    interpret=True),
+        fl._apply_call.__wrapped__(t0, i, r, v, block=16, interpret=True),
+    ))(t0, vals, ix, rows).jaxpr
+    kernels = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(kernels) == 2
+    for eqn in kernels:
+        spaces = {str(v.aval).split("{")[0]
+                  for v in eqn.params["jaxpr"].invars}
+        assert spaces <= {"Ref<any>", "Ref<smem>", "Ref<semaphore_mem>"}, (
+            spaces)
 
 
 def test_mosaic_refuses_indices_past_a_cores_smem(one_chip, monkeypatch):

@@ -193,6 +193,20 @@ def test_row_kernels_under_table_vmap_at_the_cells_shape(n):
             ), line
 
 
+@pytest.mark.parametrize("n", [2304, 8200])
+def test_half_skipped_scatter_at_the_cells_shape(n):
+    """Every other slot skipped: a window of row DMAs in flight holds
+    started and skipped rows side by side, and a group is waited for by
+    the rows it started."""
+    vals, ix, rows = _cell(n)
+    ix = jnp.where(jnp.arange(n)[None] % 2 == 0, ix, -1)
+    want = jax.jit(lambda v, i, r: _xla_round(v, i, r, C_CELL))(
+        vals, ix, rows)
+    got = jax.jit(_rows_round)(vals, ix, rows)
+    assert _equal(got[0], want[0])
+    assert _equal(got[1], want[1])
+
+
 def test_all_skipped_scatter_at_the_cells_shape_touches_no_table():
     """The insert that creates no row: what `insert` pays a step for."""
     vals, ix, rows = _cell(2304, skip_all=True)

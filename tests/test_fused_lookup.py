@@ -454,3 +454,170 @@ def test_updates_mapped_over_one_table_are_noted_as_a_fallback():
     text = default_registry().render_prometheus()
     assert 'kernel="apply_rows_sr",reason="values_unmapped"' in text.replace(
         '", ', '",')
+
+
+# ------------------------------------------- the window of DMAs in flight
+#
+# A grid step keeps up to fl._GROUP * fl._AHEAD row DMAs in flight, rows
+# start in groups of fl._GROUP, and a row goes HBM to HBM: from the table to
+# its row of the result (the gather), from its row of the updates to the
+# table (the scatter). The kernels only copy, so every case is the XLA
+# oracle's bit for bit.
+
+K_ = fl._GROUP * fl._AHEAD
+CW = 640   # rows a table here: room for unique slots past four windows
+
+
+def _wvals(seed, dtype=jnp.float32, lead=(2,)):
+    rng = np.random.default_rng(seed)
+    return jnp.asarray(
+        rng.normal(0, 1, (*lead, CW, D_)).astype(np.float32)).astype(dtype)
+
+
+def _wslots(seed, n, lead=(2,)):
+    rng = np.random.default_rng(seed)
+    flat = np.stack([rng.permutation(CW)[:n]
+                     for _ in range(int(np.prod(lead)))])
+    return flat.reshape(*lead, n).astype(np.int32)
+
+
+def _xla_gather(vals, ix):
+    return jnp.take_along_axis(
+        vals, jnp.clip(ix, 0, vals.shape[-2] - 1)[..., None], axis=-2)
+
+
+def _xla_scatter(vals, ix, rows):
+    C = vals.shape[-2]
+    one = lambda v, i, r: v.at[jnp.where(i >= 0, i, C)].set(  # noqa: E731
+        r.astype(v.dtype), mode="drop")
+    for _ in range(vals.ndim - 2):
+        one = jax.vmap(one)
+    return one(vals, ix, rows)
+
+
+WINDOW_SHAPES = [  # (rows, block= or None: one grid step a table)
+    (1, None), (K_ - 3, None), (K_ + 1, None),   # round the window's rows
+    (13, None), (21, 8),                         # not a group, not a tile
+    (31, 32), (33, 32),                          # a block less one, plus one
+    (255, None), (257, None),                    # groups and a row, less one
+    (2 * K_, K_),                                # a block of exactly a window
+]
+
+
+@pytest.mark.parametrize("n,block", WINDOW_SHAPES)
+def test_windowed_gather_is_the_oracle_bit_for_bit(n, block):
+    """Out-of-range indices on both sides (clipped) and duplicates."""
+    vals = _wvals(40)
+    rng = np.random.default_rng(41 + n)
+    ix = rng.integers(-4, CW + 4, (2, n))
+    ix[:, n // 2:] = ix[:, : n - n // 2]         # every row read twice
+    ix = jnp.asarray(ix, jnp.int32)
+    got = jax.vmap(lambda v, i: gather_rows(
+        v, i, block=block, interpret=True))(vals, ix)
+    _same(got, _xla_gather(vals, ix))
+
+
+def _skip(ix, pattern, block):
+    ix = ix.copy()
+    if pattern == "all":
+        ix[:] = -1
+    elif pattern == "every_other":
+        ix[..., ::2] = -1
+    elif pattern == "odd":
+        ix[..., 1::2] = -1
+    elif pattern == "block_ends":    # the first and last row of a block or group
+        ix[..., ::block] = -1
+        ix[..., block - 1::block] = -1
+    return ix
+
+
+@pytest.mark.parametrize("pattern",
+                         ["none", "all", "every_other", "odd", "block_ends"])
+@pytest.mark.parametrize("n,block", [(K_ - 3, None), (33, 32), (96, 32),
+                                     (257, None)])
+def test_windowed_scatter_is_the_oracle_bit_for_bit(n, block, pattern):
+    """A skipped slot starts nothing and is waited for never, wherever it
+    stands in the window; every other row lands."""
+    vals = _wvals(42)
+    ix = jnp.asarray(_skip(_wslots(43 + n, n), pattern, block or fl._GROUP))
+    rows = jnp.asarray(np.random.default_rng(44).normal(
+        0, 1, (2, n, D_)).astype(np.float32))
+    got = jax.vmap(lambda v, i, r: apply_rows_sr(
+        v, i, r, jnp.int32(0), block=block, interpret=True))(vals, ix, rows)
+    _same(got, _xla_scatter(vals, ix, rows))
+
+
+@pytest.mark.parametrize("n,block", [(13, None), (33, 32), (48, 16)])
+@pytest.mark.parametrize("seeds", ["mapped", "unmapped"])
+def test_windowed_bf16_scatter_rounds_as_the_loop_and_as_xla(n, block, seeds):
+    """The stochastic-rounding branch: a rounded copy of the block, rows
+    sent from there. A table's bits are those of its own unbatched call and
+    of the XLA scatter (drawn at [n, D], whatever the block)."""
+    vals = _wvals(45, jnp.bfloat16, (3,))
+    ix = jnp.asarray(_skip(_wslots(46, n, (3,)), "every_other", 8))
+    rows = jnp.asarray(np.random.default_rng(47).normal(
+        0, 1, (3, n, D_)).astype(np.float32))
+    seed = (jnp.arange(3, dtype=jnp.int32) + 3 if seeds == "mapped"
+            else jnp.int32(11))
+    axes = (0, 0, 0, 0 if seeds == "mapped" else None)
+    kernel = lambda v, i, r, s: apply_rows_sr(  # noqa: E731
+        v, i, r, s, block=block, interpret=True)
+    xla = lambda v, i, r, s: apply_rows_sr(  # noqa: E731
+        v, i, r, s, use_pallas=False)
+    got = jax.vmap(kernel, in_axes=axes)(vals, ix, rows, seed)
+    each = jnp.broadcast_to(seed, (3,))
+    _same(got, _loop(kernel, vals, ix, rows, each))
+    _same(got, _loop(xla, vals, ix, rows, each))
+
+
+@pytest.mark.parametrize("vmaps", [1, 2])
+def test_windowed_kernels_under_one_and_two_vmaps_over_many_blocks(vmaps):
+    """Shards x tables folded into the table axis, three blocks a table."""
+    lead = (2, 2)[:vmaps]
+    n, block = 3 * 32 - 5, 32
+    vals = _wvals(48, lead=lead)
+    ix = jnp.asarray(_skip(_wslots(49, n, lead), "block_ends", block))
+    rows = jnp.asarray(np.random.default_rng(50).normal(
+        0, 1, (*lead, n, D_)).astype(np.float32))
+    g = lambda v, i: gather_rows(  # noqa: E731
+        v, i, block=block, interpret=True)
+    s = lambda v, i, r: apply_rows_sr(  # noqa: E731
+        v, i, r, jnp.int32(0), block=block, interpret=True)
+    for _ in range(vmaps):
+        g, s = jax.vmap(g), jax.vmap(s)
+    _same(g(vals, jnp.maximum(ix, 0)), _xla_gather(vals, jnp.maximum(ix, 0)))
+    _same(s(vals, ix, rows), _xla_scatter(vals, ix, rows))
+
+
+@pytest.mark.parametrize("n", [1, 6, 64, 2304, 8200, 16384])
+def test_a_grid_step_carries_all_of_a_tables_rows(n):
+    """Rows go HBM to HBM, so no block in VMEM bounds a grid step: a call
+    of a few rows and the cells' calls alike are one step a table, and no
+    index is padded (only an explicit `block=` splits a table's rows)."""
+    vals = jnp.zeros((2, 64, D_), jnp.float32)
+    ix = jnp.zeros((2, n), jnp.int32)
+    rows = jnp.zeros((2, n, D_), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda v, i, r: (
+        jax.vmap(_gather)(v, i),
+        jax.vmap(_apply, in_axes=(0, 0, 0, None))(v, i, r, jnp.int32(0)),
+    ))(vals, ix, rows).jaxpr
+    calls = [e for e in _walk(jaxpr) if e.primitive.name == "pallas_call"]
+    assert len(calls) == 2
+    for eqn in calls:
+        assert eqn.params["grid_mapping"].grid == (2, 1)
+        assert eqn.invars[1].aval.shape == (2 * n,)
+    split = jax.make_jaxpr(lambda v, i: gather_rows(
+        v, i, block=8, interpret=True))(vals[0], ix[0]).jaxpr
+    (eqn,) = [e for e in _walk(split) if e.primitive.name == "pallas_call"]
+    assert eqn.params["grid_mapping"].grid == (1, -(-n // 8))
+
+
+def test_the_schedule_a_table_rides_is_on_the_registry():
+    from deeprec_tpu.obs.metrics import default_registry
+
+    vals = _wvals(51)[0]
+    gather_rows(vals, jnp.arange(40, dtype=jnp.int32), interpret=True)
+    text = default_registry().render_prometheus().replace('", ', '",')
+    assert "deeprec_pallas_row_schedule{" in text
+    assert (f'block="40",kernel="gather_rows",rows="40",shape="1x{CW}x128",'
+            f'window="{K_}"') in text

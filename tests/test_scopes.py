@@ -448,7 +448,9 @@ def test_a_kernel_serialises_the_same_from_two_callers(monkeypatch, tmp_path):
     its program with the locations of its trace, and so into the compile
     cache's key. With one frame a location the kernel's own line is all
     that is left, and the body is the same bytes whoever calls it; with
-    jax's default ten frames the callers are in it and it is not."""
+    jax's default ten frames the callers are in it and it is not. (Shown on
+    `fused_gather_combine`: the two row kernels' calls are jitted since
+    PR 32, so one trace serves every caller whatever the setting.)"""
     from deeprec_tpu.ops import fused_lookup
     from deeprec_tpu.utils import backend
 
@@ -457,17 +459,18 @@ def test_a_kernel_serialises_the_same_from_two_callers(monkeypatch, tmp_path):
     name = "jax_traceback_in_locations_limit"
     before = getattr(jax.config, name)
     args = (jax.ShapeDtypeStruct((4096, 128), jnp.float32),
-            jax.ShapeDtypeStruct((512,), jnp.int32))
+            jax.ShapeDtypeStruct((64, 8), jnp.int32),
+            jax.ShapeDtypeStruct((64, 8), jnp.float32))
 
     def bodies():  # functions of their own: jax keeps a lowering by them
-        def one(values, ix):
-            return fused_lookup.gather_rows(values, ix)
+        def one(values, ix, w):
+            return fused_lookup.fused_gather_combine(values, ix, w)
 
-        def other(values, ix):
-            def deeper(values, ix):
-                return fused_lookup.gather_rows(values, ix) + 0
+        def other(values, ix, w):
+            def deeper(values, ix, w):
+                return fused_lookup.fused_gather_combine(values, ix, w) + 0
 
-            return deeper(values, ix)
+            return deeper(values, ix, w)
 
         found = []
         for fn in (one, other):
