@@ -23,7 +23,10 @@ expert block, the head with its loss), and inside a block the part a
 roofline is read for (`gdn_rule`, tight round the delta rule as `rows_*`
 stands round a row kernel; `moe_dispatch`, `moe_experts`). They are two
 groups so that a block's time holds its parts' (a reader picks the
-innermost name of EACH group).
+innermost name of EACH group). A stack that mixes window and global
+attention layers (models/window_stack.py) names, tight round the flash
+call inside `block_attn`, which of the two a layer is (`attn_window`,
+`attn_global`: a third group).
 
 jax wraps a scope's name in the transforms it is traced under
 (`vmap(engine_probe)`, `transpose(jvp(phase_dense_fwd_bwd))`); a reader
@@ -90,6 +93,10 @@ GDN_RULE = "gdn_rule"
 MOE_DISPATCH = "moe_dispatch"
 MOE_EXPERTS = "moe_experts"
 BLOCK_PARTS = (GDN_RULE, MOE_DISPATCH, MOE_EXPERTS)
+# Tight round the flash call of a stack that mixes the two kinds of layer.
+ATTN_WINDOW = "attn_window"
+ATTN_GLOBAL = "attn_global"
+ATTN_PARTS = (ATTN_WINDOW, ATTN_GLOBAL)
 
 # What a layer's remat keeps instead of making again (`checkpoint_name`s; no
 # trace shows them): the experts each token chose and the rows they were
@@ -211,6 +218,7 @@ def vocabulary() -> dict:
         "groups": {
             "block": {"pick": "innermost", "names": list(BLOCKS)},
             "block_part": {"pick": "innermost", "names": list(BLOCK_PARTS)},
+            "attn_part": {"pick": "innermost", "names": list(ATTN_PARTS)},
             "probe_part": {"pick": "innermost", "names": list(PROBE_PARTS)},
         },
     }

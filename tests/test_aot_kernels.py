@@ -161,17 +161,66 @@ def test_flash_kernels_compile_at_grouped_queries_and_head_dim_256(
     assert "bf16[16,8192,256]{2,1,0} broadcast" not in hlo
 
 
-def test_grouped_products_compile_at_the_cells_widths(one_chip, monkeypatch):
-    """The expert layer's three kernels at 32 held experts of width 512 on
-    a hidden size of 2048 and a budget of 24,576 pairs: both products'
-    shapes, forward and backward."""
-    from deeprec_tpu.ops import moe
+@pytest.mark.parametrize("window", [None, 4096])
+def test_flash_kernels_compile_at_seven_query_heads_a_group_and_a_window(
+        one_chip, monkeypatch, window):
+    """28 query heads over 4 key/value heads, head dim 128, L = 16,384,
+    causal with and without a window of 4,096, bf16 operands, blocks of
+    512: the three kernels, their walks as long as the window makes them."""
+    from deeprec_tpu.ops.flash_attention import (
+        _visible_keys, _visible_queries, _walk, flash_attention)
+    from deeprec_tpu.utils import scopes
 
     monkeypatch.setattr(backend, "on_tpu", lambda: True)
     sd = _sd(one_chip)
-    held, block = 32, 128
-    nb = moe.num_blocks(24576, held, block)
-    for K, N in ((2048, 512), (512, 2048)):
+
+    def step(q, k, v, mask):
+        o, vjp = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, mask, True, 128 ** -0.5, 512, 512, False, window),
+            q, k, v)
+        return o, vjp(o)
+
+    hlo = jax.jit(step).lower(
+        sd((1, 28, 16384, 128), jnp.bfloat16),
+        sd((1, 4, 16384, 128), jnp.bfloat16),
+        sd((1, 4, 16384, 128), jnp.bfloat16), sd((1, 16384), jnp.bool_)
+    ).compile().as_text()
+    for name in (scopes.KERNEL_FLASH_FWD, scopes.KERNEL_FLASH_BWD_DKDV,
+                 scopes.KERNEL_FLASH_BWD_DQ):
+        assert name in hlo, name
+    assert "bf16[28,16384,128]{2,1,0} broadcast" not in hlo
+    walk = (512, 512, 32, True, window)
+    assert _walk(_visible_keys, 32, *walk)[0] \
+        == _walk(_visible_queries, 32, *walk)[0] \
+        == (32 if window is None else 9)
+
+
+def _pair_budget(mix: str) -> int:
+    """The budget of pairs a token cell runs: its traffic mix's, as
+    committed."""
+    import json
+    import pathlib
+
+    path = pathlib.Path(__file__).parents[1] / "benchmark" / "traffic"
+    return json.loads((path / f"{mix}.json").read_text())["pair_budget"]
+
+
+@pytest.mark.parametrize("held,width,hidden,mix", [
+    (32, 512, 2048, "seq8k-zipf11"), (8, 768, 2560, "seq16k-zipf11")])
+def test_grouped_products_compile_at_the_cells_widths(
+        one_chip, monkeypatch, held, width, hidden, mix):
+    """The expert layer's three kernels at the two token cells' shapes (32
+    held experts of width 512 on a hidden size of 2048; 8 of width 768 on
+    2560), each at the budget of pairs its traffic mix commits: both
+    products' shapes, forward and backward."""
+    from deeprec_tpu.ops import moe
+
+    budget = _pair_budget(mix)
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    sd = _sd(one_chip)
+    block = 128
+    nb = moe.num_blocks(budget, held, block)
+    for K, N in ((hidden, width), (width, hidden)):
         def step(x, w, block_expert):
             y, vjp = jax.vjp(lambda x, w: moe.grouped_matmul(
                 x, w, block_expert, block), x, w)

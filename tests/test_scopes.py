@@ -213,6 +213,48 @@ def test_the_token_stack_names_its_blocks_and_parts():
                    for (_, n), s in zip(names, got)), block
 
 
+def test_the_window_stack_names_its_attention_parts():
+    """The window-and-global stack: every layer's flash call stands under
+    `attn_window` or `attn_global` inside `block_attn`, forward and
+    backward; the router, which runs BEFORE attention, stands under
+    `block_moe` and `moe_dispatch` all the same; no delta net is named."""
+    from deeprec_tpu.models import WindowStackLM
+
+    m = WindowStackLM(
+        vocab=48, seq_len=32, capacity=128, pair_budget=256, hidden=32,
+        layers=4, sliding_window_layout=(0, 1, 1, 1),
+        rope_layout=(0, 1, 1, 1), sliding_window=8, attn_heads=4,
+        attn_kv_heads=2, head_dim=16, rope_theta=1.5e6, num_experts=16, experts_per_token=4, expert_width=16,
+        held_experts=(4, 4), flash_block=16, moe_block=8, loss_block=16)
+    tr = Trainer(m, Adagrad(lr=0.05), optax.adam(1e-3), unique_budget=40)
+    tok = jnp.arange(2 * 33, dtype=jnp.int32).reshape(2, 33) % 48
+    batch = {"tok": tok[:, :-1], "label": tok[:, 1:]}
+    names = op_names(tr._train_step.lower(tr.init(0), batch, None).compile())
+    assert_every_instruction_has_a_phase(names)
+    got = [phase_reduce.scope_of(n, VOCAB) for _, n in names]
+    assert {s.block for s in got} - {""} == set(scopes.BLOCKS) - {
+        scopes.BLOCK_GDN}
+    assert {s.block_part for s in got} - {""} == {scopes.MOE_DISPATCH,
+                                                  scopes.MOE_EXPERTS}
+    assert {s.attn_part for s in got} - {""} == set(scopes.ATTN_PARTS)
+    assert all(s.block == scopes.BLOCK_ATTN for s in got if s.attn_part)
+    assert all(s.block == scopes.BLOCK_MOE for s in got if s.block_part)
+    for part in scopes.ATTN_PARTS:
+        assert any(s.attn_part == part and "transpose(" in n
+                   for (_, n), s in zip(names, got)), part
+    # the router's top-k is the expert block's, though attention follows it
+    routed = [s for (op, n), s in zip(names, got) if "top_k" in n]
+    assert routed and all(s.block_part == scopes.MOE_DISPATCH
+                          for s in routed)
+    dense = [(op, s) for (op, _), s in zip(names, got)
+             if s.phase == scopes.PHASE_DENSE_FWD_BWD and op not in FREE]
+    # what is left is the two norms of a layer (one feeds the router AND the
+    # mixer), the residual adds and the embedding's cast, as in the hybrid
+    # stack; at this size they are a larger share of fewer operations
+    bare = [op for op, s in dense if not s.block]
+    assert len(bare) < 0.15 * len(dense), (len(bare), len(dense))
+
+
 def test_a_read_only_lookup_shows_no_insert():
     """Eval and serving resolve with `train=False`: nothing is created or
     stamped, and a trace of it must not show time under `engine_insert`."""
