@@ -46,6 +46,34 @@ def empty_key(cfg: TableConfig) -> int:
     return int(jnp.iinfo(_key_dtype(cfg)).min)
 
 
+# The ids the probe's find loop settles at a time: a pass holds one window
+# of keys an id ([ids, 128], 512 bytes an id of int32 keys), so more ids
+# than this walk the loop in slices of this many (`_probe`). A train
+# step's unique ids (2.3k / 8.2k a table in the benchmark's cells) are one
+# slice; the maintenance callers, which probe a whole table's slots at once
+# (rebuild, and through it evict, grow, maintain; a restore), are many: a
+# window of [2^18, 128] keys a table would be 128 MiB a table, and its 2^18
+# row indices more than the row kernel's SMEM holds. 2^14 ids are 8 MiB a
+# table and 64 KiB of indices. Nothing sets it.
+_PROBE_SLICE = 1 << 14
+
+
+def _note_probe_window(table: str, width: int, passes: int) -> None:
+    """What a table's find loop was built with, at TRACE time (the key
+    array's shape and `max_probes` are static), as
+    deeprec_probe_window{table,width,max_passes} 1: a gauge, so noting it
+    again on a retrace changes nothing (fused_lookup._note_schedule)."""
+    from deeprec_tpu.obs.metrics import default_registry
+
+    default_registry().gauge(
+        "deeprec_probe_window",
+        help="Slots the probe's find loop reads an id a pass, and the "
+             "passes that cover max_probes",
+        labels={"table": table, "width": str(width),
+                "max_passes": str(passes)},
+    ).set(1)
+
+
 # Row indices of the packed per-slot metadata leaf (TableState.meta, [3, C]):
 # freq / version / dirty live in ONE int32 array so the train hot path
 # updates all three with a single fused scatter instead of three. The layout
@@ -352,6 +380,26 @@ class EmbeddingTable:
             use_pallas=self.use_pallas, pair_kernels=self.pair_kernels,
         )
 
+    def _key_window(self, key_rows: jnp.ndarray,
+                    row_ix: jnp.ndarray) -> jnp.ndarray:
+        """key_rows[row_ix] for the probe's find loop: the key array as
+        [C / W, W] rows, one row an id, [U, W]. A negative row index is an
+        id that reads nothing (the caller masks that row out). Rows of 128
+        int32 keys ride the row kernel like a value row (it folds the table
+        vmap and splits a call over table ranges by itself). Whether they
+        do is decided HERE, so that a key read the kernel cannot take is no
+        fallback of the value gather's on /metrics: int64 keys, a table
+        under one lane tile and kernel="xla" take XLA's row gather (as
+        every backend but the TPU does, inside `gather_rows`)."""
+        from deeprec_tpu.ops import fused_lookup
+
+        with scopes.scope(scopes.ROWS_GATHER):
+            if self.use_pallas and fused_lookup._dma_ok(
+                    key_rows.shape[1], key_rows.dtype):
+                return fused_lookup.gather_rows(key_rows, row_ix,
+                                                skip_negative=True)
+            return key_rows.at[row_ix].get(mode="clip")
+
     def _scatter(self, values: jnp.ndarray, slot_ix: jnp.ndarray,
                  rows: jnp.ndarray, capacity: int,
                  seed: jnp.ndarray | int = 0) -> jnp.ndarray:
@@ -465,41 +513,89 @@ class EmbeddingTable:
 
         Two loops. The FIND loop writes nothing and carries [U]-sized
         arrays only (under the table vmap its per-pass selects are [T, U],
-        never [T, C]): every id walks its chain, one gather a pass, to its
-        own key or to the first empty slot. That settles residency exactly:
+        never [T, C]). It sees the key array as rows of W = min(128, C)
+        consecutive slots and reads, a pass, ONE such row an id: the
+        aligned window that holds the next slots of the id's chain (pass p
+        reads row (home // W + p) mod C / W, so the wrap at the table's end
+        is the row index's). A row of 128 int32 keys is 512 bytes, what the
+        row kernel moves for the price of one scalar gather
+        (`_key_window`), and it holds the rest of most chains. Lane j of
+        that row is probe offset p * W + j - home % W; of the lanes whose
+        offset lies in [0, max_probes) the FIRST that holds the id's own
+        key or no key settles the id: found there, or absent with its
+        chain's first empty slot there. That settles residency exactly:
         linear probing without tombstones (rebuild re-hashes) keeps a
         resident key before the first empty slot of its chain, and uids are
         unique within a call, so nothing another id claims later can be
-        this id's key. The CLAIM loop starts every absent, creatable id at
-        the empty slot the find loop left it at and races them (scatter,
-        re-gather, the winner keeps the slot, losers move on); with nothing
-        to create it runs no pass. `max_probes` bounds the slots an id sees
-        over both loops together.
+        this id's key. The passes end when no id is pending or the offsets
+        are spent: ceil((W - 1 + max_probes) / W) of them at most, two at
+        the defaults, whatever the longest chain. More than _PROBE_SLICE
+        ids (a whole table's slots: rebuild, a restore) walk the loop a
+        slice at a time, so the window a pass holds is bounded by the
+        slice and not by the table. The CLAIM loop starts
+        every absent, creatable id at the empty slot the find loop left it
+        at and races them (scatter, re-gather, the winner keeps the slot,
+        losers move on); with nothing to create it runs no pass.
+        `max_probes` bounds the slots an id sees over both loops together.
         """
+        from deeprec_tpu.ops.fused_lookup import _LANES
+
         cfg = self.cfg
-        C = keys.shape[0]
+        C, U = keys.shape[0], uids.shape[0]
+        W = min(_LANES, C)
+        rows = C // W
+        passes = -(-(W - 1 + cfg.max_probes) // W)
+        _note_probe_window(cfg.name, W, passes)
         mask_c = jnp.uint32(C - 1)
         h = hashing.mix32(hashing.fold64(uids))
         sentinel = jnp.asarray(empty_key(cfg), keys.dtype)
+        key_rows = keys.reshape(rows, W)
+        lane = jnp.arange(W, dtype=jnp.int32)
 
         def position(off):
             return ((h + off.astype(jnp.uint32)) & mask_c).astype(jnp.int32)
 
-        def find_cond(carry):
-            step, pending, *_ = carry
-            return jnp.logical_and(step < cfg.max_probes, jnp.any(pending))
+        def find(uids, home):
+            """The find loop over one slice of the ids (their home slots
+            beside them): (unresolved, slot_ix, empty_at) of the slice."""
+            # W is a power of two, as C is
+            home_row, home_lane = home >> (W.bit_length() - 1), home & (W - 1)
 
-        def find_body(carry):
-            step, pending, slot_ix, empty_at = carry
-            pos = position(step)  # [U]
-            k = keys[pos]
-            found = pending & (k == uids)
-            slot_ix = jnp.where(found, pos, slot_ix)
-            # the first empty slot of the chain: the key is definitively
-            # absent, and this is where a creatable id starts its claim
-            at_empty = pending & (k == sentinel)
-            empty_at = jnp.where(at_empty, step, empty_at)
-            return step + 1, pending & ~(found | at_empty), slot_ix, empty_at
+            def find_cond(carry):
+                step, pending, *_ = carry
+                return jnp.logical_and(step < passes, jnp.any(pending))
+
+            def find_body(carry):
+                step, pending, slot_ix, empty_at = carry
+                row = (home_row + step) & (rows - 1)  # [U]
+                # a settled id reads nothing (and its lanes do not count)
+                window = self._key_window(
+                    key_rows, jnp.where(pending, row, -1))
+                off = step * W + lane - home_lane[:, None]  # [U, W]
+                counts = pending[:, None] & (off >= 0) & (
+                    off < cfg.max_probes)
+                own = window == uids[:, None]
+                settles = counts & (own | (window == sentinel))
+                # the first lane that settles the id and which of the two
+                # its key is, in one lane reduction: 2 * lane + (0 own,
+                # 1 empty)
+                first = jnp.min(
+                    jnp.where(settles, 2 * lane + (~own).astype(jnp.int32),
+                              2 * W), axis=1)
+                settled, at = first < 2 * W, first >> 1
+                found = settled & ((first & 1) == 0)
+                slot_ix = jnp.where(found, row * W + at, slot_ix)
+                # the first empty slot of the chain: the key is
+                # definitively absent, and this is where a creatable id
+                # starts its claim
+                empty_at = jnp.where(
+                    settled & ~found, step * W + at - home_lane, empty_at)
+                return step + 1, pending & ~settled, slot_ix, empty_at
+
+            none = jnp.full(uids.shape, -1, jnp.int32)
+            return jax.lax.while_loop(
+                find_cond, find_body,
+                (jnp.int32(0), uids != sentinel, none, none))[1:]
 
         def claim_cond(carry):
             pending, *_ = carry
@@ -519,12 +615,19 @@ class EmbeddingTable:
             pending = pending & ~won & (off < cfg.max_probes)
             return pending, off, slot_ix, keys
 
-        none = jnp.full(uids.shape, -1, jnp.int32)
+        home = (h & mask_c).astype(jnp.int32)
         with scopes.scope(scopes.PROBE_FIND):
-            _, unresolved, slot_ix, empty_at = jax.lax.while_loop(
-                find_cond, find_body,
-                (jnp.int32(0), uids != sentinel, none, none),
-            )
+            if U <= _PROBE_SLICE:
+                unresolved, slot_ix, empty_at = find(uids, home)
+            else:  # slices of the ids, one after another, the last padded
+                pad = -U % _PROBE_SLICE
+                unresolved, slot_ix, empty_at = (
+                    x.reshape(-1)[:U] for x in jax.lax.map(
+                        lambda xs: find(*xs), (
+                            jnp.pad(uids, (0, pad), constant_values=sentinel
+                                    ).reshape(-1, _PROBE_SLICE),
+                            jnp.pad(home, (0, pad)
+                                    ).reshape(-1, _PROBE_SLICE))))
         with scopes.scope(scopes.PROBE_CLAIM):
             # absent and not creatable: given up (slot_ix -1, not failed)
             claiming = (empty_at >= 0) & want_create

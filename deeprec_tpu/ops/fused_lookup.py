@@ -271,7 +271,7 @@ def _wait_rows(table_ref, k: int, sem) -> None:
         pltpu.make_async_copy(done, done, sem).wait()
 
 
-def _row_window(rows: int, start, wait, live=None) -> None:
+def _row_window(rows: int, start, wait, live=None, moved=None) -> None:
     """One grid step's `rows` row DMAs, a window of them in flight: rows
     start in groups of _GROUP (unrolled: one loop branch a group), a group
     is waited for as the group _AHEAD after it starts, and what is left in
@@ -284,44 +284,64 @@ def _row_window(rows: int, start, wait, live=None) -> None:
     all of them. `live(i)`, where given, says whether row i moves at all (a
     skipped slot, a row past the call's last): a row that does not starts
     nothing and is waited for never, because a group counts the rows it
-    started and is waited for by that count."""
+    started and is waited for by that count. `moved(g)`, where given beside
+    it, is that count of group g, made before the call: a group that moves
+    whole then starts its rows without a test a row, one that moves none
+    reads one number, and only a mixed group asks `live` row by row."""
     from jax.experimental import pallas as pl
 
     groups, rest = divmod(rows, _GROUP)
 
-    def start_group(first, size):
-        """Start rows [first, first + size); how many of them moved."""
+    def start_group(g, size):
+        """Start rows [g * _GROUP, g * _GROUP + size); how many moved."""
+        first = g * _GROUP
         if live is None:
             for u in range(size):
                 start(first + u)
             return jnp.int32(size)
-        ok = [live(first + u) for u in range(size)]
-        moved = sum((o.astype(jnp.int32) for o in ok), jnp.int32(0))
+        if moved is None:
+            ok = [live(first + u) for u in range(size)]
+            count = sum((o.astype(jnp.int32) for o in ok), jnp.int32(0))
 
-        @pl.when(moved > 0)  # one branch for a group that starts nothing
+            @pl.when(count > 0)  # one branch for a group that starts nothing
+            def _():
+                for u in range(size):
+                    pl.when(ok[u])(functools.partial(start, first + u))
+
+            return count
+        if size == 0:
+            return jnp.int32(0)
+        count = moved(g)
+
+        @pl.when(count == size)
         def _():
             for u in range(size):
-                pl.when(ok[u])(functools.partial(start, first + u))
+                start(first + u)
 
-        return moved
+        @pl.when((count > 0) & (count < size))
+        def _():
+            for u in range(size):
+                pl.when(live(first + u))(functools.partial(start, first + u))
 
-    def wait_rows(moved):
+        return count
+
+    def wait_rows(count):
         """A whole group in ONE wait, else a row at a time."""
-        whole = moved == _GROUP
+        whole = count == _GROUP
         pl.when(whole)(lambda: wait(_GROUP))
-        jax.lax.fori_loop(0, jnp.where(whole, 0, moved),
+        jax.lax.fori_loop(0, jnp.where(whole, 0, count),
                           lambda j, _: wait(1) or 0, 0)
 
     def step(g, in_flight):
         # the rows the last _AHEAD groups started, oldest first: none yet
         # for the first of them, so the first waits are for nothing
         wait_rows(in_flight[0])
-        return in_flight[1:] + (start_group(g * _GROUP, _GROUP),)
+        return in_flight[1:] + (start_group(g, _GROUP),)
 
     in_flight = (jnp.int32(0),) * _AHEAD
     if groups:
         in_flight = jax.lax.fori_loop(0, groups, step, in_flight)
-    wait_rows(sum(in_flight) + start_group(groups * _GROUP, rest))
+    wait_rows(sum(in_flight) + start_group(groups, rest))
 
 
 def _sr_bits(seed, shape):
@@ -515,14 +535,19 @@ def apply_rows_sr_pair(values: jnp.ndarray, slot_ix: jnp.ndarray,
 
 def gather_rows(values: jnp.ndarray, ix: jnp.ndarray, *,
                 block: int | None = None, interpret: bool = False,
-                pair_kernels: bool = False) -> jnp.ndarray:
+                pair_kernels: bool = False,
+                skip_negative: bool = False) -> jnp.ndarray:
     """values [C, D], ix [n] int32 -> [n, D]; out-of-range ix clamp (the
     'clip' semantics of the jnp fallback). Rows go by DMA, HBM to HBM, a
     window of them in flight (_row_window); `block=` splits a table's rows
     into grid steps of that many (default: one step carries them all).
     pair_kernels=True additionally routes eligible bf16 tables through the
     pair-granule kernel (explicit kernel="pallas" or a measured-winners
-    flag — see AUTO_TRUSTS_BF16_PAIR)."""
+    flag — see AUTO_TRUSTS_BF16_PAIR). skip_negative=True is for a caller
+    that masks those rows out itself: the row kernel then starts no DMA for
+    a negative index, as the scatter starts none for a skipped slot, and
+    that row of the result holds whatever its memory held (on the other
+    paths it holds some row of the table)."""
     if pair_kernels and _dma_pair_ok(values.shape, values.dtype) and (
         interpret or backend.on_tpu()
     ):
@@ -536,46 +561,83 @@ def gather_rows(values: jnp.ndarray, ix: jnp.ndarray, *,
                        values.shape, values.dtype)
         return values.at[ix].get(mode="clip")
 
-    return _gather_rows_op(block, interpret)(values[None], ix[None])[0]
+    return _gather_rows_op(block, interpret, skip_negative)(
+        values[None], ix[None])[0]
 
 
-def _gather_rows_stacked(values, ix, *, block, interpret):
+def _gather_rows_stacked(values, ix, *, block, interpret, skip):
     """The gather kernel, with its table axis: values [T, C, D], ix [T, n]
     -> [T, n, D]. One call for a range of tables (_table_ranges; all of
-    them while their indices fit the budget). A grid step carries all of a
+    them while their indices fit the budget), and where ONE table's
+    indices pass it, calls over row ranges. A grid step carries all of a
     table's rows unless `block=` splits them; indices are padded only
     then, and a padded row starts no DMA."""
     n = ix.shape[1]
+    # ONE table's indices over the SMEM budget (a whole table's slots
+    # probed at once; a vmap over indices folded into one unmapped table):
+    # calls over row ranges, as _table_ranges makes calls over tables
+    most = _SMEM_INDEX_BYTES // 4
+    most -= most // _GROUP if skip else 0  # the groups' counts ride too
+    if n > most:
+        return jnp.concatenate([
+            _gather_rows_stacked(values, ix[:, r0:r0 + most], block=block,
+                                 interpret=interpret, skip=skip)
+            for r0 in range(0, n, most)], axis=1)
     block = block or max(n, 1)
     _note_schedule(scopes.KERNEL_GATHER_ROWS, values.shape, n, block)
     ixp = _pad_rows(ix.astype(jnp.int32), block)
+    # under `skip`, how many rows of each group move: [T, groups] beside the
+    # indices in SMEM, so that the kernel tests a group and not its rows
+    moved = _group_counts(ixp, n, block) if skip else None
     outs = [
         _gather_call(jnp.full((1,), t0, jnp.int32), ixp[t0:t0 + tables],
-                     values, n=n, block=block, interpret=interpret)
-        for t0, tables in _table_ranges(values.shape[0], ixp.shape[1])
+                     values, moved[t0:t0 + tables] if skip else None,
+                     n=n, block=block, interpret=interpret)
+        for t0, tables in _table_ranges(
+            values.shape[0], ixp.shape[1] + (moved.shape[1] if skip else 0))
     ]
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs)
 
 
+def _group_counts(ixp, n, block):
+    """The rows that move (index >= 0, inside the call's n) of each group
+    of _GROUP rows as _row_window walks them: [T, blocks * groups a block],
+    a block's last group the rest of it."""
+    tables, np_ = ixp.shape
+    live = (ixp >= 0) & (jnp.arange(np_) < n)
+    per = -(-block // _GROUP)
+    live = jnp.pad(live.reshape(tables, np_ // block, block),
+                   ((0, 0), (0, 0), (0, per * _GROUP - block)))
+    return live.reshape(tables, -1, _GROUP).sum(-1, dtype=jnp.int32)
+
+
 @functools.partial(jax.jit, static_argnames=("n", "block", "interpret"))
-def _gather_call(t0, ixp, values, *, n, block, interpret):
+def _gather_call(t0, ixp, values, moved=None, *, n, block, interpret):
     """One Pallas call of the gather: the tables [t0, t0 + len(ixp)) of
     values [T, C, D] at the (padded) indices ixp -> [len(ixp), n, D]. The
     grid is (table, row block), the indices are one flat scalar prefetch,
     and a row's DMA goes from values_ref[t0 + t, idx] in the whole stacked
     array straight to its row of the result, HBM to HBM with no block in
-    VMEM, a window of them in flight (_row_window). Jitted, so that a
-    step's calls of one shape are traced and lowered once."""
+    VMEM, a window of them in flight (_row_window). With `moved`
+    (skip_negative: _group_counts of ixp, a third scalar prefetch) a
+    negative index is a row that does not move. Jitted, so that a step's
+    calls of one shape are traced and lowered once."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     _, C, D = values.shape
     tables, np_ = ixp.shape
+    skip = moved is not None
 
-    def kernel(t0_ref, ix_ref, values_ref, out_ref, sem):
+    def kernel(t0_ref, ix_ref, *refs):
+        moved_ref = refs[0] if skip else None
+        values_ref, out_ref, sem = refs[skip:]
         t = pl.program_id(0)
         row0 = pl.program_id(1) * block
         base = t * np_ + row0
+        if skip:  # this grid step's first group among `moved`'s
+            group0 = (t * (np_ // block) + pl.program_id(1)) * (
+                moved.shape[1] // (np_ // block))
 
         def start(i):
             idx = jnp.clip(ix_ref[base + i], 0, C - 1)
@@ -587,11 +649,16 @@ def _gather_call(t0, ixp, values, *, n, block, interpret):
         def wait(k):
             _wait_rows(values_ref.at[t0_ref[0] + t], k, sem)
 
+        def live(i):
+            inside = row0 + i < n
+            return inside & (ix_ref[base + i] >= 0) if skip else inside
+
         _row_window(block, start, wait,
-                    None if np_ == n else lambda i: row0 + i < n)
+                    live if skip or np_ != n else None,
+                    (lambda g: moved_ref[group0 + g]) if skip else None)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=2 + skip,
         grid=(tables, np_ // block),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
@@ -603,15 +670,15 @@ def _gather_call(t0, ixp, values, *, n, block, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((tables, n, D), values.dtype),
         interpret=interpret,
-    )(t0, ixp.reshape(-1), values)
+    )(t0, ixp.reshape(-1), *([moved.reshape(-1)] if skip else []), values)
 
 
 @functools.lru_cache(maxsize=None)
-def _gather_rows_op(block, interpret):
+def _gather_rows_op(block, interpret, skip=False):
     """_gather_rows_stacked under the hook that folds a vmap into its
     table axis (module docstring, "Batching")."""
     op = jax.custom_batching.custom_vmap(functools.partial(
-        _gather_rows_stacked, block=block, interpret=interpret
+        _gather_rows_stacked, block=block, interpret=interpret, skip=skip
     ))
 
     @op.def_vmap

@@ -15,7 +15,9 @@ run time. Three kinds nest in every step, as written:
 
 Inside `engine_probe` the probe's two loops stand under `probe_find` and
 `probe_claim` (a group of their own, so that the stage's time and passes
-hold both loops').
+hold both loops'). A pass of `probe_find` reads every pending id one
+aligned window of 128 consecutive keys (a row read under `rows_gather`, so
+a rows reader books it too), two passes at the default `max_probes`.
 
 A token model's stack (models/hybrid_stack.py) names two more inside
 `phase_dense_fwd_bwd`: `block_*`, the parts of a layer (the two mixers, the
@@ -72,9 +74,10 @@ ENGINE_INSERT = "engine_insert"
 ENGINE_GATHER = "engine_gather"
 STAGES = (ENGINE_ROUTE, ENGINE_PROBE, ENGINE_INSERT, ENGINE_GATHER)
 
-# The two loops of `engine_probe` (EmbeddingTable._probe): the read-only walk
-# to an id's key or its chain's first empty slot, and the race for the empty
-# slots, which runs no pass when no row is to be created.
+# The two loops of `engine_probe` (EmbeddingTable._probe): the read-only find
+# (a lane-wide window of an id's chain a pass, to the id's key or its chain's
+# first empty slot), and the race for the empty slots, which runs no pass
+# when no row is to be created.
 PROBE_FIND = "probe_find"
 PROBE_CLAIM = "probe_claim"
 PROBE_PARTS = (PROBE_FIND, PROBE_CLAIM)

@@ -283,29 +283,102 @@ def _body_of(hlo, loop):
             for n, i in instrs.items() if i["computation"] in inside]
 
 
-@pytest.mark.parametrize("n", [2304, 8200])   # `.zipf`, `.uniform`: U + 8
-def test_the_find_loop_gathers_once_a_pass_and_moves_no_keys(
-        one_chip, monkeypatch, n):
-    """`jax.vmap(EmbeddingTable._probe)` over the bundle's 26 key arrays:
-    the body of the find loop holds a gather and `[26, n]` elementwise
-    work, with no sort, no scatter and nothing that makes a value the key
-    arrays' size (the loop passes the keys through untouched; the `select`
-    that `vmap` makes of a `while` whose predicate differs by table is over
-    the carry, and the keys are no carry). The claim loop, which runs no
-    pass when no row is to be created, is where those stand."""
+def _probe_hlo(one_chip, monkeypatch, n, key_dtype="int32"):
     from deeprec_tpu import EmbeddingTable, TableConfig
 
     monkeypatch.setattr(backend, "on_tpu", lambda: True)
     sd = _sd(one_chip)
-    table = EmbeddingTable(TableConfig(name="t", dim=D, capacity=C))
-    hlo = jax.jit(jax.vmap(table._probe), donate_argnums=0).lower(
-        sd((T, C), jnp.int32), sd((T, n), jnp.int32),
+    table = EmbeddingTable(TableConfig(name="t", dim=D, capacity=C,
+                                       key_dtype=key_dtype))
+    kdt = jnp.dtype(key_dtype)
+    return jax.jit(jax.vmap(table._probe), donate_argnums=0).lower(
+        sd((T, C), kdt), sd((T, n), kdt),
         sd((T, n), jnp.bool_)).compile().as_text()
+
+
+@pytest.mark.parametrize("n", [2304, 8200])   # `.zipf`, `.uniform`: U + 8
+def test_the_find_loop_reads_a_window_of_keys_a_pass_through_the_row_kernel(
+        one_chip, monkeypatch, n):
+    """`jax.vmap(EmbeddingTable._probe)` over the bundle's 26 key arrays:
+    the body of the find loop reads one `[128]` row of keys an id through
+    `gather_rows`, a call a table range as the lookup's own gather splits
+    (one in `.zipf`, four in `.uniform`: ROADMAP D0), and reduces it to
+    `[26, n]`: no scalar gather of the keys, no sort, no scatter, and
+    nothing that makes a value the key arrays' size (the loop passes the
+    keys through untouched; the `select` that `vmap` makes of a `while`
+    whose predicate differs by table is over the carry, and the keys are no
+    carry). The claim loop, which runs no pass when no row is to be
+    created, is where those stand."""
+    hlo = _probe_hlo(one_chip, monkeypatch, n)
     passed_through = {"parameter", "get-tuple-element", "tuple"}
     find = _body_of(hlo, "probe_find")
     ops = {op for _, op in find}
-    assert "gather" in ops and not {"sort", "scatter"} & ops, ops
+    assert not {"gather", "sort", "scatter"} & ops, ops
+    reads = [r for r, op in find if op == "custom-call"]
+    ranges = fl._table_ranges(T, n)
+    assert len(reads) == len(ranges) == (1 if n == 2304 else 4)
+    assert sorted(r.split("{")[0] for r in reads) == sorted(
+        f"s32[{tables},{n},128]" for _, tables in ranges)
     assert not [(r, op) for r, op in find if op not in passed_through
-                and f"s32[{T},{C}]" in r]
+                and (f"s32[{T},{C}]" in r or f"s32[{T},{C // 128},128]" in r)]
+    # one lane reduction of the windows to [26, n] (no value the windows'
+    # size but what is fused into it), and the count of the rows that move
+    # in each group of 16, which the kernel reads in the rows' place
+    assert sorted(r.split("{")[0] for r, op in find
+                  if op == "reduce" and r.startswith("s32")) == sorted(
+        [f"s32[{T},{n}]", f"s32[{T},{-(-n // fl._GROUP)}]"])
     claim = {op for _, op in _body_of(hlo, "probe_claim")}
     assert {"sort", "scatter"} & claim, claim
+
+
+def test_the_find_loop_of_int64_keys_takes_xlas_row_gather(one_chip,
+                                                           monkeypatch):
+    """A row of 128 int64 keys is 1 KiB, which the row kernel does not move
+    (`_dma_ok` asks for 4-byte lanes): the window read is XLA's row gather,
+    `[26, n, 128]` keys a pass, and no Pallas call stands in the program."""
+    n = 2304
+    with jax.enable_x64(True):
+        hlo = _probe_hlo(one_chip, monkeypatch, n, key_dtype="int64")
+    assert "tpu_custom_call" not in hlo
+    # the compiler splits 64-bit keys into halves: a row gather of each
+    find = _body_of(hlo, "probe_find")
+    assert [r.split("{")[0] for r, op in find if op == "gather"] == [
+        f"u32[{T},{n},128]"] * 2
+    assert not fl._dma_ok(128, jnp.int64) and fl._dma_ok(128, jnp.int32)
+
+
+@pytest.mark.parametrize("tables,capacity,dim", [
+    (T, C, D),            # the cell's bundle in one vmap (Trainer.maintain)
+    (2, 1 << 20, 16),     # chip_smoke's tables: 4 MiB of indices a table
+])
+def test_rebuild_compiles_with_windows_of_a_slice_of_the_ids(
+        one_chip, monkeypatch, tables, capacity, dim):
+    """rebuild probes all C slots of a table at once, into a fresh key
+    array that `vmap` leaves unmapped: the find loop walks them a slice at
+    a time (table.py::_PROBE_SLICE), so the key windows a pass holds are
+    [tables x slice, 128] whatever the capacity, and the row kernel splits
+    ONE table's indices over calls where they pass SMEM's budget (Mosaic
+    refuses 1 MiB of them: the test above). Before, the windows were
+    [tables x C, 128] and the indices C a call."""
+    import re
+
+    from deeprec_tpu import EmbeddingTable, TableConfig
+    from deeprec_tpu.embedding import table as table_module
+
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    table = EmbeddingTable(TableConfig(name="t", dim=dim, capacity=capacity))
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: jax.vmap(lambda _: table.create())(
+            jnp.arange(tables))))
+    compiled = jax.jit(jax.vmap(table.rebuild),
+                       donate_argnums=0).lower(state).compile()
+    hlo = compiled.as_text()
+    windows = {(int(a), int(b))
+               for a, b in re.findall(r"s32\[(\d+),(\d+),128\]", hlo)}
+    ids = tables * table_module._PROBE_SLICE
+    assert (1, ids) in windows and max(a * b for a, b in windows) == ids
+    calls = [int(n) for n in re.findall(
+        r"s32\[1,(\d+),128\]\S* custom-call\([^\n]*tpu_custom_call", hlo)]
+    assert calls and max(calls) * 4 <= fl._SMEM_INDEX_BYTES, calls
+    assert sum(calls) == ids

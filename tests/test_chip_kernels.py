@@ -214,6 +214,141 @@ def test_all_skipped_scatter_at_the_cells_shape_touches_no_table():
     assert _equal(new, vals)
 
 
+def _walk(keys, uids, empty, max_probes):
+    """The scalar walk over [T, C] keys and [T, n] ids, a slot a step in
+    NumPy: (slot_ix, failed) of a read-only probe."""
+    from deeprec_tpu.utils import hashing
+
+    C = keys.shape[1]
+    home = np.asarray(hashing.mix32(hashing.fold64(jnp.asarray(uids))))
+    slot_ix = np.full(uids.shape, -1, np.int64)
+    pending = uids != empty
+    t = np.arange(keys.shape[0])[:, None]
+    for off in range(max_probes):
+        pos = ((home.astype(np.int64) + off) & (C - 1))
+        k = keys[t, pos]
+        found = pending & (k == uids)
+        slot_ix[found] = pos[found]
+        pending &= ~(found | (k == empty))
+    return slot_ix, pending
+
+
+@pytest.mark.parametrize("n", [2304, 8200])
+def test_probe_under_table_vmap_at_the_cells_shape(n):
+    """The find loop's window read at the cells' shape: 26 key arrays of
+    2^18 slots filled to a half through the probe itself, then every id a
+    table sees in a step (resident ones, absent ones, padding) found where
+    the scalar walk finds it; in the compiled program the loop's reads are
+    Mosaic calls, a table range each, and no scalar gather of the keys."""
+    from deeprec_tpu import EmbeddingTable, TableConfig
+    from deeprec_tpu.embedding.table import empty_key
+
+    table = EmbeddingTable(TableConfig(name="t", dim=LANES, capacity=C_CELL))
+    empty = empty_key(table.cfg)
+    probe = jax.jit(jax.vmap(table._probe), donate_argnums=0)
+    keys = jnp.full((T, C_CELL), empty, jnp.int32)
+    rng = np.random.default_rng(300 + n)
+    held = np.stack([rng.choice(1 << 30, C_CELL // 2, replace=False)
+                     for _ in range(T)]).astype(np.int32)
+    fill = 8192
+    for j in range(0, C_CELL // 2, fill):
+        chunk = jnp.asarray(held[:, j:j + fill])
+        keys, _, created, failed = probe(
+            keys, chunk, jnp.ones(chunk.shape, bool))
+        assert bool(created.all()) and not bool(failed.any())
+    uids = np.concatenate([
+        held[:, rng.permutation(C_CELL // 2)[:n - 64]],
+        rng.integers(1 << 30, (1 << 31) - 1, (T, 56)).astype(np.int32),
+        np.full((T, 8), empty, np.int32)], axis=1)
+    before = np.asarray(keys)
+    want_ix, want_failed = _walk(before, uids, empty, table.cfg.max_probes)
+    compiled = probe.lower(keys, jnp.asarray(uids),
+                           jnp.zeros(uids.shape, bool)).compile()
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == len(
+        fl._table_ranges(T, n))
+    new_keys, slot_ix, created, failed = compiled(
+        keys, jnp.asarray(uids), jnp.zeros(uids.shape, bool))
+    np.testing.assert_array_equal(np.asarray(slot_ix), want_ix)
+    np.testing.assert_array_equal(np.asarray(failed), want_failed)
+    assert not bool(created.any())
+    np.testing.assert_array_equal(np.asarray(new_keys), before)
+    assert (want_ix[:, :n - 64] >= 0).all() and (want_ix[:, n - 64:] < 0).all()
+
+
+@pytest.mark.parametrize("tables,capacity,dim", [
+    (T, C_CELL, LANES),     # the cell's bundle: every table's 2^18 slots
+    (1, 1 << 20, 16),       # one of chip_smoke's tables (packed rows)
+])
+def test_rebuild_at_capacity_walks_the_find_loop_in_slices(tables, capacity,
+                                                           dim):
+    """rebuild (and through it evict, grow, maintain) probes ALL slots of
+    a table into a fresh one: tables filled to a half, two thirds of the
+    keys kept. In the compiled program no window of keys is larger than a
+    slice of the ids (table.py::_PROBE_SLICE) a table and no row-kernel
+    call carries more indices than SMEM's budget; afterwards every kept
+    key stands where a read-only probe finds it, with the row it had, and
+    nothing else is held."""
+    import dataclasses
+    import re
+
+    from deeprec_tpu import EmbeddingTable, TableConfig
+    from deeprec_tpu.embedding import table as table_module
+    from deeprec_tpu.embedding.table import empty_key
+    from deeprec_tpu.ops.packed import pack_array, unpack_array
+
+    table = EmbeddingTable(TableConfig(name="t", dim=dim, capacity=capacity))
+    empty = empty_key(table.cfg)
+    half = capacity // 2
+    rng = np.random.default_rng(capacity + tables)
+    held = np.stack([rng.choice(1 << 30, half, replace=False)
+                     for _ in range(tables)]).astype(np.int32)
+    probe = jax.jit(jax.vmap(table._probe), donate_argnums=0)
+    keys = jnp.full((tables, capacity), empty, jnp.int32)
+    for j in range(0, half, 8192):
+        chunk = jnp.asarray(held[:, j:j + 8192])
+        keys, _, created, failed = probe(
+            keys, chunk, jnp.ones(chunk.shape, bool))
+        assert bool(created.all()) and not bool(failed.any())
+    # a row holds the number of the slot it stood in before the rebuild
+    rows = jnp.broadcast_to(
+        jnp.arange(capacity, dtype=jnp.float32)[:, None], (capacity, dim))
+    state = jax.jit(jax.vmap(lambda k: dataclasses.replace(
+        table.create(), keys=k,
+        values=pack_array(rows, table.pack_width(dim)))))(keys)
+    old_keys = np.asarray(keys)
+    keep = (np.arange(capacity) % 3 != 0)
+    rebuild = jax.jit(jax.vmap(
+        lambda s: table.rebuild(s, keep=jnp.asarray(keep))), donate_argnums=0)
+    compiled = rebuild.lower(state).compile()
+    hlo = compiled.as_text()
+    windows = {(int(a), int(b)) for a, b in
+               re.findall(r"s32\[(\d+),(\d+),128\]", hlo)}
+    assert windows and max(a * b for a, b in windows) <= (
+        tables * table_module._PROBE_SLICE), windows
+    assert 'custom_call_target="tpu_custom_call"' in hlo
+    new = compiled(state)
+    assert int(jnp.sum(new.insert_fails)) == 0
+    new_keys = np.asarray(new.keys)
+    for t in range(tables):
+        kept = old_keys[t][keep & (old_keys[t] != empty)]
+        assert np.array_equal(np.sort(new_keys[t][new_keys[t] != empty]),
+                              np.sort(kept))
+    # every kept key where a read-only probe finds it, with its old row
+    first = jax.jit(jax.vmap(
+        lambda v: unpack_array(v, capacity)[:, 0]))(new.values)
+    was = np.where(keep & (old_keys != empty), np.arange(capacity), -1)
+    uids = np.where(was >= 0, old_keys, empty)[:, :8192 * 4]
+    _, slot_ix, _, failed = jax.jit(jax.vmap(table._probe))(
+        new.keys, jnp.asarray(uids), jnp.zeros(uids.shape, bool))
+    slot_ix = np.asarray(slot_ix)
+    assert not bool(failed.any())
+    assert ((slot_ix >= 0) == (uids != empty)).all()
+    got = np.take_along_axis(np.asarray(first), np.maximum(slot_ix, 0), 1)
+    assert np.array_equal(got[slot_ix >= 0],
+                          was[:, :uids.shape[1]][slot_ix >= 0])
+
+
 # ----------------------------------------------------------- off the path
 
 C_BF16 = 1 << 17  # a dim-128 bf16 table: 32 MB

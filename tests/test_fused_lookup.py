@@ -440,6 +440,39 @@ def test_tables_past_the_smem_budget_split_into_ranges(monkeypatch):
     _same(apply_tables(vals, six, rows, seed), want_s)
 
 
+@pytest.mark.parametrize("n", [16, 17, 40, 64])
+@pytest.mark.parametrize("tables", ["one", "indices_over_one", "stacked"])
+def test_one_tables_rows_past_the_smem_budget_split_into_row_ranges(
+        monkeypatch, n, tables):
+    """A table range bottoms out at one table: where ONE table's indices
+    pass the budget (a whole table's slots probed at once in rebuild; a
+    vmap over indices folded into one unmapped table) the gather makes
+    calls over row ranges of the same whole array, as many as it takes,
+    with no loop and no table-sized slice, and the same bits."""
+    rng = np.random.default_rng(70 + n)
+    vals = _stack(37)
+    monkeypatch.setattr(fl, "_SMEM_INDEX_BYTES", 16 * 4)  # 16 rows a call
+    calls = -(-n // 16)
+    if tables == "one":
+        fn, args = _gather, (vals[0], jnp.asarray(
+            rng.integers(0, C_ + 4, (n,)), jnp.int32))
+        want = _xla_gather(*args)
+    elif tables == "indices_over_one":   # 2 x n rows of the one table
+        fn = jax.vmap(_gather, in_axes=(None, 0))
+        args = (vals[0], jnp.asarray(
+            rng.integers(0, C_ + 4, (2, n)), jnp.int32))
+        want, calls = jax.vmap(_xla_gather, in_axes=(None, 0))(*args), -(
+            -2 * n // 16)
+    else:   # every table of the stack over the budget by itself
+        fn, args = jax.vmap(_gather), (vals, jnp.asarray(
+            rng.integers(0, C_ + 4, (T_, n)), jnp.int32))
+        want = _xla_gather(*args)   # a row range, then its table ranges
+        calls = sum(len(fl._table_ranges(T_, min(16, n - r0)))
+                    for r0 in range(0, n, 16))
+    assert _mechanism(fn, *args) == (calls, 0, [])
+    _same(fn(*args), want)
+
+
 def test_updates_mapped_over_one_table_are_noted_as_a_fallback():
     """Folding them into one call would break the unique-slot contract:
     each gets a copy of the table, and the repo's fallback counter says
@@ -529,6 +562,30 @@ def _skip(ix, pattern, block):
         ix[..., ::block] = -1
         ix[..., block - 1::block] = -1
     return ix
+
+
+@pytest.mark.parametrize("pattern", ["none", "all", "every_other",
+                                     "block_ends"])
+@pytest.mark.parametrize("n,block", [(K_ - 3, None), (33, 32), (257, None)])
+def test_windowed_gather_that_skips_negative_rows(n, block, pattern):
+    """`skip_negative=True` (the probe's window read of int32 key rows): a
+    negative index starts no DMA and is waited for never, wherever it
+    stands in the window, and every other row of the result is the
+    oracle's; what a skipped row holds is the caller's to mask."""
+    vals = (_wvals(42) * 1000).astype(jnp.int32)
+    rng = np.random.default_rng(43 + n)
+    ix = _skip(rng.integers(0, CW + 4, (2, n)), pattern, block or fl._GROUP)
+    ix = jnp.asarray(ix, jnp.int32)
+    got = jax.vmap(lambda v, i: gather_rows(
+        v, i, block=block, interpret=True, skip_negative=True))(vals, ix)
+    read = np.asarray(ix) >= 0
+    np.testing.assert_array_equal(np.asarray(got)[read],
+                                  np.asarray(_xla_gather(vals, ix))[read])
+    # off the TPU the same call is XLA's gather, which reads those rows too
+    np.testing.assert_array_equal(
+        np.asarray(jax.vmap(lambda v, i: gather_rows(
+            v, i, skip_negative=True))(vals, ix))[read],
+        np.asarray(_xla_gather(vals, ix))[read])
 
 
 @pytest.mark.parametrize("pattern",

@@ -488,6 +488,11 @@ def test_the_find_loop_carries_no_keys_and_writes_nothing():
     assert all(shape in ((), (U,)) for shape in carry(find))
     body = _primitives(find.params["body_jaxpr"].jaxpr)
     assert "gather" in body and not {"scatter", "sort"} & body
+    # off the TPU the window read is XLA's row gather, [U, 128] keys a pass
+    assert not {"pallas_call", "custom_vmap_call"} & body
+    assert [e.outvars[0].aval.shape
+            for e in find.params["body_jaxpr"].jaxpr.eqns
+            if e.primitive.name == "gather"] == [(U, 128)]
     assert "scatter" in _primitives(claim.params["body_jaxpr"].jaxpr)
 
 
@@ -497,10 +502,10 @@ def test_the_find_loop_carries_no_keys_and_writes_nothing():
 def test_the_claim_loop_runs_no_pass_when_no_row_is_to_be_created(
         monkeypatch, name, creates):
     """The passes of the two loops, counted by running them eagerly: the
-    find loop takes as many as the longest walk of any id (to its key or to
-    its chain's first empty slot), the claim loop none unless some id is
-    absent AND may create (what `probe_claim_passes_per_step` reads in a
-    trace)."""
+    find loop takes as many as the windows the longest walk of any id (to
+    its key or to its chain's first empty slot) reaches into from its home
+    lane, the claim loop none unless some id is absent AND may create (what
+    `probe_claim_passes_per_step` reads in a trace)."""
     if name == "read_only":   # the serving path: absent ids, none may create
         kw, resident, uids, want = _mix("resident_and_new")
         want = [False] * len(uids)
@@ -524,10 +529,367 @@ def test_the_claim_loop_runs_no_pass_when_no_row_is_to_be_created(
     check_probe(plain, uids, want, got)
     find, claim = passes
 
-    def walk(uid):
-        chain = plain.chain(uid)
-        return 1 + next(i for i, pos in enumerate(chain)
-                        if plain.slots[pos] in (uid, plain.empty))
+    W = min(128, plain.capacity)
 
-    assert find == max(walk(uid) for uid in uids)
+    def windows(uid):
+        chain = plain.chain(uid)
+        walked = next(i for i, pos in enumerate(chain)
+                      if plain.slots[pos] in (uid, plain.empty))
+        return 1 + (chain[0] % W + walked) // W
+
+    assert find == max(windows(uid) for uid in uids)
     assert (claim > 0) == creates
+
+
+# ------------------------------- the find loop's windows, slot for slot
+#
+# `_probe` against the walk it replaced, written out in NumPy: one slot a
+# step down every id's chain, then the claim loop's rounds. Every case is
+# built so that no two new ids want one slot in one round (the walk checks
+# it), so the race has one outcome and ALL of what `_probe` returns is
+# compared: the new keys, `slot_ix`, `created`, `failed`.
+
+
+def scalar_probe(plain, uids, want):
+    empty, cap, probes = plain.empty, plain.capacity, plain.max_probes
+    keys = np.asarray(plain.slots, np.int64)
+    uids = np.asarray(uids, np.int64)
+    home = hashes(uids).astype(np.int64)
+    n = len(uids)
+    slot_ix = np.full(n, -1, np.int64)
+    empty_at = np.full(n, -1, np.int64)
+    unresolved = np.zeros(n, bool)
+    for i, uid in enumerate(uids):
+        if uid == empty:
+            continue
+        for off in range(probes):
+            pos = (home[i] + off) & (cap - 1)
+            if keys[pos] == uid:
+                slot_ix[i] = pos
+                break
+            if keys[pos] == empty:
+                empty_at[i] = off
+                break
+        else:
+            unresolved[i] = True
+    claiming = (empty_at >= 0) & np.asarray(want, bool)
+    pending, off = claiming.copy(), empty_at.copy()
+    while pending.any():
+        pos = (home + off) & (cap - 1)
+        wants = pending & (keys[pos] == empty)
+        for i in np.flatnonzero(wants):
+            assert keys[pos[i]] in (empty, uids[i]), (
+                "two ids race for one slot: the case is not deterministic")
+            keys[pos[i]] = uids[i]
+        won = wants & (keys[pos] == uids)
+        slot_ix[won] = pos[won]
+        off += 1
+        pending &= ~won & (off < probes)
+    created = claiming & (slot_ix >= 0)
+    return keys, slot_ix, created, unresolved | (claiming & ~created)
+
+
+_POOL = {}
+
+
+def homed(capacity, slot, n, first=0):
+    """n ids whose home slot in a table of `capacity` is `slot` (from the
+    `first`-th such id on), by the table's own hash."""
+    if capacity not in _POOL:
+        pool = np.arange(1, 300_000)
+        _POOL[capacity] = pool, hashes(pool) & np.uint64(capacity - 1)
+    pool, home = _POOL[capacity]
+    got = pool[home == slot][first:first + n].tolist()
+    assert len(got) == n
+    return got
+
+
+def _window_case(name):
+    """(table kwargs, resident ids in the order they were inserted, uids,
+    want_create, what the case must show: f(slot_ix, failed))
+    of a named case. W is min(128, capacity)."""
+    empty = int(np.iinfo(np.int32).min)
+    if name == "a_chain_crosses_a_windows_end":
+        # 12 keys from lanes 122-127 of the first window: slots 122-133
+        cap = 512
+        old = sum((homed(cap, s, 3) for s in (122, 124, 126, 127)), [])
+        uids = old + homed(cap, 123, 1) + homed(cap, 200, 1)
+        return (dict(capacity=cap), old, uids, [True] * len(uids),
+                lambda slot_ix, failed: (
+                    sum(slot_ix[:12] >= 128) >= 4 and slot_ix[12] == 134
+                    and slot_ix[13] == 200 and not failed.any()))
+    if name == "a_chain_wraps_the_tables_end":
+        cap = 256
+        old = sum((homed(cap, s, 3) for s in range(250, 256)), [])
+        uids = old + homed(cap, 253, 1, first=3)
+        return (dict(capacity=cap), old, uids, [True] * len(uids),
+                lambda slot_ix, failed: (
+                    sum(slot_ix[:18] < 12) == 12 and slot_ix[18] == 12
+                    and not failed.any()))
+    if name == "a_home_slot_in_a_windows_last_lane":
+        cap = 512
+        old = sum((homed(cap, s, 2) for s in (127, 255, 511)), [])
+        uids = old + homed(cap, 255, 1, first=2) + homed(cap, 383, 1)
+        return (dict(capacity=cap), old, uids, [True] * len(uids),
+                lambda slot_ix, failed: (
+                    slot_ix.tolist() == [127, 128, 255, 256, 511, 0, 257,
+                                         383]))
+    if name == "a_table_smaller_than_a_window":
+        cap = 32    # W = 32, one row; 64 probes walk the table twice
+        old = (np.arange(30) * 7919 + 3).tolist()
+        new = (np.arange(2) * 104729 + 11).tolist()
+        uids = old[::-1] + new
+        return (dict(capacity=cap), old, uids, [True] * 30 + [True, False],
+                lambda slot_ix, failed: (
+                    (slot_ix[:31] >= 0).all() and slot_ix[31] == -1
+                    and not failed.any()))
+    if name == "a_full_table_smaller_than_a_window":
+        cap = 16    # full: an absent id sees all 16 slots four times over
+        old = (np.arange(16) * 7919 + 3).tolist()
+        new = (np.arange(3) * 104729 + 11).tolist()
+        return (dict(capacity=cap), old, old + new, [True] * 19,
+                lambda slot_ix, failed: (
+                    (slot_ix[:16] >= 0).all() and failed[16:].all()))
+    if name == "a_table_of_one_window":
+        cap = 128   # 100 keys of 128: long chains, and they wrap in the row
+        old = (np.arange(100) * 7919 + 3).tolist()
+        new = homed(cap, 5, 1) + [empty] + homed(cap, 77, 1)
+        return (dict(capacity=cap), old, old + new, [True] * 103,
+                lambda slot_ix, failed: (
+                    (slot_ix[:100] >= 0).all() and not failed.any()))
+    if name == "a_full_region_runs_out_of_probes":
+        # slots 100-169 held: 64 keys homed at 100, then 6 homed at 150
+        cap = 512
+        old = homed(cap, 100, 64) + homed(cap, 150, 6)
+        new = (homed(cap, 100, 1, first=64) + homed(cap, 107, 1)
+               + homed(cap, 106, 1))
+        return (dict(capacity=cap), old, old + new, [True] * 73,
+                lambda slot_ix, failed: (
+                    slot_ix[63] == 163 and slot_ix[69] == 169
+                    and failed[70] and slot_ix[71] == 170 and failed[72]
+                    and failed.sum() == 2))
+    if name == "max_probes_smaller_than_a_window":
+        cap = 512
+        old = homed(cap, 126, 5)                     # slots 126-130
+        new = (homed(cap, 126, 1, first=5) + homed(cap, 127, 1)
+               + homed(cap, 125, 1))
+        return (dict(capacity=cap, max_probes=5), old, old + new, [True] * 8,
+                lambda slot_ix, failed: (
+                    slot_ix.tolist() == [126, 127, 128, 129, 130, -1, 131,
+                                         125] and failed.tolist()
+                    == [False] * 5 + [True, False, False]))
+    if name == "max_probes_of_one_window":
+        cap = 512
+        old = homed(cap, 60, 128)                    # slots 60-187
+        new = homed(cap, 60, 1, first=128) + homed(cap, 61, 1)
+        return (dict(capacity=cap, max_probes=128), old, old + new,
+                [True] * 130,
+                lambda slot_ix, failed: (
+                    slot_ix[127] == 187 and failed[128]
+                    and slot_ix[129] == 188 and failed.sum() == 1))
+    if name == "max_probes_not_a_multiple_of_a_window":
+        cap = 1024  # 200 probes from lane 100 reach into a third window
+        old = homed(cap, 100, 200)                   # slots 100-299
+        new = (homed(cap, 100, 1, first=200) + homed(cap, 101, 1)
+               + homed(cap, 99, 1))
+        return (dict(capacity=cap, max_probes=200), old, old + new,
+                [True] * 203,
+                lambda slot_ix, failed: (
+                    slot_ix[199] == 299 and failed[200]
+                    and slot_ix[201] == 300 and slot_ix[202] == 99
+                    and failed.sum() == 1))
+    if name == "sentinel_ids":
+        cap = 512
+        old = sum((homed(cap, s, 3) for s in (122, 124, 126, 127)), [])
+        uids = [empty, old[11], empty, empty] + homed(cap, 123, 1) + [empty]
+        return (dict(capacity=cap), old, uids, [True] * 6,
+                lambda slot_ix, failed: (
+                    slot_ix.tolist() == [-1, 133, -1, -1, 134, -1]
+                    and not failed.any()))
+    if name == "absent_ids_that_may_not_create":
+        cap = 512
+        old = sum((homed(cap, s, 3) for s in (122, 124, 126, 127)), [])
+        new = (homed(cap, 123, 1) + homed(cap, 200, 1)
+               + homed(cap, 127, 1, first=3))
+        return (dict(capacity=cap), old, old + new,
+                [False] * 12 + [False, True, False],
+                lambda slot_ix, failed: (
+                    (slot_ix[:12] >= 0).all()
+                    and slot_ix[12:].tolist() == [-1, 200, -1]
+                    and not failed.any()))
+    raise KeyError(name)
+
+
+WINDOW_CASES = [
+    "a_chain_crosses_a_windows_end", "a_chain_wraps_the_tables_end",
+    "a_home_slot_in_a_windows_last_lane", "a_table_smaller_than_a_window",
+    "a_full_table_smaller_than_a_window", "a_table_of_one_window",
+    "a_full_region_runs_out_of_probes", "max_probes_smaller_than_a_window",
+    "max_probes_of_one_window", "max_probes_not_a_multiple_of_a_window",
+    "sentinel_ids", "absent_ids_that_may_not_create"]
+
+
+@pytest.mark.parametrize("name", WINDOW_CASES)
+def test_probe_equals_the_scalar_walk_slot_for_slot(name):
+    from deeprec_tpu.embedding.table import probe_jit
+
+    kw, resident, uids, want, shows = _window_case(name)
+    t = make_table(**kw)
+    plain = PlainTable(t, resident)
+    assert len(plain.where) == len(resident)
+    expected = scalar_probe(plain, uids, want)
+    got = probe_jit(t, plain.keys(), jnp.asarray(uids, jnp.int32),
+                    jnp.asarray(want))
+    for a, b, what in zip(got, expected,
+                          ("keys", "slot_ix", "created", "failed")):
+        np.testing.assert_array_equal(np.asarray(a), b, err_msg=what)
+    # the case is the case its name says
+    assert shows(expected[1], expected[3]), (expected[1], expected[3])
+
+
+@pytest.mark.parametrize("capacity", [64, 512])
+def test_probe_equals_the_scalar_walk_under_vmap_over_tables(capacity):
+    """The table axis: an empty table, one filled to a half and one filled
+    to three quarters in one vmap, so the tables' find loops want different
+    numbers of passes and one table creates rows where another creates
+    none; each table's results are its own scalar walk's."""
+    t = make_table(capacity=capacity)
+    n = capacity * 3 // 4
+    ids = (np.arange(n) * 7919 + 3).tolist()
+    plains = [PlainTable(t), PlainTable(t, ids[:capacity // 2]),
+              PlainTable(t, ids)]
+    new = homed(capacity, 7, 1) + homed(capacity, capacity - 1, 1)
+    apart = sum((homed(capacity, s, 1, first=9) for s in range(0, 48, 2)),
+                [])                     # 24 ids, no two with one home slot
+    uids = [apart, ids[:24], ids[-22:] + new]
+    want = [[True] * 24, [True] * 24, [True] * 23 + [False]]
+    got = jax.jit(jax.vmap(t._probe))(
+        jnp.stack([p.keys() for p in plains]), jnp.asarray(uids, jnp.int32),
+        jnp.asarray(want))
+    for i, plain in enumerate(plains):
+        expected = scalar_probe(plain, uids[i], want[i])
+        for a, b in zip(got, expected):
+            np.testing.assert_array_equal(np.asarray(a[i]), b)
+    created = np.asarray(got[2]).sum(axis=1).tolist()
+    assert created == [24, 0, 1] and not np.asarray(got[3]).any()
+
+
+@pytest.mark.parametrize("name", ["a_chain_crosses_a_windows_end",
+                                  "a_full_region_runs_out_of_probes",
+                                  "sentinel_ids"])
+def test_probe_through_the_row_kernel_equals_the_scalar_walk(monkeypatch,
+                                                             name):
+    """What a TPU runs, interpreted: the window read through `gather_rows`
+    (rows of 128 int32 keys, a settled id's row skipped) inside the find
+    loop, alone and with the table `vmap` folded into the kernel's own
+    table axis."""
+    import functools
+
+    from deeprec_tpu.ops import fused_lookup
+
+    monkeypatch.setattr(fused_lookup, "gather_rows", functools.partial(
+        fused_lookup.gather_rows, interpret=True))
+    kw, resident, uids, want, _ = _window_case(name)
+    t = make_table(**kw)
+    plain, other = PlainTable(t, resident), PlainTable(t, resident[::3])
+    uids_, want_ = jnp.asarray(uids, jnp.int32), jnp.asarray(want)
+    probe = lambda *a: t._probe(*a)   # noqa: E731 — a jit cache of its own
+    jaxpr = jax.make_jaxpr(probe)(plain.keys(), uids_, want_).jaxpr
+    find = _loops(jaxpr)[0]   # (the kernel's own loops stand inside it)
+    assert "custom_vmap_call" in _primitives(find.params["body_jaxpr"].jaxpr)
+    got = jax.jit(probe)(plain.keys(), uids_, want_)
+    for a, b in zip(got, scalar_probe(plain, uids, want)):
+        np.testing.assert_array_equal(np.asarray(a), b)
+    # two tables, read-only (the ids absent from the second would race)
+    nowhere = [False] * len(uids)
+    got = jax.jit(jax.vmap(probe, in_axes=(0, None, None)))(
+        jnp.stack([plain.keys(), other.keys()]), uids_, jnp.asarray(nowhere))
+    for i, p in enumerate((plain, other)):
+        for a, b in zip(got, scalar_probe(p, uids, nowhere)):
+            np.testing.assert_array_equal(np.asarray(a[i]), b)
+
+
+@pytest.mark.parametrize("arm", ["xla", "kernel"])
+@pytest.mark.parametrize("name", ["a_chain_crosses_a_windows_end",
+                                  "a_full_region_runs_out_of_probes",
+                                  "sentinel_ids"])
+def test_more_ids_than_a_slice_walk_the_find_loop_in_slices(monkeypatch,
+                                                            name, arm):
+    """A caller that probes a whole table's slots at once (rebuild, a
+    restore) has more ids than `_PROBE_SLICE`: the find loop then runs a
+    slice at a time inside ONE scan, the last slice padded, so the window
+    a pass holds is [slice, W] whatever the table's size. The slice is cut
+    to 5 ids here (14, 73 and 6 ids: none a multiple of it); each arm,
+    alone and under the table vmap, gives the scalar walk's results."""
+    import functools
+
+    from deeprec_tpu.embedding import table as table_module
+    from deeprec_tpu.ops import fused_lookup
+
+    monkeypatch.setattr(table_module, "_PROBE_SLICE", 5)
+    if arm == "kernel":
+        monkeypatch.setattr(fused_lookup, "gather_rows", functools.partial(
+            fused_lookup.gather_rows, interpret=True))
+    kw, resident, uids, want, _ = _window_case(name)
+    t = make_table(kernel="pallas" if arm == "kernel" else "xla", **kw)
+    plain, other = PlainTable(t, resident), PlainTable(t, resident[::3])
+    uids_, want_ = jnp.asarray(uids, jnp.int32), jnp.asarray(want)
+    probe = lambda *a: t._probe(*a)   # noqa: E731 — a jit cache of its own
+    jaxpr = jax.make_jaxpr(probe)(plain.keys(), uids_, want_).jaxpr
+    scans = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+    assert len(scans) == 1 and scans[0].params["length"] == -(-len(uids) // 5)
+    body = scans[0].params["jaxpr"].jaxpr
+    assert len(_loops(body)) >= 1 and (
+        ("custom_vmap_call" in _primitives(body)) == (arm == "kernel"))
+    got = jax.jit(probe)(plain.keys(), uids_, want_)
+    for a, b in zip(got, scalar_probe(plain, uids, want)):
+        np.testing.assert_array_equal(np.asarray(a), b)
+    nowhere = [False] * len(uids)
+    got = jax.jit(jax.vmap(probe, in_axes=(0, None, None)))(
+        jnp.stack([plain.keys(), other.keys()]), uids_, jnp.asarray(nowhere))
+    for i, p in enumerate((plain, other)):
+        for a, b in zip(got, scalar_probe(p, uids, nowhere)):
+            np.testing.assert_array_equal(np.asarray(a[i]), b)
+
+
+@pytest.mark.parametrize("capacity", [256, 1024])
+def test_rebuild_in_slices_is_the_rebuild(monkeypatch, capacity):
+    """rebuild probes all C slots of the old table into the fresh one: cut
+    into slices of 64 ids (4 and 16 of them) it leaves the state, slot for
+    slot, that the one-slice find loop leaves."""
+    from deeprec_tpu.embedding import table as table_module
+
+    t = make_table(capacity=capacity)
+    s = t.create()
+    ids = jnp.asarray(np.arange(capacity // 2) * 7919 + 3, jnp.int32)
+    s, _ = t.lookup_unique(s, ids, step=1)
+    keep = jnp.asarray(np.arange(capacity) % 3 != 0)
+    whole = jax.jit(lambda s: t.rebuild(s, keep=keep))(s)
+    monkeypatch.setattr(table_module, "_PROBE_SLICE", 64)
+    sliced = jax.jit(lambda s: t.rebuild(s, keep=keep))(s)
+    assert 0 < int(t.size(whole)) < capacity // 2
+    for a, b in zip(jax.tree.leaves(whole), jax.tree.leaves(sliced)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("kw", [dict(capacity=64),
+                                dict(capacity=512, key_dtype="int64")])
+def test_a_key_window_the_row_kernel_cannot_take_is_no_fallback_of_the_gathers(
+        monkeypatch, kw):
+    """`deeprec_pallas_fallback_total{kernel="gather_rows"}` is read as the
+    VALUE gather's fallbacks: a table under one lane tile, or int64 keys,
+    under kernel="pallas" on a TPU takes XLA's row gather for its key
+    windows by `_key_window`'s own decision and notes nothing."""
+    from deeprec_tpu.ops import fused_lookup
+    from deeprec_tpu.utils import backend
+
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    with jax.enable_x64(kw.get("key_dtype") == "int64"):
+        t = make_table(kernel="pallas", **kw)
+        kdt = jnp.dtype(kw.get("key_dtype", "int32"))
+        noted = set(fused_lookup._fallback_noted)
+        jaxpr = jax.make_jaxpr(lambda *a: t._probe(*a))(
+            jnp.zeros((t.cfg.capacity,), kdt), jnp.arange(1, 9, dtype=kdt),
+            jnp.ones((8,), bool)).jaxpr
+    assert fused_lookup._fallback_noted == noted
+    assert "custom_vmap_call" not in _primitives(jaxpr)
