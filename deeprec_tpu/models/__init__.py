@@ -9,5 +9,6 @@ from deeprec_tpu.models.dssm import DSSM
 from deeprec_tpu.models.masknet import MaskNet
 from deeprec_tpu.models.hybrid_stack import HybridStackLM
 from deeprec_tpu.models.window_stack import WindowStackLM
+from deeprec_tpu.models.latent_stack import LatentStackLM
 from deeprec_tpu.models.multitask import DBMTL, ESMM, MMoE, PLE, SimpleMultiTask
 from deeprec_tpu.models.registry import REGISTRY, build_model

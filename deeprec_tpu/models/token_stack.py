@@ -4,10 +4,13 @@ table (`pooling="none"`) in front of a stack of layers
     h = x + mixer_i(norm(x; w_in));  y = h + experts(norm(h; w_post))
 
 whose feed-forward is a block of sparse experts of which THIS chip holds a
-range (`held_experts`), a final norm and an untied head. A model gives its
+range (`held_experts`), a final norm and an untied head. A layer has a
+kind: the first `dense_layers` of the stack (default 0) have a dense gated
+feed-forward `dense_width` wide in the experts' place (`mlp_block`, scope
+`block_mlp`) and no router, no experts and no counters. A model gives its
 mixers, what its expert block adds to the held experts' part, and its
 initialiser (`_init_mixer`, `_init_moe`, `_layer`); the stack, the remat BY
-LAYER, the loss and the expert layer's counters are written here, once.
+LAYER, the loss and the expert layers' counters are written here, once.
 
 Products take bf16 operands and accumulate in f32; norms, softmaxes, the
 router and the loss are f32; the residual stream is f32.
@@ -18,11 +21,12 @@ that no whole `[positions, vocab]` logits array (nor its gradient) ever
 exists, and remat by layer inside the model (`Trainer(remat=True)` wraps
 the whole `apply` and then keeps every layer's recomputed activations alive
 at once). Its metrics carry the expert layer's counters, summed over the
-layers: `moe_pairs`, `moe_overflow` (pairs over the static budget: a step in
-which it is not 0 left work out), `moe_max_load`; `moe_pairs_max`, the
-fullest layer's pairs, which is what the budget has to hold; and, from a
-model whose gate is a ReLU, `moe_hidden_live` (hidden units of the held
-pairs that the gate leaves above 0).
+layers that have experts: `moe_pairs`, `moe_overflow` (pairs over the static
+budget: a step in which it is not 0 left work out), `moe_max_load`;
+`moe_pairs_max`, the fullest layer's pairs, which is what the budget has to
+hold; from a model whose gate is a ReLU, `moe_hidden_live` (hidden units of
+the held pairs that the gate leaves above 0); and whatever else a model's
+`_total` makes of its layers' counters.
 """
 from __future__ import annotations
 
@@ -117,6 +121,9 @@ class TokenStackLM:
     expert_width: int
     held_experts: Tuple[int, int]    # (first, count) held here
     norm_topk_prob: bool = True
+    # leading layers whose feed-forward is dense, and its width
+    dense_layers: int = 0
+    dense_width: int = 0
     # numerics and sizes of the implementation
     eps: float = 1e-6
     init_std: float = 0.02
@@ -155,15 +162,26 @@ class TokenStackLM:
                             "wu": self._normal(ks[8], (held, d, f)),
                             "wd": self._normal(ks[9], (held, f, d))}}
 
+    def is_dense(self, i: int) -> bool:
+        return i < self.dense_layers
+
+    def _init_swiglu(self, ks, width: int) -> Dict:
+        """A gated feed-forward `width` wide from three keys."""
+        d = self.hidden
+        return {"wg": self._normal(ks[0], (d, width)),
+                "wu": self._normal(ks[1], (d, width)),
+                "wd": self._normal(ks[2], (width, d))}
+
     def init(self, key) -> Dict:
         keys = jax.random.split(key, self.layers + 1)
         layers = []
         for i in range(self.layers):
             ks = jax.random.split(keys[i], _LAYER_KEYS)
+            ffn = {"mlp": self._init_swiglu(ks[7:10], self.dense_width)} \
+                if self.is_dense(i) else {"moe": self._init_moe(ks)}
             layers.append({"in_norm": self._norm_init(),
                            "mixer": self._init_mixer(ks, i),
-                           "post_norm": self._norm_init(),
-                           "moe": self._init_moe(ks)})
+                           "post_norm": self._norm_init(), **ffn})
         return {"layers": layers, "final_norm": self._norm_init(),
                 "head": self._normal(keys[-1], (self.hidden, self.vocab))}
 
@@ -191,12 +209,13 @@ class TokenStackLM:
         return attention_reference(q, k, v, causal=True, sm_scale=scale,
                                    window=window)
 
-    def route(self, router, xt):
-        """(weights, experts) [T, top_k] of the tokens xt [T, d]: the
-        router's part of `moe_dispatch`, wherever in the layer it runs."""
+    def route(self, router, xt, **scoring):
+        """(weights, experts) [T, top_k] of the tokens xt [T, d], scored as
+        `moe.route_topk` scores under `scoring`: the router's part of
+        `moe_dispatch`, wherever in the layer it runs."""
         with scopes.scope(scopes.MOE_DISPATCH):
             w, e = moe.route_topk(xt, router, self.experts_per_token,
-                                  self.norm_topk_prob)
+                                  self.norm_topk_prob, **scoring)
             return w, checkpoint_name(e, scopes.KEPT_MOE_ROUTE)
 
     def held(self, experts, xt, w, e, activation=jax.nn.silu,
@@ -209,11 +228,24 @@ class TokenStackLM:
             compute_dtype=self.compute_dtype, interpret=self.interpret,
             activation=activation, count_live=count_live)
 
+    def mlp_block(self, p: Dict, m):
+        """A dense layer's feed-forward: m [B, T, d] (normed) -> [B, T, d]."""
+        with scopes.scope(scopes.BLOCK_MLP):
+            return nn.swiglu_apply(m, p["wg"], p["wu"], p["wd"],
+                                   self.compute_dtype)
+
     # ----------------------------------------------------------------- stack
+
+    def _total(self, counters) -> Dict:
+        """The step's counters of the layers' (one dict an expert layer)."""
+        total = {k: sum(c[k] for c in counters) for k in counters[0]}
+        total["pairs_max"] = functools.reduce(
+            jnp.maximum, (c["pairs"] for c in counters))
+        return total
 
     def hidden_states(self, params: Dict, inputs):
         """([B, T, d] after the last layer, before the final norm; the
-        expert layers' counters summed over the layers)."""
+        counters of the layers that have experts, summed over them)."""
         x, _ = inputs.seq["tok"]
         x = x.astype(jnp.float32)
         counters = []
@@ -225,11 +257,9 @@ class TokenStackLM:
                 policy=jax.checkpoint_policies.save_only_these_names(
                     *scopes.REMAT_KEPT))
             x, c = layer(p, x)
-            counters.append(c)
-        total = {k: sum(c[k] for c in counters) for k in counters[0]}
-        total["pairs_max"] = functools.reduce(
-            jnp.maximum, (c["pairs"] for c in counters))
-        return x, total
+            if not self.is_dense(i):
+                counters.append(c)
+        return x, self._total(counters)
 
     def apply(self, params: Dict, inputs, train: bool):
         """Logits [B, T, vocab] f32, whole: for small sizes and inspection;

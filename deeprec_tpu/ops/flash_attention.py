@@ -19,6 +19,12 @@ The products take their operands in the dtype they are handed (bf16
 operands run the MXU at its bf16 rate; f32 operands as before), always
 accumulating in f32; softmax statistics are f32.
 
+A value head dim of its own: q and k share one head dim `D`, v (and so
+o, do and dv) may have another, `Dv` (latent attention scores with
+192-wide queries and keys and sums 128-wide values). Every block is whole in
+its last axis, so neither width need be a multiple of the 128-lane tile;
+where `Dv = D` the program is the one it was.
+
 A sliding window (`window=W`, causal only): query `t` sees the keys
 `s <= t` with `t - s < W`. The kernels' minor grid axis is then as long as
 the most blocks any one block can see, each walk starts at the first block
@@ -49,7 +55,8 @@ NEG_INF = -1e30
 def attention_reference(q, k, v, mask=None, causal=False, sm_scale=None,
                         window=None):
     """Plain jnp attention (oracle + CPU fallback). q: [B, H, L, D];
-    k, v: [B, Hkv, S, D] with H a multiple of Hkv."""
+    k: [B, Hkv, S, D], v: [B, Hkv, S, Dv] with H a multiple of Hkv
+    -> [B, H, L, Dv]."""
     _check_window(causal, window)
     B, H, Lq, D = q.shape
     S = k.shape[2]
@@ -196,7 +203,7 @@ def _fa_fwd_kernel(
     def _step():
         q = q_ref[0]  # [block_q, D]
         k = k_ref[0]  # [block_k, D]
-        v = v_ref[0]
+        v = v_ref[0]  # [block_k, Dv]
         mk = mask_ref[0]  # [1, block_k]
         s = _masked_scores(q, k, mk, qb, kb, block_q, block_k, sm_scale,
                            causal, window)
@@ -223,12 +230,12 @@ def _pallas_forward(q, k, v, mask, causal, sm_scale, block_q, block_k,
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, Lq, D = q.shape
-    Hkv, S = k.shape[1], k.shape[2]
+    Hkv, S, Dv = k.shape[1], k.shape[2], v.shape[3]
     G = H // Hkv  # query heads a key/value head
     BH = B * H
     qr = q.reshape(BH, Lq, D)
     kr = k.reshape(B * Hkv, S, D)
-    vr = v.reshape(B * Hkv, S, D)
+    vr = v.reshape(B * Hkv, S, Dv)
     # Mosaic needs the last two dims of a block (8, 128)-aligned or whole,
     # which a (1, block) block over a 2-D array is not: the key mask rides
     # as [B*Hkv, 1, S] rows and the LSE as [BH, Lq, 1] columns — also the
@@ -250,27 +257,27 @@ def _pallas_forward(q, k, v, mask, causal, sm_scale, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, D), kv),
-            pl.BlockSpec((1, block_k, D), kv),
+            pl.BlockSpec((1, block_k, Dv), kv),
             pl.BlockSpec((1, 1, block_k),
                          lambda b, i, j: (b // G, 0, key_block(i, j))),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, Lq, D), q.dtype),
+            jax.ShapeDtypeStruct((BH, Lq, Dv), q.dtype),
             jax.ShapeDtypeStruct((BH, Lq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
         ],
         interpret=interpret,
         name=scopes.KERNEL_FLASH_FWD,
     )(qr, kr, vr, maskr)
-    return o.reshape(B, H, Lq, D), lse.reshape(B, H, Lq)
+    return o.reshape(B, H, Lq, Dv), lse.reshape(B, H, Lq)
 
 
 # --------------------------------------------------- blockwise jnp fwd (lse)
@@ -306,7 +313,7 @@ def _blockwise_forward(q, k, v, mask, causal, sm_scale, block_k, window):
 
     m0 = jnp.full((B, H, Lq, 1), NEG_INF, jnp.float32)  # noqa: DRT003 — keepdims accumulator for the scan's broadcast; one padded sublane, Pallas path owns the real layout
     l0 = jnp.zeros((B, H, Lq, 1), jnp.float32)  # noqa: DRT003 — keepdims accumulator, same contract as m0 above
-    a0 = jnp.zeros((B, H, Lq, D), jnp.float32)
+    a0 = jnp.zeros((B, H, Lq, v.shape[3]), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0), jnp.arange(nb))
     l_safe = jnp.maximum(l, 1e-30)
     o = (acc / l_safe).astype(q.dtype)
@@ -350,8 +357,8 @@ def _fa_bwd_dkdv_kernel(
     def _step():
         q = q_ref[0]                           # [block_q, D]
         k = k_ref[0]                           # [block_k, D]
-        v = v_ref[0]
-        do = do_ref[0]                         # [block_q, D]
+        v = v_ref[0]                           # [block_k, Dv]
+        do = do_ref[0]                         # [block_q, Dv]
         lse = lse_ref[0]                       # [block_q, 1]
         delta = delta_ref[0]
         mk = mask_ref[0]                       # [1, block_k]
@@ -420,13 +427,13 @@ def _pallas_backward(q, k, v, mask, causal, sm_scale, block_q, block_k,
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, Lq, D = q.shape
-    Hkv, S = k.shape[1], k.shape[2]
+    Hkv, S, Dv = k.shape[1], k.shape[2], v.shape[3]
     G = H // Hkv
     BH, BK = B * H, B * Hkv
     qr = q.reshape(BH, Lq, D)
     kr = k.reshape(BK, S, D)
-    vr = v.reshape(BK, S, D)
-    dor = do.astype(q.dtype).reshape(BH, Lq, D)
+    vr = v.reshape(BK, S, Dv)
+    dor = do.astype(q.dtype).reshape(BH, Lq, Dv)
     lser = lse.astype(jnp.float32).reshape(BH, Lq, 1)
     maskr = jnp.repeat(mask.astype(jnp.int32), Hkv, axis=0)[:, None, :]
     # delta = rowsum(do * o): cheap elementwise+reduce, XLA fuses it; the
@@ -437,6 +444,7 @@ def _pallas_backward(q, k, v, mask, causal, sm_scale, block_q, block_k,
 
     num_qb, num_kb = Lq // block_q, S // block_k
     qspec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
+    dospec = pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0))
     common = dict(interpret=interpret)
 
     q_steps, query_block = _walk(_visible_queries, num_kb, block_q, block_k,
@@ -456,23 +464,23 @@ def _pallas_backward(q, k, v, mask, causal, sm_scale, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1, block_q, D), q_side),                        # q
             pl.BlockSpec((1, block_k, D), lambda b, kb, t: (b, kb, 0)),   # k
-            pl.BlockSpec((1, block_k, D), lambda b, kb, t: (b, kb, 0)),   # v
+            pl.BlockSpec((1, block_k, Dv), lambda b, kb, t: (b, kb, 0)),  # v
             pl.BlockSpec((1, 1, block_k), lambda b, kb, t: (b, 0, kb)),   # mask
-            pl.BlockSpec((1, block_q, D), q_side),                        # do
+            pl.BlockSpec((1, block_q, Dv), q_side),                       # do
             pl.BlockSpec((1, block_q, 1), q_side),                        # lse
             pl.BlockSpec((1, block_q, 1), q_side),                        # delta
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda b, kb, t: (b, kb, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, kb, t: (b, kb, 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda b, kb, t: (b, kb, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BK, S, D), k.dtype),
-            jax.ShapeDtypeStruct((BK, S, D), v.dtype),
+            jax.ShapeDtypeStruct((BK, S, Dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, Dv), jnp.float32),
         ],
         name=scopes.KERNEL_FLASH_BWD_DKDV,
         **common,
@@ -492,10 +500,10 @@ def _pallas_backward(q, k, v, mask, causal, sm_scale, block_q, block_k,
         in_specs=[
             qspec,                                                        # q
             pl.BlockSpec((1, block_k, D), kv),                            # k
-            pl.BlockSpec((1, block_k, D), kv),                            # v
+            pl.BlockSpec((1, block_k, Dv), kv),                           # v
             pl.BlockSpec((1, 1, block_k),
                          lambda b, i, j: (b // G, 0, key_block(i, j))),   # mask
-            qspec,                                                        # do
+            dospec,                                                       # do
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),     # lse
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),     # delta
         ],
@@ -509,7 +517,7 @@ def _pallas_backward(q, k, v, mask, causal, sm_scale, block_q, block_k,
     return (
         dq.reshape(B, H, Lq, D),
         dk.reshape(B, Hkv, S, D),
-        dv.reshape(B, Hkv, S, D),
+        dv.reshape(B, Hkv, S, Dv),
     )
 
 
@@ -520,7 +528,7 @@ def _blockwise_backward(q, k, v, mask, causal, sm_scale, block_k, o, lse, do,
                         window):
     """Flash-style exact backward from the saved LSE; scans K blocks."""
     B, H, Lq, D = q.shape
-    Hkv, S = k.shape[1], k.shape[2]
+    Hkv, S, Dv = k.shape[1], k.shape[2], v.shape[3]
     k, v = _repeat_kv(k, H), _repeat_kv(v, H)
     nb = S // block_k
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)  # [B,H,L]
@@ -549,10 +557,10 @@ def _blockwise_backward(q, k, v, mask, causal, sm_scale, block_k, o, lse, do,
     dq, (dk_blocks, dv_blocks) = jax.lax.scan(body, dq0, jnp.arange(nb))
     # scan stacks blocks on axis 0: [nb, B, H, block_k, D] -> [B, H, S, D]
     dk = jnp.moveaxis(dk_blocks, 0, 2).reshape(B, H, S, D)
-    dv = jnp.moveaxis(dv_blocks, 0, 2).reshape(B, H, S, D)
+    dv = jnp.moveaxis(dv_blocks, 0, 2).reshape(B, H, S, Dv)
     if Hkv != H:  # a key/value head's gradient: the sum over its group
         dk = dk.reshape(B, Hkv, H // Hkv, S, D).sum(axis=2)
-        dv = dv.reshape(B, Hkv, H // Hkv, S, D).sum(axis=2)
+        dv = dv.reshape(B, Hkv, H // Hkv, S, Dv).sum(axis=2)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
@@ -568,8 +576,9 @@ def flash_attention(
 ):
     """Masked multi-head attention, O(L·block) memory.
 
-    q: [B, H, Lq, D]; k, v: [B, Hkv, S, D] with H a multiple of Hkv (grouped
-    queries; Hkv = H is plain multi-head); mask: [B, S] bool (True = real).
+    q: [B, H, Lq, D]; k: [B, Hkv, S, D]; v: [B, Hkv, S, Dv] (Dv = D or
+    not) -> [B, H, Lq, Dv], with H a multiple of Hkv (grouped queries;
+    Hkv = H is plain multi-head); mask: [B, S] bool (True = real).
     Lq/S must be multiples of the block sizes (pad outside; padded KV rows
     are masked, padded Q rows produce zeros-safe outputs). `window=W`
     (causal only): query t sees the keys s <= t with t - s < W; a block

@@ -1,8 +1,9 @@
 """Dropless top-k dispatch of tokens to the sparse experts HELD HERE.
 
 Expert parallelism gives each chip a range of a layer's experts. The layer
-routes every token over ALL `E` experts (router product, softmax and top-k
-in f32, the weights renormalised over the `top_k` chosen when asked), keeps
+routes every token over ALL `E` experts (router product, scores and top-k
+in f32: a softmax, or a sigmoid with a selection bias that chooses and does
+not weigh; the weights renormalised over the `top_k` chosen when asked), keeps
 the (token, expert) pairs whose expert is one of the `held` ones, computes
 those experts' part of the result and adds it up by token. What the absent
 experts would add is another chip's part; on one chip the layer runs
@@ -59,15 +60,33 @@ _VMEM_LIMIT = 64 * 1024 * 1024
 # -------------------------------------------------------------------- route
 
 
-def route_topk(x, w_router, top_k: int, renormalise: bool = True):
-    """(weights [T, top_k] f32, experts [T, top_k] int32): softmax over all
-    the router's outputs in f32, the `top_k` largest."""
+def route_topk(x, w_router, top_k: int, renormalise: bool = True, *,
+               scoring: str = "softmax", bias=None, scale: float = 1.0):
+    """(weights [T, top_k] f32, experts [T, top_k] int32), router product
+    and scores in f32. `scoring="softmax"`: a softmax over all the router's
+    outputs, the `top_k` largest, renormalised over the chosen when asked.
+    `scoring="sigmoid"`: a sigmoid an output; the `top_k` largest of
+    `score + bias` are CHOSEN (`bias` [E], a selection bias that receives no
+    gradient: it decides who is chosen and never what a chosen one
+    weighs), each WEIGHS by its score without the bias, renormalised over
+    the chosen when asked, times `scale`."""
     logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
                      precision=HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    w, e = jax.lax.top_k(probs, top_k)
-    if renormalise:
-        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    if scoring == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        w, e = jax.lax.top_k(probs, top_k)
+        if renormalise:
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, e = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
+        w = jnp.take_along_axis(scores, e, axis=-1)
+        if renormalise:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = w * scale
+    else:
+        raise ValueError(f"scoring is softmax or sigmoid; got {scoring!r}")
     return w, e.astype(jnp.int32)
 
 

@@ -156,7 +156,8 @@ class AsyncShardedTrainer(ShardedTrainer):
 
         # (4) dense update
         return (
-            AsyncState(inner=self._dense_update(state, g_dense, tables),
+            AsyncState(inner=self._dense_update(state, g_dense, tables,
+                                                mets),
                        batch=batch_t, views=views_t, bundle_res=res_t),
             mets,
         )

@@ -680,14 +680,29 @@ class Trainer:
             )
         return g_dense, g_embs, mets
 
-    def _dense_update(self, state: TrainState, g_dense, tables) -> TrainState:
+    def _dense_update(self, state: TrainState, g_dense, tables,
+                      mets) -> TrainState:
         """The dense optimizer's update and the step count: the state
-        after a step whose sparse side left `tables`."""
+        after a step whose sparse side left `tables`.
+
+        A leaf a rule owns: a model may keep in its dense tree leaves that
+        no gradient moves (it cuts them off behind `stop_gradient`, so the
+        dense optimizer's update of them is exactly 0) and give
+        `after_update(dense, metrics) -> dense`, which runs here once a
+        step, after the optimizer's update, on the step's metrics `mets`
+        (with accumulation the micro-batches' SUM; on a mesh
+        the replicas' mean, as every metric is: a rule that reads only the
+        sign of a load's distance from the loads' mean, as a router's
+        selection bias does, is the same under both). A model without the
+        hook lowers to the program it lowered to before."""
         with scopes.scope(scopes.PHASE_DENSE_APPLY):
             updates, opt_state = self.dense_opt.update(
                 g_dense, state.opt_state, state.dense
             )
             dense = optax.apply_updates(state.dense, updates)
+            rule = getattr(self.model, "after_update", None)
+            if rule is not None:
+                dense = rule(dense, mets)
             step = state.step + 1
         return TrainState(
             step=step, tables=self._tables_out(tables), dense=dense,
@@ -798,7 +813,7 @@ class Trainer:
         if self.sentinel is not None:
             with scopes.scope(scopes.PHASE_SENTINEL):
                 mets, guard = self._sentinel_fold(mets, guard)
-        return self._dense_update(state, g_dense, tables), mets
+        return self._dense_update(state, g_dense, tables, mets), mets
 
     def _accum_impl(self, state: TrainState, batch, lr, guard=None):
         """Gradient micro-batching — the Auto-Micro-Batch analog
@@ -823,8 +838,10 @@ class Trainer:
         )
         with scopes.scope(scopes.PHASE_DENSE_APPLY):
             g_mean = jax.tree.map(lambda g: g / jnp.float32(A), g_acc)
-        new_state = self._dense_update(state, g_mean, tables)
         sen = mets.pop("_sentinel", None)  # [A]-stacked micro observations
+        new_state = self._dense_update(
+            state, g_mean, tables,
+            jax.tree.map(lambda m: jnp.sum(m, axis=0), mets))
         mets = jax.tree.map(jnp.mean, mets)
         if self.sentinel is not None and sen is not None:
             # The dispatch is the sentinel unit: micro-batch observations
@@ -947,7 +964,7 @@ class Trainer:
         else:
             batch_next, views_n, res_n = prev_batch, views, carry.bundle_res
         return PipelineCarry(
-            inner=self._dense_update(state, g_dense, tables),
+            inner=self._dense_update(state, g_dense, tables, mets),
             batch=batch_next, views=views_n, bundle_res=res_n, guard=guard,
         ), mets
 

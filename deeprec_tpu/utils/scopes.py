@@ -28,7 +28,11 @@ groups so that a block's time holds its parts' (a reader picks the
 innermost name of EACH group). A stack that mixes window and global
 attention layers (models/window_stack.py) names, tight round the flash
 call inside `block_attn`, which of the two a layer is (`attn_window`,
-`attn_global`: a third group).
+`attn_global`: a third group); a latent stack (models/latent_stack.py)
+names its flash call `attn_latent` there, its shared experts `moe_shared`
+inside `block_moe`, a leading layer's dense feed-forward `block_mlp`, and,
+inside `phase_dense_apply`, the rule that moves its routers' selection bias
+`router_bias_update` (a group of its own).
 
 jax wraps a scope's name in the transforms it is traced under
 (`vmap(engine_probe)`, `transpose(jvp(phase_dense_fwd_bwd))`); a reader
@@ -91,15 +95,23 @@ BLOCK_GDN = "block_gdn"
 BLOCK_ATTN = "block_attn"
 BLOCK_MOE = "block_moe"
 BLOCK_HEAD_LOSS = "block_head_loss"
-BLOCKS = (BLOCK_GDN, BLOCK_ATTN, BLOCK_MOE, BLOCK_HEAD_LOSS)
+BLOCK_MLP = "block_mlp"          # a leading layer's dense feed-forward
+BLOCKS = (BLOCK_GDN, BLOCK_ATTN, BLOCK_MOE, BLOCK_HEAD_LOSS, BLOCK_MLP)
 GDN_RULE = "gdn_rule"
 MOE_DISPATCH = "moe_dispatch"
 MOE_EXPERTS = "moe_experts"
-BLOCK_PARTS = (GDN_RULE, MOE_DISPATCH, MOE_EXPERTS)
-# Tight round the flash call of a stack that mixes the two kinds of layer.
+MOE_SHARED = "moe_shared"        # the shared experts, computed in full
+BLOCK_PARTS = (GDN_RULE, MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED)
+# Tight round the flash call: which of a window stack's two kinds a layer
+# is, or a latent stack's call (keys wider than values).
 ATTN_WINDOW = "attn_window"
 ATTN_GLOBAL = "attn_global"
-ATTN_PARTS = (ATTN_WINDOW, ATTN_GLOBAL)
+ATTN_LATENT = "attn_latent"
+ATTN_PARTS = (ATTN_WINDOW, ATTN_GLOBAL, ATTN_LATENT)
+# Inside `phase_dense_apply`: a model's own rule for the leaves of its dense
+# tree that no gradient moves (the router's selection bias).
+ROUTER_BIAS_UPDATE = "router_bias_update"
+DENSE_RULES = (ROUTER_BIAS_UPDATE,)
 
 # What a layer's remat keeps instead of making again (`checkpoint_name`s; no
 # trace shows them): the experts each token chose and the rows they were
@@ -222,6 +234,7 @@ def vocabulary() -> dict:
             "block": {"pick": "innermost", "names": list(BLOCKS)},
             "block_part": {"pick": "innermost", "names": list(BLOCK_PARTS)},
             "attn_part": {"pick": "innermost", "names": list(ATTN_PARTS)},
+            "dense_rule": {"pick": "innermost", "names": list(DENSE_RULES)},
             "probe_part": {"pick": "innermost", "names": list(PROBE_PARTS)},
         },
     }

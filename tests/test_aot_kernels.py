@@ -195,6 +195,41 @@ def test_flash_kernels_compile_at_seven_query_heads_a_group_and_a_window(
         == (32 if window is None else 9)
 
 
+def test_flash_kernels_compile_at_keys_wider_than_values(one_chip,
+                                                         monkeypatch):
+    """The latent cell's shape: 16 heads, queries and keys 192 wide (one
+    and a half lane tiles, taken whole), values 128, L = 8192, causal, bf16
+    operands, blocks of 512: forward and both backward kernels, the output
+    and dV at the VALUE's width, dQ and dK at the key's."""
+    from deeprec_tpu.ops.flash_attention import flash_attention
+    from deeprec_tpu.utils import scopes
+
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    sd = _sd(one_chip)
+
+    def step(q, k, v, mask):
+        o, vjp = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, mask, True, 192 ** -0.5, 512, 512), q, k, v)
+        return o, vjp(o)
+
+    compiled = jax.jit(step).lower(
+        sd((1, 16, 8192, 192), jnp.bfloat16),
+        sd((1, 16, 8192, 192), jnp.bfloat16),
+        sd((1, 16, 8192, 128), jnp.bfloat16), sd((1, 8192), jnp.bool_)
+    ).compile()
+    hlo = compiled.as_text()
+    for name in (scopes.KERNEL_FLASH_FWD, scopes.KERNEL_FLASH_BWD_DKDV,
+                 scopes.KERNEL_FLASH_BWD_DQ):
+        assert name in hlo, name
+    o, (dq, dk, dv) = jax.eval_shape(
+        step, *(jax.ShapeDtypeStruct(s, d) for s, d in (
+            ((1, 16, 8192, 192), jnp.bfloat16),
+            ((1, 16, 8192, 192), jnp.bfloat16),
+            ((1, 16, 8192, 128), jnp.bfloat16), ((1, 8192), jnp.bool_))))
+    assert o.shape == dv.shape == (1, 16, 8192, 128)
+    assert dq.shape == dk.shape == (1, 16, 8192, 192)
+
+
 def _pair_budget(mix: str) -> int:
     """The budget of pairs a token cell runs: its traffic mix's, as
     committed."""
@@ -206,12 +241,14 @@ def _pair_budget(mix: str) -> int:
 
 
 @pytest.mark.parametrize("held,width,hidden,mix", [
-    (32, 512, 2048, "seq8k-zipf11"), (8, 768, 2560, "seq16k-zipf11")])
+    (32, 512, 2048, "seq8k-zipf11"), (8, 768, 2560, "seq16k-zipf11"),
+    (8, 1408, 2048, "seq8k-zipf11-v20480")])
 def test_grouped_products_compile_at_the_cells_widths(
         one_chip, monkeypatch, held, width, hidden, mix):
-    """The expert layer's three kernels at the two token cells' shapes (32
+    """The expert layer's three kernels at the token cells' shapes (32
     held experts of width 512 on a hidden size of 2048; 8 of width 768 on
-    2560), each at the budget of pairs its traffic mix commits: both
+    2560; 8 of width 1,408 on 2048), each at the budget of pairs its
+    traffic mix commits: both
     products' shapes, forward and backward."""
     from deeprec_tpu.ops import moe
 

@@ -197,8 +197,10 @@ def test_the_token_stack_names_its_blocks_and_parts():
     names = op_names(tr._train_step.lower(state, batch, None).compile())
     assert_every_instruction_has_a_phase(names)
     got = [phase_reduce.scope_of(n, VOCAB) for _, n in names]
-    assert {s.block for s in got} - {""} == set(scopes.BLOCKS)
-    assert {s.block_part for s in got} - {""} == set(scopes.BLOCK_PARTS)
+    blocks = set(scopes.BLOCKS) - {scopes.BLOCK_MLP}   # no dense layer
+    assert {s.block for s in got} - {""} == blocks
+    assert {s.block_part for s in got} - {""} == set(scopes.BLOCK_PARTS) - {
+        scopes.MOE_SHARED}
     # a part stands inside its block
     inside = {"gdn_rule": "block_gdn", "moe_dispatch": "block_moe",
               "moe_experts": "block_moe"}
@@ -208,7 +210,7 @@ def test_the_token_stack_names_its_blocks_and_parts():
     bare = [op for op, s in dense if not s.block]
     assert len(bare) < 0.1 * len(dense), (len(bare), len(dense))
     # the backward of every block is named too
-    for block in scopes.BLOCKS:
+    for block in blocks:
         assert any(s.block == block and "transpose(" in n
                    for (_, n), s in zip(names, got)), block
 
@@ -233,13 +235,14 @@ def test_the_window_stack_names_its_attention_parts():
     assert_every_instruction_has_a_phase(names)
     got = [phase_reduce.scope_of(n, VOCAB) for _, n in names]
     assert {s.block for s in got} - {""} == set(scopes.BLOCKS) - {
-        scopes.BLOCK_GDN}
+        scopes.BLOCK_GDN, scopes.BLOCK_MLP}
     assert {s.block_part for s in got} - {""} == {scopes.MOE_DISPATCH,
                                                   scopes.MOE_EXPERTS}
-    assert {s.attn_part for s in got} - {""} == set(scopes.ATTN_PARTS)
+    parts = (scopes.ATTN_WINDOW, scopes.ATTN_GLOBAL)
+    assert {s.attn_part for s in got} - {""} == set(parts)
     assert all(s.block == scopes.BLOCK_ATTN for s in got if s.attn_part)
     assert all(s.block == scopes.BLOCK_MOE for s in got if s.block_part)
-    for part in scopes.ATTN_PARTS:
+    for part in parts:
         assert any(s.attn_part == part and "transpose(" in n
                    for (_, n), s in zip(names, got)), part
     # the router's top-k is the expert block's, though attention follows it
@@ -251,6 +254,61 @@ def test_the_window_stack_names_its_attention_parts():
     # what is left is the two norms of a layer (one feeds the router AND the
     # mixer), the residual adds and the embedding's cast, as in the hybrid
     # stack; at this size they are a larger share of fewer operations
+    bare = [op for op, s in dense if not s.block]
+    assert len(bare) < 0.15 * len(dense), (len(bare), len(dense))
+
+
+def test_the_latent_stack_names_its_parts_and_its_rule():
+    """The latent stack: every layer's flash call stands under
+    `attn_latent` inside `block_attn`, the leading layer's feed-forward
+    under `block_mlp`, the shared experts under `moe_shared` inside
+    `block_moe`, forward and backward; the rule that moves the selection
+    bias under `router_bias_update` inside `phase_dense_apply`, and nothing
+    else there is named by it; and the names are the ones the benchmark's
+    phase file holds."""
+    from deeprec_tpu.models import LatentStackLM
+
+    m = LatentStackLM(
+        vocab=48, seq_len=32, capacity=128, pair_budget=256, hidden=32,
+        layers=3, dense_layers=1, dense_width=48, attn_heads=4,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=12,
+        kv_lora_rank=16, rope_theta=5e4, num_experts=16, experts_per_token=4,
+        expert_width=16, shared_expert_width=32, routed_scaling_factor=2.446,
+        bias_update_rate=1e-3, held_experts=(4, 2), flash_block=16,
+        moe_block=8, loss_block=16)
+    tr = Trainer(m, Adagrad(lr=0.05), optax.adam(1e-3), unique_budget=40)
+    tok = jnp.arange(2 * 33, dtype=jnp.int32).reshape(2, 33) % 48
+    batch = {"tok": tok[:, :-1], "label": tok[:, 1:]}
+    names = op_names(tr._train_step.lower(tr.init(0), batch, None).compile())
+    assert_every_instruction_has_a_phase(names)
+    got = [phase_reduce.scope_of(n, VOCAB) for _, n in names]
+    assert {s.block for s in got} - {""} == set(scopes.BLOCKS) - {
+        scopes.BLOCK_GDN}
+    assert {s.block_part for s in got} - {""} == {
+        scopes.MOE_DISPATCH, scopes.MOE_EXPERTS, scopes.MOE_SHARED}
+    assert {s.attn_part for s in got} - {""} == {scopes.ATTN_LATENT}
+    assert all(s.block == scopes.BLOCK_ATTN for s in got if s.attn_part)
+    assert all(s.block == scopes.BLOCK_MOE for s in got if s.block_part)
+    for field, name in (("attn_part", scopes.ATTN_LATENT),
+                        ("block", scopes.BLOCK_MLP),
+                        ("block_part", scopes.MOE_SHARED)):
+        assert any(getattr(s, field) == name and "transpose(" in n
+                   for (_, n), s in zip(names, got)), name
+    ruled = [s for s in got if s.dense_rule]
+    assert ruled and all(s.phase == scopes.PHASE_DENSE_APPLY
+                         and s.dense_rule == scopes.ROUTER_BIAS_UPDATE
+                         for s in ruled)
+    assert any(s.phase == scopes.PHASE_DENSE_APPLY and not s.dense_rule
+               for s in got)          # Adam's own update is not the rule's
+    with open(os.path.join(ROOT, "benchmark", "phases",
+                           "57-latent-stack.json")) as f:
+        ours = json.load(f)["groups"]
+    assert {g: spec["names"] for g, spec in ours.items()} == {
+        "block": [scopes.BLOCK_MLP], "block_part": [scopes.MOE_SHARED],
+        "attn_part": [scopes.ATTN_LATENT],
+        "dense_rule": list(scopes.DENSE_RULES)}
+    dense = [(op, s) for (op, _), s in zip(names, got)
+             if s.phase == scopes.PHASE_DENSE_FWD_BWD and op not in FREE]
     bare = [op for op, s in dense if not s.block]
     assert len(bare) < 0.15 * len(dense), (len(bare), len(dense))
 
