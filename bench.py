@@ -17,8 +17,8 @@ is the requested K's best repetition; `steps_per_dispatch` records it.
 `--smoke` (or BENCH_SMOKE=1, used by cibuild) shrinks the sweep and the
 timed windows so CI completes quickly.
 
-Unique budgets: `--unique-budget auto` (default) engages the hash dedup
-engine (ops/dedup.py) — each table's unique fraction is measured during
+Unique budgets: `--unique-budget auto` (default) engages the budgeted
+dedup (ops/dedup.py) — each table's unique fraction is measured during
 pre-fill, folded into an EMA budget, and every downstream op of the lookup/
 apply hot path is sized at the budget instead of the full flattened batch;
 the JSON records the per-table `unique_fraction`/`dedup_overflow` under
@@ -74,7 +74,7 @@ def _measure_k(trainer, batches, B, k, timed_steps, reps):
     jax.block_until_ready(mets["loss"])
     if trainer.unique_budget is not None:
         # Fold the pre-fill's measured unique fractions into the budgets so
-        # the warmed/timed windows run the hash dedup engine at-budget
+        # the warmed/timed windows run the dedup at-budget
         # (docs/perf.md); the one recompile lands in the warmup window.
         state, _ = trainer.update_budgets(state)
 
@@ -1568,7 +1568,7 @@ def main():
                    help="fast CI path: endpoints-only K sweep, short windows")
     p.add_argument("--unique-budget",
                    default=os.environ.get("BENCH_UNIQUE_BUDGET", "auto"),
-                   help="hash dedup unique budget: 'auto' (measured EMA, "
+                   help="dedup unique budget: 'auto' (measured EMA, "
                         "default), an int (fixed ids per lookup), or 'off' "
                         "(legacy full-batch sort-unique)")
     p.add_argument("--pipeline-mode",

@@ -290,7 +290,7 @@ class TableConfig:
     # docs/perf.md), so "auto" resolves to unpacked there. "on"/"off" force it
     # either way (tests exercise the packed path on CPU via "on").
     packed: str = "auto"  # auto | on | off
-    # Unique-budget for the hash dedup engine (ops/dedup.py): per lookup,
+    # Unique-budget for the budgeted dedup (ops/dedup.py): per lookup,
     # ids dedup to at most `unique_budget` uniques and EVERY downstream op
     # (probe, gather, freq/version scatters, init, backward segment-sum,
     # the sharded a2a/allgather payload) is sized at the budget instead of

@@ -643,7 +643,7 @@ class EmbeddingTable:
 
     def default_unique_size(self, n: int) -> Optional[int]:
         """Resolve cfg.unique_budget for an n-position flattened TRAIN
-        lookup: the uids-array size for the hash dedup engine, or None for
+        lookup: the uids-array size for the budgeted dedup, or None for
         the legacy U = N sort-unique (logged once per table so the waste
         is visible — None/"auto" configs; "off" stays silent). Trainers
         override this resolution with their own (EMA-driven) budgets.
@@ -755,7 +755,7 @@ class EmbeddingTable:
 
         Dedup routing: `unique_size=None` keeps the legacy sort-based
         `jnp.unique` at U = N; a concrete `unique_size` engages the O(N)
-        hash dedup engine (ops/dedup.py) at that static budget — every
+        budgeted dedup (ops/dedup.py) at that static budget — every
         downstream op then runs at U instead of N, ids past the budget
         serve the blocked default and count into `dedup_overflow`.
 

@@ -32,7 +32,7 @@ class SparseFeature:
              set distinct max_len values to keep differently-shaped features
              in separate groups.
     unique_budget: per-feature override of TableConfig.unique_budget (the
-             hash-dedup unique budget, ops/dedup.py): int fixed budget,
+             dedup unique budget, ops/dedup.py): int fixed budget,
              "auto" trainer-derived, "off" to force the legacy U=N path,
              None (default) to inherit the table's setting. Features
              sharing a bundle resolve to the largest member budget.

@@ -1,14 +1,13 @@
 """Scatter-free masked-row compaction at a static budget.
 
-The prefix-sum / searchsorted index compaction PR 2 built for the dedup
-engine (ops/dedup.py: occupied scratch slots -> dense ranks) is the
-general device-side primitive for "collect the rows where mask is True
-without a sort and without a data-dependent shape". This module hoists it
-out so the incremental-checkpoint exporter (training/checkpoint.py) and
-the multi-tier migration extractor (embedding/multi_tier.py) can compact
-dirty/demotable rows ON DEVICE — the device->host transfer then scales
-with the selected fraction, not the table capacity, which is the whole
-point of taking checkpoint/migration traffic off the training stall path.
+A prefix-sum / searchsorted index compaction: the general device-side
+primitive for "collect the rows where mask is True without a sort and
+without a data-dependent shape". The incremental-checkpoint exporter
+(training/checkpoint.py) compacts dirty rows with it ON DEVICE, and the
+multi-tier migration extractor (embedding/multi_tier.py) sizes its
+exports with `quantize_rows` — the device->host transfer then scales with
+the selected fraction, not the table capacity, which is the whole point of
+taking checkpoint/migration traffic off the training stall path.
 
 Contract:
 
@@ -17,9 +16,13 @@ Contract:
     (-1 padding past the count) — the same ordering `np.nonzero` gives the
     legacy host-side exporter, so compacted exports are byte-identical to
     the host-masked ones after truncation.
-  * Everything is cumsum + searchsorted + gathers: scatter is the
-    expensive primitive on every backend (measured ~50x a gather on CPU,
-    ops/dedup.py), and none is needed.
+  * Everything is cumsum + searchsorted + gathers. That form was chosen on
+    a CPU, where a scatter measured ~50x a gather. On a TPU the
+    `searchsorted` is the cost: log2(C) dependent passes of one scalar
+    gather a query (10 ns a query a pass on a v5e, PERF.md, PR 36), which
+    is why the train step's dedup no longer compacts through here
+    (ops/dedup.py packs its unique ids with a sort). A save runs it once
+    a table, off the step's path.
   * `quantize_rows` buckets a measured count to a power of two so drift
     in the dirty fraction re-traces at most log2(C) times per table, the
     same never-recompile posture as the dedup budget grid.
@@ -55,7 +58,7 @@ def rank_compact(
       * `n` is the total True count (NOT clipped to `size`).
       * `rank` is the inclusive prefix sum (`rank[i]` = number of True
         positions at or before i) — callers that need the inverse map
-        (ops/dedup.py ranks its scratch slots with it) reuse it for free.
+        reuse it for free.
     """
     rank = jnp.cumsum(mask.astype(jnp.int32))
     n = rank[-1]

@@ -1,21 +1,35 @@
-"""Hash-based device dedup at a static unique budget.
+"""Device dedup at a static unique budget, by sorting.
 
-`jnp.unique(size=U)` is sort-based: O(N log N) compare/exchange passes over
-the full flattened batch, and with the default U = N every downstream op —
-probe, embedding gather, freq/version/dirty scatters, `_init_rows`, the
-backward segment-sum — runs at batch size rather than unique-id size. On
-zipf-skewed recsys batches that is a multi-x waste (docs/perf.md charges
-~25% of the CPU step to "probe bookkeeping, unique, combiners").
+With the default U = N every op downstream of the dedup — probe, embedding
+gather, freq/version/dirty scatters, `_init_rows`, the backward
+segment-sum — runs at batch size rather than unique-id size; on
+zipf-skewed recsys batches that is a multi-x waste. The budget (`size`)
+cuts that width, and `dedup_at_budget` finds the unique ids at it with
+three lane-parallel sorts and a prefix sum: a sort by (mixed hash, id)
+puts equal ids side by side, a prefix sum over the groups' first elements
+ranks them, a sort on the permutation carries every position's rank back
+to input order, and a sort on "is a group's first element" packs the
+unique ids to the front; a group's count is the distance to the next
+group's start. No loop, no scratch table, no per-id gather or scatter.
 
-This module replaces the sort with the same vectorized open-addressing
-claim-race probe the embedding table already uses for its own slots
-(the claim loop of `EmbeddingTable._probe`): every position gathers its scratch-slot
-candidate, first-comers claim empty slots via a batched scatter, losers of
-a claim race advance one probe offset. The loop is a `lax.while_loop` of
-pure gathers/scatters — O(N · expected-probes) with expected-probes ~1-2
-at the <=50% scratch load the sizing below guarantees. No sort anywhere.
+Why sorts (PERF.md section 6, PR 36; one v5e chip, 26 tables x 8,192 ids
+under `vmap`, device time from a trace): the three sorts and the prefix
+sums take 0.40 ms, whatever the budget and however many ids repeat. What
+stood here until PR 36 — an open-addressing claim race over a scratch of
+4 (N + 1) slots (`lax.while_loop` of gather / claim-scatter / re-gather)
+and a prefix-sum + 17-pass `searchsorted` compaction of that scratch —
+took 36.8 ms on zipf ids and 90.9 ms on uniform ones, over half of both
+DLRM cells' steps, every access of it a per-id scalar one at 5-13 ns. That
+form had been chosen on a CPU, where it beat `jnp.unique` 1.45-1.89x and
+where the sort form is about 3x slower than it; no deployment trains
+there. Within the sort form, on the same chip: with `inverse` brought
+back by a permutation scatter instead of the second sort the whole dedup
+takes 1.28 ms for 0.40, with the unique ids packed by scatters instead of
+the third sort 2.32 ms; the id as second key of the first sort costs
+0.002 ms, stable sorts 0.15 ms more (none is needed: every tie is between
+equal ids).
 
-Budget contract (`hash_dedup`):
+Budget contract (`dedup_at_budget`):
 
   * `size` is STATIC — the returned arrays are `uids [size]`,
     `counts [size]`, plus `inverse [N]` and a scalar `overflow`.
@@ -25,11 +39,13 @@ Budget contract (`hash_dedup`):
     gradient mask drops their update — exactly the per-step degradation
     contract of the budgeted all2all (`ShardedTable`, `a2a_overflow`). At
     most `size - 1` real unique ids fit.
-  * `overflow` counts the distinct ids compacted out past the budget plus
-    any positions whose probe never resolved (near-impossible at the
-    default scratch sizing) — the same transient-counter contract as
-    `insert_fails` / `a2a_overflow`; consume it at host cadence
-    (`Trainer.update_budgets`) to widen the budget.
+  * The real ids stand at `uids[1..n]` in the order of their mixed hash
+    (ties by id): the same order on every backend, alone and under `vmap`.
+    A batch over its budget loses the ids whose hash is largest — a
+    pseudo-random set, never "the largest ids".
+  * `overflow` counts the distinct ids past the budget — the same
+    transient-counter contract as `insert_fails` / `a2a_overflow`; consume
+    it at host cadence (`Trainer.update_budgets`) to widen the budget.
 
 Everything is shape-static and built from vmap/scan-safe primitives, so it
 runs unchanged inside the stacked-bundle `vmap`, the K-step `lax.scan`
@@ -52,10 +68,6 @@ logger = logging.getLogger("deeprec_tpu.dedup")
 _logged_full_fallback: set = set()
 
 
-def next_pow2(n: int) -> int:
-    return 1 << max(0, int(n) - 1).bit_length()
-
-
 def _mult8(n: int) -> int:
     return max(8, ((int(n) + 7) // 8) * 8)
 
@@ -71,7 +83,7 @@ def resolve_size(budget: int, n: int) -> int:
 
 def log_full_fallback(name: str, n: int) -> None:
     """Record (once per table) that a lookup fell back to U = N — the
-    full-batch sort-unique whose downstream waste the budget exists to cut.
+    full-batch `jnp.unique` whose downstream waste the budget exists to cut.
     Visible so the silent default never hides the cost again."""
     if name in _logged_full_fallback:
         return
@@ -88,32 +100,21 @@ def log_full_fallback(name: str, n: int) -> None:
         pass
     logger.info(
         "table %s: no unique budget resolved — dedup falls back to U=N=%d "
-        "(sort-based, every downstream op at batch size). Set "
+        "(every downstream op at batch size). Set "
         "TableConfig.unique_budget / SparseFeature.unique_budget or "
-        "Trainer(unique_budget=...) to engage the hash dedup engine.",
+        "Trainer(unique_budget=...) to dedup at a budget.",
         name, n,
     )
 
 
-def scratch_size(n: int) -> int:
-    """Scratch-table size for an N-position dedup: the next power of two
-    >= 4·(N+1), so even an all-distinct batch loads the table at <=25% and
-    linear-probe chains stay short. The loop cost is per-ITERATION (one
-    claim scatter over all N lanes — the dominant primitive on every
-    backend), so a wider scratch that removes one probe round pays for its
-    extra int32 rows many times over (measured: 5 -> 4 rounds at N=53k)."""
-    return next_pow2(4 * (int(n) + 1))
-
-
-def hash_dedup(
+def dedup_at_budget(
     flat: jnp.ndarray,
     size: int,
     *,
     sentinel,
     weights: Optional[jnp.ndarray] = None,
-    max_probes: int = 64,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Deduplicate `flat` [N] into at most `size - 1` unique ids, O(N).
+    """Deduplicate `flat` [N] into at most `size - 1` unique ids by sorting.
 
     Args:
       flat: [N] ids with padding already collapsed onto `sentinel`.
@@ -123,87 +124,63 @@ def hash_dedup(
       weights: optional [N] int per-position weights for `counts`
         (default 1 each — occurrence counts). Sentinel positions never
         contribute.
-      max_probes: probe-chain bound; unresolved positions count as
-        overflow.
 
     Returns `(uids [size], inverse [N] int32, counts [size] int32,
     overflow [] int32)` where `uids[inverse]` reconstructs every budgeted
-    position and `inverse == 0` marks padding/overflow positions.
+    position and `inverse == 0` marks padding/overflow positions. Real
+    ids stand at `uids[1..n]` in the order of their mixed hash.
     """
     N = flat.shape[0]
     sent = jnp.asarray(sentinel, flat.dtype)
-    S = scratch_size(N)
-    mask_s = jnp.uint32(S - 1)
-    h = hashing.mix32(hashing.fold64(flat))
     valid = flat != sent
+    pos = jnp.arange(N, dtype=jnp.int32)
 
-    scratch0 = jnp.full((S,), sent, flat.dtype)
-    slot0 = jnp.full((N,), -1, jnp.int32)
+    # 1. Equal ids adjacent, the sentinel strictly last. The hash leads so
+    # that a batch over its budget loses pseudo-random ids, not always the
+    # largest (on a frequency-ranked vocabulary: the same cold ones); the
+    # id is the second key because two ids may share a hash (folded int64
+    # ids, and the one id whose hash is clamped below the sentinel's key).
+    top = jnp.uint32(0xFFFFFFFF)
+    h = hashing.mix32(hashing.fold64(flat))
+    key = jnp.where(valid, jnp.minimum(h, top - 1), top)
+    operands = (key, flat, pos)
+    if weights is not None:
+        operands += (jnp.where(valid, weights.astype(jnp.int32), 0),)
+    _, ids_s, perm, *w_s = jax.lax.sort(operands, num_keys=2, is_stable=False)
 
-    def cond(carry):
-        step, pending, *_ = carry
-        return jnp.logical_and(step < max_probes, jnp.any(pending))
+    # 2. A group's head is its first element; a head's rank is its uid slot.
+    real = ids_s != sent
+    head = real & (ids_s != jnp.concatenate([sent[None], ids_s[:-1]]))
+    rank = jnp.cumsum(head.astype(jnp.int32))
+    overflow = jnp.maximum(rank[-1] - jnp.int32(size - 1), 0)
 
-    def body(carry):
-        step, pending, slot, scratch = carry
-        pos = ((h + jnp.uint32(step)) & mask_s).astype(jnp.int32)  # [N]
-        k = scratch[pos]
-        hit = pending & (k == flat)
-        slot = jnp.where(hit, pos, slot)
-        pending = pending & ~hit
-        # Claim race on empty scratch slots: scatter all claimants, the
-        # re-gather reveals the one winner; losers advance a probe offset.
-        # (The fused step kernel — ops/fused_lookup.fused_sparse_forward —
-        # replaces this whole O(N)-lane scatter round with a sequential
-        # in-VMEM slot write per id, so the ~50x-a-gather cost below never
-        # appears on the fused path.)
-        want = pending & (k == sent)
-        claim_pos = jnp.where(want, pos, S)  # S = out of bounds -> dropped
-        scratch = scratch.at[claim_pos].set(flat, mode="drop")
-        won = want & (scratch[pos] == flat)
-        slot = jnp.where(won, pos, slot)
-        pending = pending & ~won
-        return step + 1, pending, slot, scratch
+    # 3. Every position's slot, back in input order.
+    slot = jnp.where(real & (rank < size), rank, 0)
+    _, inverse = jax.lax.sort((perm, slot), num_keys=1, is_stable=False)
 
-    _, failed, slot, scratch = jax.lax.while_loop(
-        cond, body, (jnp.int32(0), valid, slot0, scratch0)
+    # 4. The heads to the front, in order, each with the count (or weight)
+    # that stands before its group: a group's count is the next head's
+    # start less its own, and the last group's next start is the total.
+    if w_s:
+        starts, total = (jnp.cumsum(w_s[0]) - w_s[0],), w_s[0]
+    else:  # the real ids are a prefix: a head's start is its position
+        starts, total = (), valid
+    total = jnp.sum(total, dtype=jnp.int32)
+    ckey, ids_c, *start_c = jax.lax.sort(
+        (jnp.where(head, pos, pos + N), ids_s) + starts,
+        num_keys=1, is_stable=False,
     )
+    is_head = ckey < N
+    start_c = jnp.where(is_head, start_c[0] if start_c else ckey, total)
 
-    # Budget compaction: the j-th occupied scratch slot (slot order) takes
-    # dense index j in 1..size-1; the rest compact out as overflow.
-    # Deliberately scatter-free — the shared prefix-sum + searchsorted
-    # compaction (ops/compact.py, also behind the incremental-checkpoint
-    # dirty export) — because scatter is the expensive primitive here (an
-    # [S]-lane scatter measured ~50x a gather on CPU); the one remaining
-    # scatter is the [N]-lane counts segment-add.
-    from deeprec_tpu.ops.compact import rank_compact
+    def first(n, x, fill):  # `resolve_size` allows up to 8 slots over N
+        return jnp.pad(x[:n], (0, max(n - N, 0)), constant_values=fill)
 
-    occ = scratch != sent  # [S]
-    sel, n_occ, rank = rank_compact(occ, size - 1)
-    uids_tail = jnp.where(
-        sel >= 0, scratch.at[sel].get(mode="clip"), sent
-    )
-    uids = jnp.concatenate([jnp.full((1,), sent, flat.dtype), uids_tail])
-
-    pos_ok = valid & (slot >= 0)
-    r = rank.at[jnp.where(pos_ok, slot, 0)].get(mode="clip")  # lane's rank
-    budgeted = pos_ok & (r < size)
-    inverse = jnp.where(budgeted, r, 0).astype(jnp.int32)
-
-    w = (
-        jnp.ones((N,), jnp.int32)
-        if weights is None
-        else weights.astype(jnp.int32)
-    )
-    counts = (
-        jnp.zeros((size,), jnp.int32)
-        .at[jnp.where(budgeted, inverse, size)]
-        .add(w, mode="drop")
-    )
-    overflow = (
-        jnp.maximum(n_occ - jnp.int32(size - 1), 0) + jnp.sum(failed)
-    ).astype(jnp.int32)
-    return uids, inverse, counts, overflow
+    uids = jnp.concatenate(
+        [sent[None], first(size - 1, jnp.where(is_head, ids_c, sent), sent)])
+    counts = jnp.concatenate(
+        [jnp.zeros((1,), jnp.int32), jnp.diff(first(size, start_c, total))])
+    return uids, inverse, counts, overflow.astype(jnp.int32)
 
 
 @scopes.scoped(scopes.ENGINE_ROUTE)
@@ -215,8 +192,8 @@ def route_ids(
     unique_size: Optional[int] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray, Optional[jnp.ndarray]]:
     """The apply-independent ROUTING half of a lookup: flatten, collapse
-    padding onto the sentinel, dedup (hash engine at `unique_size`, legacy
-    sort at None). A pure function of the id batch — it reads NO table
+    padding onto the sentinel, dedup (`dedup_at_budget` at `unique_size`,
+    `sort_unique` at None). A pure function of the id batch — it reads NO table
     state — which is what lets the pipelined trainers hoist it (and, for
     sharded tables, the id exchange built on it) a full step ahead of the
     tables it will hit (docs/perf.md "in-step pipelining").
@@ -236,7 +213,7 @@ def route_ids(
         )
         overflow = None
     else:
-        uids, inverse, counts, overflow = hash_dedup(
+        uids, inverse, counts, overflow = dedup_at_budget(
             flat, unique_size, sentinel=sentinel
         )
     valid = uids != sent
@@ -246,12 +223,12 @@ def route_ids(
 def sort_unique(
     flat: jnp.ndarray, size: int, *, sentinel
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """The legacy sort-based dedup (`jnp.unique` at a static size) with the
-    table's sentinel/counts conventions — kept as the U=N fallback and as
-    the reference curve for `tools/bench_dedup.py`. Note its budget
-    semantics are WEAKER than `hash_dedup`: past-`size` uniques are
-    silently truncated with an undefined inverse, which is why the hash
-    engine (defined overflow) is the one budgets route through."""
+    """`jnp.unique` at a static size with the table's sentinel/counts
+    conventions — the U=N path of eval and serving lookups, and the
+    reference curve for `tools/bench_dedup.py`. Note its budget semantics
+    are WEAKER than `dedup_at_budget`: past-`size` uniques are silently
+    truncated with an undefined inverse, which is why `dedup_at_budget`
+    (defined overflow) is the one budgets route through."""
     sent = jnp.asarray(sentinel, flat.dtype)
     uids, inverse, counts = jnp.unique(
         flat, size=size, fill_value=sent, return_inverse=True,

@@ -963,9 +963,8 @@ def _apply_rows_op(block, interpret):
 # ------------------------------------------------------- fused sparse step
 #
 # The single-pass per-table step kernels (docs/kernels.md). Forward: one
-# Pallas pass runs the hash-probe dedup inline (the scratch table lives in
-# VMEM, so the claim-scatter that costs ~50x a gather as an [S]-lane XLA
-# scatter — ops/dedup.py's compaction comment — becomes a plain in-kernel
+# Pallas pass runs a hash-probe dedup inline (its scratch table lives in
+# VMEM and the ids are walked one by one, so a claim is a plain in-kernel
 # slot write), DMAs each unique row from HBM exactly once, and
 # segment-combines straight into the [B, D] output: the [U, D] unique-rows
 # buffer never round-trips through HBM. Backward: one pass segment-sums the
@@ -983,9 +982,9 @@ class FusedBags(NamedTuple):
     out      [B, D] f32 pooled bags (always f32: rows are cast up before
              the combine on BOTH paths, so bf16 tables pool exactly).
     uids     [U] int32 unique row indices; uids[0] == -1 (reserved
-             sentinel, the hash_dedup contract). NOTE the ORDER of uids is
-             path-dependent (kernel claims in first-occurrence order, the
-             XLA fallback compacts in scratch-slot order); `out` and the
+             sentinel, the dedup_at_budget contract). NOTE the ORDER of
+             uids is path-dependent (kernel claims in first-occurrence
+             order, the XLA fallback packs in hash order); `out` and the
              uids↔inverse correspondence are order-independent.
     inverse  [B, L] int32 position -> unique slot (0 = pad/overflow).
     counts   [U] int32 occurrences per unique slot (counts[0] == 0).
@@ -1070,6 +1069,13 @@ def fusable_optimizer(opt, dim: int) -> bool:
     return True
 
 
+def _scratch_size(n: int) -> int:
+    """Slots of the fused forward kernel's in-VMEM probe table for `n`
+    positions: the next power of two >= 4 (n + 1), so an all-distinct
+    batch loads it to a quarter and its linear-probe chains stay short."""
+    return 1 << (4 * (int(n) + 1) - 1).bit_length()
+
+
 def fused_sparse_forward(values: jnp.ndarray, ids: jnp.ndarray, *,
                          combiner: str = "sum", unique_size: int,
                          max_probes: int = 64, interpret: bool = False,
@@ -1082,10 +1088,12 @@ def fused_sparse_forward(values: jnp.ndarray, ids: jnp.ndarray, *,
     sentinel slot — use dedup.resolve_size). Returns FusedBags.
 
     Off-TPU (and for any shape _dma_ok rejects) this is the identical-
-    semantics XLA composition hash_dedup -> gather -> combiners.combine,
-    which doubles as the oracle for the interpret-mode kernel tests.
-    When `overflow > 0` the SET of budgeted ids is path-dependent (claim
-    order vs scratch-slot order) — both satisfy the budget contract.
+    semantics XLA composition dedup_at_budget -> gather ->
+    combiners.combine, which doubles as the oracle for the interpret-mode
+    kernel tests. When `overflow > 0` the SET of budgeted ids is
+    path-dependent (claim order vs hash order) — both satisfy the budget
+    contract. `max_probes` bounds the kernel's own probe chains; the XLA
+    composition has none.
     """
     B, L = ids.shape
     C, D = values.shape
@@ -1103,8 +1111,8 @@ def fused_sparse_forward(values: jnp.ndarray, ids: jnp.ndarray, *,
         from deeprec_tpu.embedding import combiners
         from deeprec_tpu.ops import dedup
 
-        uids, inverse, counts, overflow = dedup.hash_dedup(
-            flat, U, sentinel=-1, max_probes=max_probes
+        uids, inverse, counts, overflow = dedup.dedup_at_budget(
+            flat, U, sentinel=-1
         )
         emb = values.at[jnp.clip(uids, 0, C - 1)].get(mode="clip").astype(
             jnp.float32
@@ -1120,14 +1128,13 @@ def fused_sparse_forward(values: jnp.ndarray, ids: jnp.ndarray, *,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from deeprec_tpu.ops import dedup
     from deeprec_tpu.utils import hashing
 
-    # Probe table sizing: same load-factor policy as the XLA engine, but
-    # laid out (S // 128, 128) so slot access is a dynamic SUBLANE slice
-    # plus an iota-select over lanes (a dynamic LANE index is not
-    # expressible on TPU). Floor of one full lane row.
-    S = max(dedup.scratch_size(N), _LANES)
+    # Probe table sizing: `_scratch_size`'s load factor, laid out
+    # (S // 128, 128) so slot access is a dynamic SUBLANE slice plus an
+    # iota-select over lanes (a dynamic LANE index is not expressible on
+    # TPU). Floor of one full lane row.
+    S = max(_scratch_size(N), _LANES)
 
     def kernel(ids_ref, values_ref, out_ref, uids_ref, inv_ref, cnt_ref,
                ovf_ref, ubuf, lbuf, tabk, tabu, usm, sem):
@@ -1152,10 +1159,9 @@ def fused_sparse_forward(values: jnp.ndarray, ids: jnp.ndarray, *,
         # no uninitialized VMEM can leak through a future indexing bug.
         ubuf[...] = jnp.zeros_like(ubuf[...])
 
-        # ---- phase 1: sequential hash-probe insert — ops/dedup.py's
-        # claim-scatter as an in-kernel slot write (the insert loop is
-        # serial in here, so there is no claim race to re-check and no
-        # O(N)-lane scatter to pay for).
+        # ---- phase 1: sequential hash-probe insert: a claim is an
+        # in-kernel slot write (the insert loop is serial in here, so
+        # there is no claim race to re-check).
         def insert(n, carry):
             nu, ovf = carry
             idv = ids_ref[n]
@@ -1200,8 +1206,7 @@ def fused_sparse_forward(values: jnp.ndarray, ids: jnp.ndarray, *,
                 cond, body,
                 (jnp.int32(0), ~valid, jnp.int32(0), nu, ovf),
             )
-            # probe chain exhausted: same per-position overflow accounting
-            # as hash_dedup's `sum(failed)`.
+            # probe chain exhausted: the position counts as overflow.
             ovf = ovf + jnp.where(valid & ~done, 1, 0).astype(jnp.int32)
             inv_ref[pl.ds(n, 1), :] = u.reshape(1, 1)
 

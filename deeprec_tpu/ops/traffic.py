@@ -834,8 +834,9 @@ def expected_lookup_apply_ops(
 
     Base constants are CALIBRATED against the lowered program (they hold on
     the installed jax 0.9.0: tests/test_traffic_diet.py counts the same ops;
-    the extra ops over a hand inventory come from jnp.unique / hash-dedup
-    internals and clip/where index lowering; `count_stablehlo_ops` counts a
+    the extra ops over a hand inventory come from jnp.unique's internals
+    and clip/where index lowering (the budgeted dedup is sorts and prefix
+    sums: it adds none); `count_stablehlo_ops` counts a
     gather or scatter twice, once for the op and once for its attribute; the
     probe's read-only find loop is one gather, +2 here, beside the claim
     loop's two gathers and one scatter).  The diet deltas are the
@@ -852,8 +853,8 @@ def expected_lookup_apply_ops(
     `bench.py` measures off the actually-lowered program, so any change to
     the engine's op mix must be reflected here (that is the point).
     """
-    if budgeted:  # hash dedup engine front-end (ops/dedup.py)
-        counts = {"gather": 22, "scatter": 14}
+    if budgeted:  # dedup_at_budget front-end (ops/dedup.py)
+        counts = {"gather": 12, "scatter": 10}
     else:  # legacy sort-based jnp.unique front-end
         counts = {"gather": 16, "scatter": 18}
     if not diet:

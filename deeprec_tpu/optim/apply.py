@@ -6,7 +6,7 @@ matching value/slot rows, runs the optimizer row-function, masks out invalid /
 filter-blocked keys, and scatters everything back. One fused pass over [U, D].
 
 U is whatever the dedup produced: the full flattened batch on the legacy
-path, or the static unique BUDGET under the hash dedup engine
+path, or the static unique BUDGET under the budgeted dedup
 (ops/dedup.py) — the whole gather->update->scatter pass shrinks with it.
 Budget-overflowed ids never reach here as rows: their positions point at
 the reserved sentinel entry (uids[0], valid=False), which the `ok` mask

@@ -6,7 +6,7 @@ per-key slot updates executed inside the PS. Here each optimizer is a pure
 row-function: it receives the gathered value/slot rows for the unique touched
 keys ([U, D]) plus per-key batch counts, and returns updated rows which the
 table scatters back. XLA fuses the whole thing into one pass over [U, D],
-where U is the dedup width — the unique BUDGET when the hash dedup engine
+where U is the dedup width — the unique BUDGET when the budgeted dedup
 (ops/dedup.py) is engaged, so the optimizer pass shrinks with it too.
 
 `*WithCounts` semantics: DeepRec's WithCounts variants thread the per-key

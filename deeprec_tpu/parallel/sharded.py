@@ -325,7 +325,7 @@ class ShardedTable:
         unique_size: Optional[int] = None,
         plan=None,
     ) -> Tuple[TableState, ShardedLookup]:
-        """`unique_size` (static) engages the hash dedup engine at that
+        """`unique_size` (static) engages the budgeted dedup at that
         budget BEFORE the exchange: the all_gather/all2all id payload, the
         owner-side work and the embedding return all shrink by the same
         U/N factor. None keeps the legacy sort-unique at U = N.
@@ -364,13 +364,13 @@ class ShardedTable:
                      budgeted: bool = False):
         """Dedup exchanged ids on the owner side (the same id may arrive from
         many peers) and segment-sum their counts. Under a budget the dedup
-        is the sort-free hash engine sized to hold every exchanged id (a
-        few pad slots over G), so the owner side never overflows."""
+        is `dedup_at_budget` sized to hold every exchanged id (a few pad
+        slots over G), so the owner side never overflows."""
         G = g_ids.shape[0]
         if budgeted:
             from deeprec_tpu.ops import dedup
 
-            o_uids, o_inverse, o_counts, _ = dedup.hash_dedup(
+            o_uids, o_inverse, o_counts, _ = dedup.dedup_at_budget(
                 jnp.where(include, g_ids, sentinel),
                 dedup.resolve_size(G, G),
                 sentinel=empty_key(self.table.cfg),

@@ -368,6 +368,32 @@ def test_the_find_loop_reads_a_window_of_keys_a_pass_through_the_row_kernel(
     assert {"sort", "scatter"} & claim, claim
 
 
+@pytest.mark.parametrize("size", [2304, 8200])   # `.zipf`, `.uniform`
+def test_route_is_three_sorts_and_no_loop_scatter_or_gather(one_chip, size):
+    """`jax.vmap(route_ids)` over the bundle's `[26, 8192]` ids at the
+    cells' budgets: the dedup is three native sorts along the id axis (by
+    hash and id; back to input order; the groups' heads to the front) and
+    prefix sums. What went with the claim loop must not come back: no
+    `while`, no `scatter` and no `gather` at all (both step 3 and step 4
+    kept the sort on the chip's readings, PERF.md section 6, PR 36: the
+    number of per-id accesses this pins is ZERO), and no value the size of
+    the old scratch (`[26, 65536]`)."""
+    from benchmark import trace_reduce
+    from deeprec_tpu.ops import dedup
+
+    sent = int(jnp.iinfo(jnp.int32).min)
+    hlo = jax.jit(jax.vmap(lambda ids: dedup.route_ids(
+        ids, pad_value=-1, sentinel=sent, unique_size=size))).lower(
+        _sd(one_chip)((T, 8192), jnp.int32)).compile().as_text()
+    ops = [i["op"] for i in trace_reduce.parse_hlo(hlo).values()]
+    assert ops.count("sort") == 3, ops
+    assert not {"while", "scatter", "gather", "custom-call"} & set(ops), ops
+    assert f"[{T},65536]" not in hlo
+    sorts = [line for line in hlo.splitlines() if " sort(" in line]
+    assert all(f"[{T},8192]" in line and "dimensions={1}" in line
+               for line in sorts), sorts
+
+
 def test_the_find_loop_of_int64_keys_takes_xlas_row_gather(one_chip,
                                                            monkeypatch):
     """A row of 128 int64 keys is 1 KiB, which the row kernel does not move

@@ -1,11 +1,13 @@
-"""Hash dedup engine (ops/dedup.py) + unique budgets through the hot path.
+"""Budgeted dedup (ops/dedup.py) + unique budgets through the hot path.
 
 Three layers, matching the test_train_steps standard (exact on table ints):
 
   * engine vs `jnp.unique`: same unique set / counts / inverse semantics
     (hash order instead of sorted order), pad-sentinel collapse, defined
     overflow saturation past the budget, and all of it under `vmap` (the
-    stacked-bundle layout).
+    stacked-bundle layout); and, array for array, against the same
+    contract written in NumPy over a grid of shapes, alone, under `vmap`
+    and inside a `lax.scan`.
   * budgeted `lookup_unique` vs the legacy path: identical per-key table
     content when the budget covers the batch; default-serving + no-update
     semantics for overflowed ids when it does not.
@@ -37,7 +39,7 @@ def _collapse(ids, pad=-1):
 # ------------------------------------------------------------ engine level
 
 
-def test_hash_dedup_matches_jnp_unique_semantics():
+def test_dedup_at_budget_matches_jnp_unique_semantics():
     rng = np.random.default_rng(0)
     for trial in range(4):
         N = int(rng.integers(64, 2000))
@@ -46,7 +48,7 @@ def test_hash_dedup_matches_jnp_unique_semantics():
         flat = _collapse(ids)
         size = dedup.resolve_size(N, N)  # no-overflow budget
         u, inv, c, ovf = map(
-            np.asarray, dedup.hash_dedup(jnp.asarray(flat), size, sentinel=SENT)
+            np.asarray, dedup.dedup_at_budget(jnp.asarray(flat), size, sentinel=SENT)
         )
         ref = np.unique(flat[flat != SENT])
         # same unique set (hash order, not sorted), zero overflow
@@ -65,7 +67,7 @@ def test_hash_dedup_matches_jnp_unique_semantics():
         assert c.sum() == real.sum()
 
 
-def test_hash_dedup_overflow_saturation():
+def test_dedup_at_budget_overflow_saturation():
     """More distinct ids than budget: exactly budget-many survive, the rest
     are counted in overflow and their positions collapse onto the sentinel
     bucket (inverse 0) — never onto another id's row."""
@@ -73,7 +75,7 @@ def test_hash_dedup_overflow_saturation():
     flat = np.arange(N, dtype=np.int32)  # all distinct
     size = dedup.resolve_size(100, N)
     u, inv, c, ovf = map(
-        np.asarray, dedup.hash_dedup(jnp.asarray(flat), size, sentinel=SENT)
+        np.asarray, dedup.dedup_at_budget(jnp.asarray(flat), size, sentinel=SENT)
     )
     kept = u[u != SENT]
     assert len(kept) == size - 1
@@ -84,7 +86,7 @@ def test_hash_dedup_overflow_saturation():
     assert c.sum() == surv.sum()
 
 
-def test_hash_dedup_under_vmap():
+def test_dedup_at_budget_under_vmap():
     rng = np.random.default_rng(3)
     T, N = 5, 384
     ids = rng.integers(0, 60, size=(T, N)).astype(np.int32)
@@ -92,13 +94,13 @@ def test_hash_dedup_under_vmap():
     flat = _collapse(ids)
     size = dedup.resolve_size(N, N)
     vu, vi, vc, vo = jax.vmap(
-        lambda f: dedup.hash_dedup(f, size, sentinel=SENT)
+        lambda f: dedup.dedup_at_budget(f, size, sentinel=SENT)
     )(jnp.asarray(flat))
     for t in range(T):
         u, inv, c, o = (np.asarray(x[t]) for x in (vu, vi, vc, vo))
         su, si, sc, so = map(
             np.asarray,
-            dedup.hash_dedup(jnp.asarray(flat[t]), size, sentinel=SENT),
+            dedup.dedup_at_budget(jnp.asarray(flat[t]), size, sentinel=SENT),
         )
         np.testing.assert_array_equal(u, su)
         np.testing.assert_array_equal(inv, si)
@@ -106,20 +108,164 @@ def test_hash_dedup_under_vmap():
         assert o == so == 0
 
 
-def test_hash_dedup_weighted_counts():
+def test_dedup_at_budget_weighted_counts():
     """Owner-side dedup segment-sums exchanged counts via `weights`."""
     flat = np.array([7, 7, 9, SENT, 9, 7], np.int32)
     w = np.array([2, 3, 5, 100, 1, 4], np.int32)
     size = dedup.resolve_size(6, 6)
     u, inv, c, _ = map(
         np.asarray,
-        dedup.hash_dedup(
+        dedup.dedup_at_budget(
             jnp.asarray(flat), size, sentinel=SENT, weights=jnp.asarray(w)
         ),
     )
     assert c[u == 7][0] == 2 + 3 + 4
     assert c[u == 9][0] == 5 + 1
     assert c[0] == 0  # sentinel weight never lands
+
+
+def _np_dedup(flat, size, sent, weights=None):
+    """The contract of `dedup_at_budget` written out: the distinct real ids
+    in the order of (mixed hash clamped under the sentinel's key, id), the
+    first `size - 1` of them at `uids[1:]`, everything else at bucket 0."""
+    from deeprec_tpu.utils import hashing
+
+    real = flat != sent
+    ids = np.unique(flat[real])
+    key = np.minimum(hashing.mix32_np(hashing.fold64_np(ids)),
+                     np.uint32(0xFFFFFFFE))
+    ids = ids[np.lexsort((ids, key))]
+    kept = ids[: size - 1]
+    uids = np.full((size,), sent, flat.dtype)
+    uids[1: 1 + len(kept)] = kept
+    slot = {int(u): j + 1 for j, u in enumerate(kept)}
+    inverse = np.array([slot.get(int(x), 0) if ok else 0
+                        for x, ok in zip(flat, real)], np.int32)
+    w = np.ones(len(flat), np.int64) if weights is None else weights
+    counts = np.zeros((size,), np.int64)
+    np.add.at(counts, inverse, np.where(inverse > 0, w, 0))
+    return (uids, inverse, counts.astype(np.int32),
+            np.int32(max(len(ids) - (size - 1), 0)))
+
+
+def _case(name):
+    """`(flat, size, weights, dtype)` of one named case, ids from a seed."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    dtype, weights = np.int32, None
+
+    def draw(n, high, pad, low=0):
+        ids = rng.integers(low, high, size=n)
+        ids[rng.random(n) < pad] = -1
+        return ids
+
+    if name == "zipf_like_quarter_padded":
+        ids, budget = draw(1500, 90, 0.25), 1500
+    elif name == "all_distinct_no_padding":
+        ids, budget = rng.permutation(700), 700
+    elif name == "size_over_n":           # resolve_size: _mult8(N + 1) = N + 7
+        ids, budget = draw(65, 40, 0.1), 65
+    elif name == "size_over_n_all_distinct":
+        ids, budget = rng.permutation(33) + 5, 33
+    elif name == "all_sentinel":
+        ids, budget = np.full(96, -1), 16
+    elif name == "one_id_n_times":
+        ids, budget = np.full(128, 77), 128
+    elif name == "n_equals_budget_exactly":    # 23 distinct, size 24
+        ids, budget = np.concatenate([np.arange(23), draw(177, 23, 0.2)]), 23
+    elif name == "one_over_budget":            # 24 distinct, size 24
+        ids, budget = np.concatenate([np.arange(24), draw(176, 24, 0.2)]), 23
+    elif name == "far_over_budget":
+        ids, budget = rng.permutation(512), 100
+    elif name == "weighted_under_overflow":
+        ids, budget = draw(400, 120, 0.15), 50
+        weights = rng.integers(0, 9, size=400)
+    elif name == "weighted_covering_budget":
+        ids, budget = draw(300, 50, 0.3), 300
+        weights = rng.integers(1, 1000, size=300)
+    elif name == "negative_and_large_ids":
+        ids, budget = draw(600, 2**31 - 1, 0.1, low=-(2**31) + 1), 256
+        ids[::7] = ids[0]
+    elif name == "two_ids_share_the_key_under_the_sentinels":
+        # mix32 sends these to 0xFFFFFFFF and 0xFFFFFFFE: clamped, one key
+        pair = np.array([857579651, -606117695])
+        ids = np.concatenate([pair[rng.integers(0, 2, 40)], draw(60, 30, .3)])
+        ids, budget = rng.permutation(ids), 100
+    elif name == "int64_ids":                  # distinct ids, equal low words
+        ids = draw(500, 60, 0.2).astype(np.int64)
+        ids = np.where(ids >= 0, ids + (ids % 3 << 40), ids)
+        budget, dtype = 500, np.int64
+    elif name == "int64_ids_over_budget_weighted":
+        ids = (draw(300, 2**62, 0.1, low=2**33)).astype(np.int64)
+        ids[::5] = ids[1]
+        budget, dtype = 40, np.int64
+        weights = rng.integers(0, 5, size=300)
+    else:
+        raise KeyError(name)
+    sent = int(np.iinfo(dtype).min)
+    flat = np.where(ids == -1, sent, ids).astype(dtype)
+    return flat, dedup.resolve_size(budget, len(flat)), weights, dtype
+
+
+CASES = [
+    "zipf_like_quarter_padded", "all_distinct_no_padding", "size_over_n",
+    "size_over_n_all_distinct", "all_sentinel", "one_id_n_times",
+    "n_equals_budget_exactly", "one_over_budget", "far_over_budget",
+    "weighted_under_overflow", "weighted_covering_budget",
+    "negative_and_large_ids", "two_ids_share_the_key_under_the_sentinels",
+    "int64_ids", "int64_ids_over_budget_weighted",
+]
+
+
+@pytest.mark.parametrize("name", CASES)
+def test_dedup_at_budget_is_its_contract_alone_under_vmap_and_in_a_scan(name):
+    """Array for array the NumPy contract; then the same batch as one of 5
+    tables under `vmap` and as one of 3 steps of a `lax.scan`, exact."""
+    flat, size, weights, dtype = _case(name)
+    sent = int(np.iinfo(dtype).min)
+    want = _np_dedup(flat, size, sent, weights)
+    if name == "size_over_n":
+        assert size > len(flat)
+    if name == "n_equals_budget_exactly":
+        assert (want[0] != sent).sum() == size - 1 and want[3] == 0
+    if name == "one_over_budget":
+        assert want[3] == 1
+    if name == "weighted_under_overflow":
+        assert want[3] > 0
+    if name == "two_ids_share_the_key_under_the_sentinels":
+        from deeprec_tpu.utils import hashing
+        pair = np.array([857579651, -606117695]).astype(np.uint32)
+        assert list(hashing.mix32_np(pair)) == [0xFFFFFFFF, 0xFFFFFFFE]
+        assert set(pair.astype(np.int32)) <= set(flat)
+
+    with jax.enable_x64(dtype == np.int64):
+        w = None if weights is None else jnp.asarray(weights, jnp.int32)
+
+        def one(f, w):
+            return dedup.dedup_at_budget(f, size, sentinel=sent, weights=w)
+
+        got = jax.jit(one)(jnp.asarray(flat), w)
+        assert [g.dtype for g in got] == [dtype, np.int32, np.int32, np.int32]
+        for g, e in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g), e)
+
+        # 5 tables / 3 steps: this batch and rotations of it with other ids
+        # padded, so the lanes differ and every lane has its own answer
+        rows = [flat] + [
+            np.where(np.arange(len(flat)) % (k + 2) == 0, sent,
+                     np.roll(flat, k)) for k in range(1, 5)]
+        stack = jnp.asarray(np.stack(rows))
+        ws = None if w is None else jnp.stack([w] * 5)
+        batched = jax.jit(jax.vmap(one, in_axes=(0, None if w is None else 0))
+                          )(stack, ws)
+        _, scanned = jax.lax.scan(
+            lambda c, x: (c, one(x, w)), 0, stack[:3])
+        for t, row in enumerate(rows):
+            e = _np_dedup(row, size, sent, weights)
+            for k in range(4):
+                np.testing.assert_array_equal(np.asarray(batched[k][t]), e[k])
+                if t < 3:
+                    np.testing.assert_array_equal(
+                        np.asarray(scanned[k][t]), e[k])
 
 
 # ------------------------------------------------------------ table level
@@ -196,7 +342,7 @@ def test_trainer_budget_typo_rejected():
 
 def test_default_unique_size_resolution():
     """cfg.unique_budget routes the no-argument lookup: int engages the
-    hash engine at that size, None/"auto"/"off" keep legacy U=N."""
+    budgeted dedup at that size, None/"auto"/"off" keep legacy U=N."""
     assert _table().default_unique_size(128) is None
     assert _table(unique_budget="auto").default_unique_size(128) is None
     assert _table(unique_budget="off").default_unique_size(128) is None
@@ -262,7 +408,7 @@ def test_budgeted_train_matches_legacy_per_key():
 
 def test_train_steps_scan_parity_with_budget():
     """K-step scan == K sequential steps, exact on table ints, with the
-    hash dedup engine engaged (fixed budget)."""
+    budgeted dedup engaged (fixed budget)."""
     K = 4
     batches = _batches(K)
     tr = Trainer(_model(), Adagrad(lr=0.1), optax.adam(2e-3),

@@ -128,7 +128,7 @@ def test_lookahead_hazard_overlapping_ids_single_device():
 
 
 def test_lookahead_with_unique_budget():
-    """The split-phase route carries the hash dedup engine: budgeted
+    """The split-phase route carries the budgeted dedup: budgeted
     pipelined scan == budgeted sequential scan exactly."""
     batches = window_batches(3)
     t_off = Trainer(model(), Adagrad(lr=0.1), unique_budget=64)

@@ -1,17 +1,26 @@
-"""Microbenchmark: sort-based `jnp.unique` vs the hash dedup engine.
+"""Microbenchmark: `jnp.unique` (`sort_unique`) vs `dedup_at_budget`.
 
-Measures the two dedup implementations behind the embedding hot path
+Times the two dedup implementations behind the embedding hot path
 (`ops/dedup.py`) at identical static output sizes, across flattened batch
 size N, unique-budget ratios U/N and zipf skew — the knob space of
 `TableConfig.unique_budget`. The reference shape is the DLRM bench batch:
 N = 26 features x 2048 = 53,248 flattened ids, U/N = 0.25, zipf α = 1.05
 (the heaviest-tail column of the CriteoStats generator).
 
+ITS GRID IS A CPU GRID: it runs on whatever platform jax resolves (named in
+the JSON), times each call from the host, and has only ever been recorded
+on this box's CPU (docs/perf.md), where the claim-race loop PR 36 replaced
+beat `jnp.unique` 1.45-1.89x and the sort form that replaced it is about
+3x slower than that loop. None of that carries to a TPU, where the
+order is the other way round (PERF.md section 6, PR 36); what the chip
+pays for the dedup is `route_device_ms_per_step` of the benchmark's
+cells, read from a device trace.
+
 Prints ONE JSON line (the bench.py convention):
-  rows[]    — per-(N, ratio, alpha): sort_ms, hash_ms, speedup,
-              true_unique_frac, overflow (ids past the budget, served the
-              default by the engine's contract)
-  reference — the DLRM reference-shape row, the acceptance comparison
+  rows[]    — per-(N, ratio, alpha): sort_ms (`sort_unique`), budget_ms
+              (`dedup_at_budget`), speedup, true_unique_frac, overflow
+              (ids past the budget, served the default by the contract)
+  reference — the DLRM reference-shape row
 
 `--smoke` shrinks the grid and the timed windows so CI merely proves both
 paths compile and run (cibuild/run_tests.sh).
@@ -46,8 +55,8 @@ def _bench_one(N, ratio, alpha, reps, vocab=None):
     sort_fn = jax.jit(  # noqa: DRT001 — built once per bench invocation, reused across the timed loop
         lambda f: dedup.sort_unique(f, size, sentinel=sentinel)
     )
-    hash_fn = jax.jit(  # noqa: DRT001 — built once per bench invocation, reused across the timed loop
-        lambda f: dedup.hash_dedup(f, size, sentinel=sentinel)
+    budget_fn = jax.jit(  # noqa: DRT001 — built once per bench invocation, reused across the timed loop
+        lambda f: dedup.dedup_at_budget(f, size, sentinel=sentinel)
     )
     x = jnp.asarray(flat)
 
@@ -61,16 +70,16 @@ def _bench_one(N, ratio, alpha, reps, vocab=None):
         return best * 1e3
 
     sort_ms = timed(sort_fn)
-    hash_ms = timed(hash_fn)
-    overflow = int(hash_fn(x)[3])
+    budget_ms = timed(budget_fn)
+    overflow = int(budget_fn(x)[3])
     return {
         "N": N,
         "ratio": ratio,
         "alpha": alpha,
         "size": size,
         "sort_ms": round(sort_ms, 3),
-        "hash_ms": round(hash_ms, 3),
-        "speedup": round(sort_ms / hash_ms, 2) if hash_ms else None,
+        "budget_ms": round(budget_ms, 3),
+        "speedup": round(sort_ms / budget_ms, 2) if budget_ms else None,
         "true_unique_frac": round(true_unique / N, 4),
         "overflow": overflow,
     }
@@ -113,7 +122,7 @@ def main():
         ref = _bench_one(REFERENCE["N"], REFERENCE["ratio"],
                          REFERENCE["alpha"], reps)
     print(json.dumps({
-        "metric": "dedup_sort_vs_hash",
+        "metric": "dedup_unique_vs_budget",
         "rows": rows,
         "reference": ref,
         "device": jax.devices()[0].platform,

@@ -265,7 +265,7 @@ def main_fused_step(args):
     """Fused single-pass sparse step vs the split-phase XLA path.
 
     Both arms run the SAME contract (fused_sparse_forward/backward): the
-    unfused arm takes the XLA fallback (hash_dedup -> gather -> combine;
+    unfused arm takes the XLA fallback (dedup_at_budget -> gather -> combine;
     expand -> segment-add -> gather/update/scatter), the fused arm the
     Pallas kernel — interpret=True off-TPU, so off-TPU step times say
     nothing about the TPU answer and the verdict here is (a) parity and
