@@ -51,8 +51,6 @@ ROOT = os.path.dirname(os.path.abspath(__file__))
 OUT = os.path.join(ROOT, "chip_smoke_out")
 
 SHARDED_COMMS = ("a2a", "allgather")
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -81,45 +79,34 @@ class Size:
 
 class CompileClock:
     """Seconds jax spent compiling (or loading from the persistent cache)
-    and how many programs the cache served, from jax.monitoring — so a
-    phase's set-up cost is read off the compiler, not guessed from a wall
-    clock that also holds the first step."""
+    and how many programs the cache served — so a phase's set-up cost is
+    read off the compiler, not guessed from a wall clock that also holds
+    the first step. A view of the package's one recorder of compile events
+    (deeprec_tpu/obs/compile_log.py), which hears jax.monitoring."""
 
     def __init__(self):
-        import jax
+        from deeprec_tpu.obs import compile_log
 
-        self.seconds = 0.0
-        self.programs = 0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_secs)
-        jax.monitoring.register_event_listener(self._on_event)
+        compile_log.install()
+        self._log = compile_log
 
-    def close(self):
-        import jax
-
-        jax.monitoring.unregister_event_duration_listener(self._on_secs)
-        jax.monitoring.unregister_event_listener(self._on_event)
-
-    def _on_secs(self, event, duration, **_):
-        if event == _COMPILE_EVENT:
-            self.seconds += duration
-            self.programs += 1
-
-    def _on_event(self, event, **_):
-        if event == _CACHE_HIT_EVENT:
-            self.cache_hits += 1
+    def _now(self) -> Tuple[float, int, int]:
+        snap = self._log.snapshot()
+        return (snap["total_s"].get(self._log.BACKEND, 0.0),
+                snap["spans"].get(self._log.BACKEND, 0),
+                snap["cache"]["hit"])
 
     @contextmanager
     def phase(self, name: str, facts: Dict):
-        s0, p0, h0, t0 = (self.seconds, self.programs, self.cache_hits,
-                          time.perf_counter())
+        (s0, p0, h0), t0 = self._now(), time.perf_counter()
         print(f"== phase {name}", flush=True)
         rec = facts.setdefault(name, {})
         yield rec
+        s1, p1, h1 = self._now()
         rec["wall_s"] = round(time.perf_counter() - t0, 2)
-        rec["compile_s"] = round(self.seconds - s0, 2)
-        rec["programs"] = self.programs - p0
-        rec["cache_hits"] = self.cache_hits - h0
+        rec["compile_s"] = round(s1 - s0, 2)
+        rec["programs"] = p1 - p0
+        rec["cache_hits"] = h1 - h0
         print(f"== phase {name} ok: set-up (compile or cache load) "
               f"{rec['compile_s']} s for {rec['programs']} programs "
               f"({rec['cache_hits']} from the cache), "
@@ -543,25 +530,22 @@ def run(size: Size) -> Dict:
     clock = CompileClock()
     fallbacks0 = fallback_counts()
     facts: Dict = {}
-    try:
-        with clock.phase("train", facts) as rec:
-            ckpt = phase_train(size, rec)
-        with clock.phase("resume", facts) as rec:
-            ref_rows, ref_probs = phase_resume(
-                size, rec, ckpt, facts["train"]["first_loss"])
-        with clock.phase("serve", facts) as rec:
-            phase_serve(size, rec, ckpt, ref_rows, ref_probs)
-        with clock.phase("kernels", facts) as rec:
-            one_chip_loss = phase_kernels(size, rec, fallbacks0)
-        if len(jax.devices()) >= 4:
-            with clock.phase("sharded", facts) as rec:
-                phase_sharded(size, rec, one_chip_loss)
-        else:
-            facts["sharded"] = {"ran": False}
-            print(f"== phase sharded did not run: it needs 4 devices and jax "
-                  f"reports {len(jax.devices())}", flush=True)
-    finally:
-        clock.close()
+    with clock.phase("train", facts) as rec:
+        ckpt = phase_train(size, rec)
+    with clock.phase("resume", facts) as rec:
+        ref_rows, ref_probs = phase_resume(
+            size, rec, ckpt, facts["train"]["first_loss"])
+    with clock.phase("serve", facts) as rec:
+        phase_serve(size, rec, ckpt, ref_rows, ref_probs)
+    with clock.phase("kernels", facts) as rec:
+        one_chip_loss = phase_kernels(size, rec, fallbacks0)
+    if len(jax.devices()) >= 4:
+        with clock.phase("sharded", facts) as rec:
+            phase_sharded(size, rec, one_chip_loss)
+    else:
+        facts["sharded"] = {"ran": False}
+        print(f"== phase sharded did not run: it needs 4 devices and jax "
+              f"reports {len(jax.devices())}", flush=True)
     shutil.rmtree(os.path.join(OUT, "ckpt"))  # hundreds of MB; facts stay
     return facts
 

@@ -7,8 +7,11 @@ eviction, frequency-aware sparse optimizers, pod-sharded tables over ICI
 collectives, staged input pipelines, full+incremental checkpointing, a
 modelzoo and a serving path. See SURVEY.md for the blueprint.
 """
+import time as _time
 
-from deeprec_tpu.config import (
+_IMPORT_T0 = _time.time(), _time.perf_counter()  # first: the import is timed
+
+from deeprec_tpu.config import (  # noqa: E402
     CBFFilter,
     CheckpointConfig,
     CheckpointOption,
@@ -27,3 +30,10 @@ from deeprec_tpu.embedding.combiners import combine
 from deeprec_tpu.features import DenseFeature, SparseFeature
 
 __version__ = "0.1.0"
+
+# The package's import as a part of set-up (jax's is inside it where the
+# package is the first to import jax): deeprec_setup_seconds_total{stage="import"}.
+from deeprec_tpu.obs import compile_log as _compile_log  # noqa: E402
+
+_compile_log.record(_compile_log.SETUP, "import", _IMPORT_T0[0], _time.time(),
+                    _time.perf_counter() - _IMPORT_T0[1])

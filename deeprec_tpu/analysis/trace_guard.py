@@ -16,11 +16,12 @@ executable:
         state, mets = trainer.train_steps(state, stacked)
     print(g.compiles)                     # 0 after warmup, by contract
 
-Counting rides jax.monitoring: one process-global listener (installed
-lazily on first use, never removed) increments counters on the
-``/jax/core/compile/backend_compile_duration`` event — fired exactly
-once per real XLA compilation, never on an executable-cache hit — and on
-``/jax/core/compile/jaxpr_trace_duration`` (tracing; informational,
+Counting rides jax.monitoring through the package's one recorder of
+compile events (obs/compile_log.py: installed on first use at the latest,
+never removed; its own counts, which ``DEEPREC_OBS=off`` does not
+silence): the ``/jax/core/compile/backend_compile_duration`` event — fired
+exactly once per real XLA compilation, never on an executable-cache hit —
+and ``/jax/core/compile/jaxpr_trace_duration`` (tracing; informational,
 retraces that hit the persistent compilation cache still cost a trace).
 Counters are process-wide: a guard around region R sees compiles from
 ANY thread that lands inside R's window. That is the desired semantics
@@ -37,16 +38,10 @@ zero).
 """
 from __future__ import annotations
 
-import threading
 from contextlib import contextmanager
 from typing import Optional
 
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
-
-_lock = threading.Lock()
-_counts = {"compiles": 0, "traces": 0}
-_installed = False
+from deeprec_tpu.obs import compile_log
 
 
 class TraceGuardViolation(AssertionError):
@@ -58,44 +53,18 @@ class TraceGuardViolation(AssertionError):
         self.max_compiles = max_compiles
 
 
-def _install() -> None:
-    """Register the process-global monitoring listener (idempotent). It is
-    installed once and counts forever; guards diff the counter."""
-    global _installed
-    if _installed:
-        return
-    with _lock:
-        if _installed:
-            return
-        import jax
-
-        def _on_duration(event, duration, **kwargs):
-            # compiles can land from any thread (background pollers,
-            # writer warm passes); the lock keeps the counters exact
-            # and costs nothing next to an XLA compile
-            if event == _COMPILE_EVENT:
-                with _lock:
-                    _counts["compiles"] += 1
-            elif event == _TRACE_EVENT:
-                with _lock:
-                    _counts["traces"] += 1
-
-        jax.monitoring.register_event_duration_secs_listener(_on_duration)
-        _installed = True
-
-
 def compile_count() -> int:
     """Process-lifetime count of real XLA compilations observed so far
-    (only since the first trace_guard/compile_count use — the listener
-    installs lazily)."""
-    _install()
-    return _counts["compiles"]
+    (only since the recorder was installed: by an entry point's
+    `enable_compile_cache()`, a `Trainer`, or the first use here)."""
+    compile_log.install()
+    return compile_log.compiles()
 
 
 def trace_count() -> int:
     """Process-lifetime count of jaxpr traces observed so far."""
-    _install()
-    return _counts["traces"]
+    compile_log.install()
+    return compile_log.traces()
 
 
 class _Guard:
@@ -107,11 +76,11 @@ class _Guard:
 
     @property
     def compiles(self) -> int:
-        return _counts["compiles"] - self._c0
+        return compile_log.compiles() - self._c0
 
     @property
     def traces(self) -> int:
-        return _counts["traces"] - self._t0
+        return compile_log.traces() - self._t0
 
 
 @contextmanager
@@ -122,8 +91,7 @@ def trace_guard(max_compiles: Optional[int] = 0, note: str = ""):
     remain valid after exit. Exceptions from the body propagate
     unchanged (the budget is not checked on an already-failing region).
     """
-    _install()
-    g = _Guard(_counts["compiles"], _counts["traces"])
+    g = _Guard(compile_count(), trace_count())
     # A body exception propagates from the yield on its own and skips the
     # budget check — a failing region is never double-reported.
     yield g

@@ -251,32 +251,33 @@ def _pallas_forward(q, k, v, mask, causal, sm_scale, block_q, block_k,
         block_q=block_q, num_kb=num_kb, steps=steps, window=window,
     )
     kv = lambda b, i, j: (b // G, key_block(i, j), 0)  # noqa: E731
-    o, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), kv),
-            pl.BlockSpec((1, block_k, Dv), kv),
-            pl.BlockSpec((1, 1, block_k),
-                         lambda b, i, j: (b // G, 0, key_block(i, j))),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, Lq, Dv), q.dtype),
-            jax.ShapeDtypeStruct((BH, Lq, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, Dv), jnp.float32),
-        ],
-        interpret=interpret,
-        name=scopes.KERNEL_FLASH_FWD,
-    )(qr, kr, vr, maskr)
+    with scopes.kernel_trace(scopes.KERNEL_FLASH_FWD):
+        o, lse = pl.pallas_call(
+            kernel,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, block_k, D), kv),
+                pl.BlockSpec((1, block_k, Dv), kv),
+                pl.BlockSpec((1, 1, block_k),
+                             lambda b, i, j: (b // G, 0, key_block(i, j))),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((BH, Lq, Dv), q.dtype),
+                jax.ShapeDtypeStruct((BH, Lq, 1), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, Dv), jnp.float32),
+            ],
+            interpret=interpret,
+            name=scopes.KERNEL_FLASH_FWD,
+        )(qr, kr, vr, maskr)
     return o.reshape(B, H, Lq, Dv), lse.reshape(B, H, Lq)
 
 
@@ -458,33 +459,34 @@ def _pallas_backward(q, k, v, mask, causal, sm_scale, block_q, block_k,
     def q_side(b, kb, t):  # step t % q_steps of query head t // q_steps
         return (b * G + t // q_steps, query_block(kb, t % q_steps), 0)
 
-    dk, dv = pl.pallas_call(
-        dkdv_kernel,
-        grid=(BK, num_kb, G * q_steps),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), q_side),                        # q
-            pl.BlockSpec((1, block_k, D), lambda b, kb, t: (b, kb, 0)),   # k
-            pl.BlockSpec((1, block_k, Dv), lambda b, kb, t: (b, kb, 0)),  # v
-            pl.BlockSpec((1, 1, block_k), lambda b, kb, t: (b, 0, kb)),   # mask
-            pl.BlockSpec((1, block_q, Dv), q_side),                       # do
-            pl.BlockSpec((1, block_q, 1), q_side),                        # lse
-            pl.BlockSpec((1, block_q, 1), q_side),                        # delta
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, D), lambda b, kb, t: (b, kb, 0)),
-            pl.BlockSpec((1, block_k, Dv), lambda b, kb, t: (b, kb, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BK, S, D), k.dtype),
-            jax.ShapeDtypeStruct((BK, S, Dv), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, Dv), jnp.float32),
-        ],
-        name=scopes.KERNEL_FLASH_BWD_DKDV,
-        **common,
-    )(qr, kr, vr, maskr, dor, lser, delta)
+    with scopes.kernel_trace(scopes.KERNEL_FLASH_BWD_DKDV):
+        dk, dv = pl.pallas_call(
+            dkdv_kernel,
+            grid=(BK, num_kb, G * q_steps),
+            in_specs=[
+                pl.BlockSpec((1, block_q, D), q_side),                        # q
+                pl.BlockSpec((1, block_k, D), lambda b, kb, t: (b, kb, 0)),   # k
+                pl.BlockSpec((1, block_k, Dv), lambda b, kb, t: (b, kb, 0)),  # v
+                pl.BlockSpec((1, 1, block_k), lambda b, kb, t: (b, 0, kb)),   # mask
+                pl.BlockSpec((1, block_q, Dv), q_side),                       # do
+                pl.BlockSpec((1, block_q, 1), q_side),                        # lse
+                pl.BlockSpec((1, block_q, 1), q_side),                        # delta
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_k, D), lambda b, kb, t: (b, kb, 0)),
+                pl.BlockSpec((1, block_k, Dv), lambda b, kb, t: (b, kb, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((BK, S, D), k.dtype),
+                jax.ShapeDtypeStruct((BK, S, Dv), v.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, D), jnp.float32),
+                pltpu.VMEM((block_k, Dv), jnp.float32),
+            ],
+            name=scopes.KERNEL_FLASH_BWD_DKDV,
+            **common,
+        )(qr, kr, vr, maskr, dor, lser, delta)
 
     k_steps, key_block = _walk(_visible_keys, num_qb, block_q, block_k,
                                num_kb, causal, window)
@@ -494,25 +496,26 @@ def _pallas_backward(q, k, v, mask, causal, sm_scale, block_q, block_k,
         window=window,
     )
     kv = lambda b, i, j: (b // G, key_block(i, j), 0)  # noqa: E731
-    (dq,) = pl.pallas_call(
-        dq_kernel,
-        grid=(BH, num_qb, k_steps),
-        in_specs=[
-            qspec,                                                        # q
-            pl.BlockSpec((1, block_k, D), kv),                            # k
-            pl.BlockSpec((1, block_k, Dv), kv),                           # v
-            pl.BlockSpec((1, 1, block_k),
-                         lambda b, i, j: (b // G, 0, key_block(i, j))),   # mask
-            dospec,                                                       # do
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),     # lse
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),     # delta
-        ],
-        out_specs=[qspec],
-        out_shape=[jax.ShapeDtypeStruct((BH, Lq, D), q.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        name=scopes.KERNEL_FLASH_BWD_DQ,
-        **common,
-    )(qr, kr, vr, maskr, dor, lser, delta)
+    with scopes.kernel_trace(scopes.KERNEL_FLASH_BWD_DQ):
+        (dq,) = pl.pallas_call(
+            dq_kernel,
+            grid=(BH, num_qb, k_steps),
+            in_specs=[
+                qspec,                                                        # q
+                pl.BlockSpec((1, block_k, D), kv),                            # k
+                pl.BlockSpec((1, block_k, Dv), kv),                           # v
+                pl.BlockSpec((1, 1, block_k),
+                             lambda b, i, j: (b // G, 0, key_block(i, j))),   # mask
+                dospec,                                                       # do
+                pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),     # lse
+                pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),     # delta
+            ],
+            out_specs=[qspec],
+            out_shape=[jax.ShapeDtypeStruct((BH, Lq, D), q.dtype)],
+            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+            name=scopes.KERNEL_FLASH_BWD_DQ,
+            **common,
+        )(qr, kr, vr, maskr, dor, lser, delta)
 
     return (
         dq.reshape(B, H, Lq, D),

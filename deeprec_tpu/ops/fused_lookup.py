@@ -433,13 +433,14 @@ def gather_rows_pair(values: jnp.ndarray, ix: jnp.ndarray, *,
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
-    out = pl.pallas_call(
-        kernel,
-        name=scopes.KERNEL_GATHER_ROWS_PAIR,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((np_, D), values.dtype),
-        interpret=interpret,
-    )(ixp, values)
+    with scopes.kernel_trace(scopes.KERNEL_GATHER_ROWS_PAIR):
+        out = pl.pallas_call(
+            kernel,
+            name=scopes.KERNEL_GATHER_ROWS_PAIR,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((np_, D), values.dtype),
+            interpret=interpret,
+        )(ixp, values)
     return out[:n]
 
 
@@ -519,15 +520,16 @@ def apply_rows_sr_pair(values: jnp.ndarray, slot_ix: jnp.ndarray,
             pltpu.SemaphoreType.DMA((1,)),
         ],
     )
-    return pl.pallas_call(
-        kernel,
-        name=scopes.KERNEL_APPLY_ROWS_SR_PAIR,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(values.shape, values.dtype),
-        input_output_aliases={3: 0},
-        compiler_params=pltpu.CompilerParams(has_side_effects=True),
-        interpret=interpret,
-    )(ixp, new_rows, bits, values)
+    with scopes.kernel_trace(scopes.KERNEL_APPLY_ROWS_SR_PAIR):
+        return pl.pallas_call(
+            kernel,
+            name=scopes.KERNEL_APPLY_ROWS_SR_PAIR,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(values.shape, values.dtype),
+            input_output_aliases={3: 0},
+            compiler_params=pltpu.CompilerParams(has_side_effects=True),
+            interpret=interpret,
+        )(ixp, new_rows, bits, values)
 
 
 # ------------------------------------------------------------- gather_rows
@@ -664,13 +666,14 @@ def _gather_call(t0, ixp, values, moved=None, *, n, block, interpret):
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[pltpu.SemaphoreType.DMA(())],
     )
-    return pl.pallas_call(
-        kernel,
-        name=scopes.KERNEL_GATHER_ROWS,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((tables, n, D), values.dtype),
-        interpret=interpret,
-    )(t0, ixp.reshape(-1), *([moved.reshape(-1)] if skip else []), values)
+    with scopes.kernel_trace(scopes.KERNEL_GATHER_ROWS):
+        return pl.pallas_call(
+            kernel,
+            name=scopes.KERNEL_GATHER_ROWS,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((tables, n, D), values.dtype),
+            interpret=interpret,
+        )(t0, ixp.reshape(-1), *([moved.reshape(-1)] if skip else []), values)
 
 
 @functools.lru_cache(maxsize=None)
@@ -795,13 +798,14 @@ def fused_gather_combine(values: jnp.ndarray, row_ix: jnp.ndarray,
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
-    out = pl.pallas_call(
-        kernel,
-        name=scopes.KERNEL_FUSED_GATHER_COMBINE,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Bp, D), jnp.float32),
-        interpret=interpret,
-    )(flat_ix, flat_w, values)
+    with scopes.kernel_trace(scopes.KERNEL_FUSED_GATHER_COMBINE):
+        out = pl.pallas_call(
+            kernel,
+            name=scopes.KERNEL_FUSED_GATHER_COMBINE,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((Bp, D), jnp.float32),
+            interpret=interpret,
+        )(flat_ix, flat_w, values)
     return out[:B]
 
 
@@ -927,15 +931,16 @@ def _apply_call(t0, ixp, new_rows, values, *, block, interpret):
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[pltpu.SemaphoreType.DMA(())],
     )
-    return pl.pallas_call(
-        kernel,
-        name=scopes.KERNEL_APPLY_ROWS_SR,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(values.shape, values.dtype),
-        input_output_aliases={3: 0},
-        compiler_params=pltpu.CompilerParams(has_side_effects=True),
-        interpret=interpret,
-    )(t0, ixp.reshape(-1), new_rows, values)
+    with scopes.kernel_trace(scopes.KERNEL_APPLY_ROWS_SR):
+        return pl.pallas_call(
+            kernel,
+            name=scopes.KERNEL_APPLY_ROWS_SR,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(values.shape, values.dtype),
+            input_output_aliases={3: 0},
+            compiler_params=pltpu.CompilerParams(has_side_effects=True),
+            interpret=interpret,
+        )(t0, ixp.reshape(-1), new_rows, values)
 
 
 @functools.lru_cache(maxsize=None)
@@ -1305,20 +1310,21 @@ def fused_sparse_forward(values: jnp.ndarray, ids: jnp.ndarray, *,
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
-    out, uids, inv, cnt, ovf = pl.pallas_call(
-        kernel,
-        name=scopes.KERNEL_FUSED_SPARSE_FORWARD,
-        grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((B, D), jnp.float32),
-            jax.ShapeDtypeStruct((U, 1), jnp.int32),
-            jax.ShapeDtypeStruct((N, 1), jnp.int32),
-            jax.ShapeDtypeStruct((U, 1), jnp.int32),
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        ),
-        compiler_params=pltpu.CompilerParams(has_side_effects=True),
-        interpret=interpret,
-    )(flat, values)
+    with scopes.kernel_trace(scopes.KERNEL_FUSED_SPARSE_FORWARD):
+        out, uids, inv, cnt, ovf = pl.pallas_call(
+            kernel,
+            name=scopes.KERNEL_FUSED_SPARSE_FORWARD,
+            grid_spec=grid_spec,
+            out_shape=(
+                jax.ShapeDtypeStruct((B, D), jnp.float32),
+                jax.ShapeDtypeStruct((U, 1), jnp.int32),
+                jax.ShapeDtypeStruct((N, 1), jnp.int32),
+                jax.ShapeDtypeStruct((U, 1), jnp.int32),
+                jax.ShapeDtypeStruct((1, 1), jnp.int32),
+            ),
+            compiler_params=pltpu.CompilerParams(has_side_effects=True),
+            interpret=interpret,
+        )(flat, values)
     return _combine_epilogue(
         FusedBags(out, uids[:, 0], inv[:, 0].reshape(B, L), cnt[:, 0],
                   ovf[0, 0]),
@@ -1536,28 +1542,29 @@ def fused_sparse_backward(values: jnp.ndarray,
         ] + [pltpu.VMEM((U, D), jnp.float32) for _ in range(K)]
         + [pltpu.SemaphoreType.DMA((1 + K,))],
     )
-    outs = pl.pallas_call(
-        kernel,
-        name=scopes.KERNEL_FUSED_SPARSE_BACKWARD,
-        grid_spec=grid_spec,
-        out_shape=tuple(
-            [jax.ShapeDtypeStruct(values.shape, values.dtype)]
-            + [jax.ShapeDtypeStruct(slots[n].shape, slots[n].dtype)
-               for n in snames]
-        ),
-        input_output_aliases={8 + i: i for i in range(1 + K)},
-        compiler_params=pltpu.CompilerParams(has_side_effects=True),
-        interpret=interpret,
-    )(
-        jnp.clip(res.uids, -1, C - 1).astype(jnp.int32),
-        res.inverse.reshape(-1).astype(jnp.int32),
-        mask.reshape(-1).astype(jnp.int32),
-        step.reshape(1),
-        lr.reshape(1),
-        gs,
-        res.counts.reshape(U, 1),
-        bits_in,
-        values,
-        *[slots[n] for n in snames],
-    )
+    with scopes.kernel_trace(scopes.KERNEL_FUSED_SPARSE_BACKWARD):
+        outs = pl.pallas_call(
+            kernel,
+            name=scopes.KERNEL_FUSED_SPARSE_BACKWARD,
+            grid_spec=grid_spec,
+            out_shape=tuple(
+                [jax.ShapeDtypeStruct(values.shape, values.dtype)]
+                + [jax.ShapeDtypeStruct(slots[n].shape, slots[n].dtype)
+                   for n in snames]
+            ),
+            input_output_aliases={8 + i: i for i in range(1 + K)},
+            compiler_params=pltpu.CompilerParams(has_side_effects=True),
+            interpret=interpret,
+        )(
+            jnp.clip(res.uids, -1, C - 1).astype(jnp.int32),
+            res.inverse.reshape(-1).astype(jnp.int32),
+            mask.reshape(-1).astype(jnp.int32),
+            step.reshape(1),
+            lr.reshape(1),
+            gs,
+            res.counts.reshape(U, 1),
+            bits_in,
+            values,
+            *[slots[n] for n in snames],
+        )
     return outs[0], {snames[k]: outs[1 + k] for k in range(K)}

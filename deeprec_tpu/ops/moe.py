@@ -174,25 +174,28 @@ def _gmm_pallas(x, w, block_expert, block: int, transpose_w: bool,
 
     R, K = x.shape
     N = w.shape[1] if transpose_w else w.shape[2]
-    return pl.pallas_call(
-        functools.partial(_gmm_kernel, transpose_w=transpose_w),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(R // block,),
-            in_specs=[
-                pl.BlockSpec((block, K), lambda b, be: (b, 0)),
-                pl.BlockSpec((1,) + w.shape[1:], lambda b, be: (be[b], 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((block, N), lambda b, be: (b, 0)),
-            scratch_shapes=[pltpu.VMEM(w.shape[1:], x.dtype)],
-        ),
-        out_shape=jax.ShapeDtypeStruct((R, N), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=interpret,
-        name=scopes.KERNEL_GROUPED_MATMUL_DX if transpose_w
-        else scopes.KERNEL_GROUPED_MATMUL,
-    )(block_expert, x, w)
+    name = (scopes.KERNEL_GROUPED_MATMUL_DX if transpose_w
+            else scopes.KERNEL_GROUPED_MATMUL)
+    with scopes.kernel_trace(name):
+        return pl.pallas_call(
+            functools.partial(_gmm_kernel, transpose_w=transpose_w),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(R // block,),
+                in_specs=[
+                    pl.BlockSpec((block, K), lambda b, be: (b, 0)),
+                    pl.BlockSpec((1,) + w.shape[1:],
+                                 lambda b, be: (be[b], 0, 0)),
+                ],
+                out_specs=pl.BlockSpec((block, N), lambda b, be: (b, 0)),
+                scratch_shapes=[pltpu.VMEM(w.shape[1:], x.dtype)],
+            ),
+            out_shape=jax.ShapeDtypeStruct((R, N), jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=_VMEM_LIMIT),
+            interpret=interpret,
+            name=name,
+        )(block_expert, x, w)
 
 
 def _tgmm_kernel(be_ref, x_ref, dy_ref, dw_ref):
@@ -217,23 +220,24 @@ def _tgmm_pallas(x, dy, block_expert, experts: int, block: int,
 
     R, K = x.shape
     N = dy.shape[1]
-    return pl.pallas_call(
-        _tgmm_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(R // block,),
-            in_specs=[
-                pl.BlockSpec((block, K), lambda b, be: (b, 0)),
-                pl.BlockSpec((block, N), lambda b, be: (b, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, K, N), lambda b, be: (be[b], 0, 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((experts, K, N), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=interpret,
-        name=scopes.KERNEL_GROUPED_MATMUL_DW,
-    )(block_expert, x, dy)
+    with scopes.kernel_trace(scopes.KERNEL_GROUPED_MATMUL_DW):
+        return pl.pallas_call(
+            _tgmm_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(R // block,),
+                in_specs=[
+                    pl.BlockSpec((block, K), lambda b, be: (b, 0)),
+                    pl.BlockSpec((block, N), lambda b, be: (b, 0)),
+                ],
+                out_specs=pl.BlockSpec((1, K, N), lambda b, be: (be[b], 0, 0)),
+            ),
+            out_shape=jax.ShapeDtypeStruct((experts, K, N), jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=_VMEM_LIMIT),
+            interpret=interpret,
+            name=scopes.KERNEL_GROUPED_MATMUL_DW,
+        )(block_expert, x, dy)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))

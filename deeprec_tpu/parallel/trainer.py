@@ -157,7 +157,7 @@ class ShardedTrainer(Trainer):
 
     # ------------------------------------------------------------------ init
 
-    def init(self, seed: int = 0) -> TrainState:
+    def _init_state(self, seed: int) -> TrainState:
         from deeprec_tpu.parallel.mesh import put_global, put_tiled_global
 
         key = jax.random.PRNGKey(seed)

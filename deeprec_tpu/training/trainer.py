@@ -35,6 +35,7 @@ from deeprec_tpu import features as fcol
 from deeprec_tpu.embedding import combiners
 from deeprec_tpu.embedding.table import EmbeddingTable, TableState
 from deeprec_tpu.features import SparseFeature
+from deeprec_tpu.obs import compile_log
 from deeprec_tpu.optim.apply import apply_gradients, ensure_slots
 from deeprec_tpu.optim.sparse import SparseOptimizer
 from deeprec_tpu.training import metrics as M
@@ -195,6 +196,7 @@ _jit_auc_update = jax.jit(M.auc_update)
 
 
 class Trainer:
+    @scopes.host_spanned(scopes.TRAINER_BUILD)
     def __init__(
         self,
         model,
@@ -208,6 +210,7 @@ class Trainer:
         pipeline_chunks: int = 4,
         sentinel=None,
     ):
+        compile_log.install()  # set-up's recorder: once a process
         self.model = model
         self.sparse_opt = sparse_opt
         self.dense_opt = dense_opt or optax.adam(1e-3)
@@ -295,7 +298,13 @@ class Trainer:
 
     # ------------------------------------------------------------------ init
 
+    @scopes.host_spanned(scopes.INIT_STATE)
     def init(self, seed: int = 0) -> TrainState:
+        """Tables (empty) and dense weights made. A mesh changes
+        `_init_state`, not this."""
+        return self._init_state(seed)
+
+    def _init_state(self, seed: int) -> TrainState:
         key = jax.random.PRNGKey(seed)
         dense = self.model.init(key)
         tables = {}

@@ -13,6 +13,8 @@ import os
 
 import jax
 
+from deeprec_tpu.obs import compile_log
+
 # <repo>/.jax_cache, resolved from this file so that every entry point of
 # one checkout agrees on it. The directory is part of the cache key's
 # lookup, so it must not move between runs: never a tempdir, a pid or a
@@ -38,8 +40,10 @@ def enable_compile_cache() -> str:
 
     `JAX_COMPILATION_CACHE_DIR`, when set, is the caller's placement and is
     left entirely to jax (which reads the variable itself); otherwise the
-    cache goes to the fixed `<repo>/.jax_cache`.
+    cache goes to the fixed `<repo>/.jax_cache`. Installs the recorder of
+    set-up (obs/compile_log.py) and tells it what the directory holds.
     """
+    compile_log.install()
     # A Pallas kernel is serialized into its program with the Python call
     # stack of the trace as location info, and so into the cache key: the
     # same train step traced from two callers (or after an edit that moves
@@ -57,7 +61,9 @@ def enable_compile_cache() -> str:
     jax.config.update("jax_traceback_in_locations_limit", 1)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
+        compile_log.note_cache_dir(placed, -1)
         return placed
     jax.config.update("jax_compilation_cache_dir", _CACHE_DIR)
     jax.config.update("jax_compilation_cache_max_size", _CACHE_MAX_BYTES)
+    compile_log.note_cache_dir(_CACHE_DIR, _CACHE_MAX_BYTES)
     return _CACHE_DIR

@@ -45,6 +45,15 @@ Host spans are `jax.profiler.TraceAnnotation`s: they land in the same
 profiler running entering one is a flag test. `deeprec.train_step` is a
 `StepTraceAnnotation`, whose `step_num` the profiler's step views key on.
 
+Three spans are set-up's (`SETUP_SPANS`), and besides being annotations they
+tell the one recorder of set-up (obs/compile_log.py) what they took:
+`deeprec.trainer_build` (`Trainer.__init__`), `deeprec.init_state`
+(`Trainer.init`: tables and dense weights made; under a caller's `jit` it is
+the trace of them) and `deeprec.kernel_trace`, the bind of a Pallas call,
+which is its body's trace to a jaxpr. Every `pl.pallas_call` of the package
+stands under `kernel_trace(<the kernel's name>)`: it runs when jax traces
+the call and never when a compiled program runs.
+
 Names hold no `/`, `(`, `)` or space. Nothing here is switched by an
 environment variable or an option. The serving path's wall-clock JSONL
 spans (obs/trace.py) are another mechanism for another job (one request
@@ -53,8 +62,11 @@ across processes) and are not routed through here.
 from __future__ import annotations
 
 import functools
+import time
 
 import jax
+
+from deeprec_tpu.obs import compile_log
 
 # ------------------------------------------------------------ device scopes
 
@@ -183,6 +195,11 @@ CKPT_SAVE = "deeprec.ckpt_save"
 CKPT_RESTORE = "deeprec.ckpt_restore"
 HOST_SPANS = (STAGE_BATCH, UPDATE_BUDGETS, MAINTAIN, EVICT_TABLES, CKPT_SAVE,
               CKPT_RESTORE)
+# Set-up's spans: each also books its seconds with obs/compile_log.py.
+TRAINER_BUILD = "deeprec.trainer_build"
+INIT_STATE = "deeprec.init_state"
+KERNEL_TRACE = "deeprec.kernel_trace"
+SETUP_SPANS = (TRAINER_BUILD, INIT_STATE, KERNEL_TRACE)
 
 
 def scope(name: str):
@@ -212,8 +229,48 @@ def scoped(name: str):
 
 
 def host_spanned(name: str):
-    """Decorator form of `host_span`: the whole call is one host span."""
+    """Decorator form of `host_span`: the whole call is one host span. One
+    of `SETUP_SPANS` (`deeprec.<stage>`) also books
+    `deeprec_setup_seconds_total{stage}`."""
+    if name in SETUP_SPANS:
+        stage = name.split(".", 1)[1]
+        return _around(
+            lambda span: _Booked(span, compile_log.SETUP, stage), name)
     return _around(host_span, name)
+
+
+class _Booked:
+    """A host span that also tells the recorder of set-up what it took:
+    where it lies on `time.time()` (the clock jax's own spans nest on) and
+    its seconds on `perf_counter`."""
+
+    __slots__ = ("_span", "_stage", "_name", "_wall", "_t0")
+
+    def __init__(self, span: str, stage: str, name: str):
+        self._span = host_span(span)
+        self._stage, self._name = stage, name
+
+    def __enter__(self):
+        self._span.__enter__()
+        if self._stage == compile_log.KERNEL_TRACE:
+            compile_log.kernel_bind_begins()
+        self._wall, self._t0 = time.time(), time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        seconds = time.perf_counter() - self._t0
+        compile_log.record(self._stage, self._name, self._wall, time.time(),
+                           seconds)
+        return self._span.__exit__(*exc)
+
+
+def kernel_trace(kernel: str):
+    """Round a `pl.pallas_call(...)(...)`: the bind of the call is the trace
+    of the kernel's body, which jax makes again for every call of every
+    program on every run, whatever the compile cache holds. `kernel` is the
+    call's `name=`. The traces jax makes inside (the call's own `wrapped`,
+    the body's jitted `jnp` functions) are booked here and not as traces."""
+    return _Booked(KERNEL_TRACE, compile_log.KERNEL_TRACE, kernel)
 
 
 def step_span(n: int):
@@ -229,7 +286,8 @@ def vocabulary() -> dict:
         "phases": list(PHASES), "stages": list(STAGES), "rows": list(ROWS),
         "exchange": list(EXCHANGE),
         "kernels": list(KERNELS + DENSE_KERNELS),
-        "step_span": TRAIN_STEP, "host_spans": list(HOST_SPANS),
+        "step_span": TRAIN_STEP,
+        "host_spans": list(HOST_SPANS + SETUP_SPANS),
         "groups": {
             "block": {"pick": "innermost", "names": list(BLOCKS)},
             "block_part": {"pick": "innermost", "names": list(BLOCK_PARTS)},

@@ -344,6 +344,10 @@ def test_restart_resets_counters_but_trace_file_survives(tmp_path):
     assert [o["before"] for o in outs] == [0.0, 0.0]  # counters reset
     assert [o["after"] for o in outs] == [5.0, 5.0]
     evs = [json.loads(ln) for ln in open(trace_path)]
+    # (beside each generation's own span the file holds what the package's
+    # recorder of set-up wrote there: the import, obs/compile_log.py)
+    assert {e["name"] for e in evs} == {"work", "setup import"}
+    evs = [e for e in evs if e["name"] == "work"]
     assert len(evs) == 2                              # file accumulated
     assert {e["pid"] for e in evs} == {o["pid"] for o in outs}
 

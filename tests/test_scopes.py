@@ -101,8 +101,9 @@ def test_the_benchmark_holds_the_same_vocabulary():
     # the first file alone is the engine's and the trainers' part of it
     with open(os.path.join(ROOT, "benchmark", "phases.json")) as f:
         first = json.load(f)
-    for key in ("phases", "stages", "rows", "exchange", "host_spans"):
+    for key in ("phases", "stages", "rows", "exchange"):
         assert first[key] == VOCAB[key], key
+    assert first["host_spans"] == list(scopes.HOST_SPANS)
     assert first["kernels"] == list(scopes.KERNELS)
     # what each per-layer metric reads by scope is a name of the vocabulary,
     # and a metric of the manifest (BENCHMARK.json) with a reader of its own
@@ -398,7 +399,7 @@ def host_events(trace_dir):
     host planes of the trace under `trace_dir`."""
     path, = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
                                    "*.xplane.pb"))
-    wanted = set(scopes.HOST_SPANS) | {scopes.TRAIN_STEP}
+    wanted = set(scopes.HOST_SPANS + scopes.SETUP_SPANS) | {scopes.TRAIN_STEP}
     out = {}
     for plane in jax.profiler.ProfileData.from_file(path).planes:
         if not plane.name.startswith("/host:"):
@@ -487,6 +488,29 @@ def test_maintenance_and_checkpoint_calls_leave_their_spans(tmp_path):
     assert count == {scopes.STAGE_BATCH: 0, scopes.UPDATE_BUDGETS: 1,
                      scopes.MAINTAIN: 1, scopes.EVICT_TABLES: 1,
                      scopes.CKPT_SAVE: 2, scopes.CKPT_RESTORE: 1}
+
+
+def test_building_a_trainer_and_its_state_leaves_the_setup_spans(tmp_path):
+    """Set-up's three spans: one `Trainer(...)`, one `.init()`, and a
+    `deeprec.kernel_trace` for every Pallas call jax binds while it traces
+    (off a TPU the trainer's row reads are XLA's and bind none; the row
+    kernel asked for by hand, interpreted, binds one)."""
+    from deeprec_tpu.ops import fused_lookup
+
+    values = jnp.ones((32, 128), jnp.float32)
+
+    def body():
+        tr = Trainer(model(), Adagrad(lr=0.1), optax.adam(1e-3),
+                     unique_budget=48)
+        jax.block_until_ready(tr.init(0))
+        fused_lookup.gather_rows(
+            values, jnp.asarray([1, 5, 2, 9, 30, 7, 0, 3], jnp.int32),
+            block=8, interpret=True).block_until_ready()
+
+    events = traced(tmp_path, body)
+    assert {name: len(events.get(name, ())) for name in scopes.SETUP_SPANS} \
+        == {scopes.TRAINER_BUILD: 1, scopes.INIT_STATE: 1,
+            scopes.KERNEL_TRACE: 1}
 
 
 def test_host_spans_allocate_nothing_when_no_profiler_runs():
