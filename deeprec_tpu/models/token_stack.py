@@ -250,8 +250,9 @@ class TokenStackLM:
         x = x.astype(jnp.float32)
         counters = []
         for i, p in enumerate(params["layers"]):
-            # a layer's backward makes its forward again, but for the few
-            # small arrays that are dear to make and cheap to keep
+            # a layer's backward makes its forward again, but for what is
+            # dear to make and cheap to keep: the routes, the delta rule's
+            # states, the attention's output and log-sum-exp
             layer = jax.checkpoint(
                 functools.partial(self._layer, i),
                 policy=jax.checkpoint_policies.save_only_these_names(

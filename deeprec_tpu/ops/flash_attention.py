@@ -43,6 +43,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from deeprec_tpu.utils import backend, scopes
 
@@ -285,8 +286,8 @@ def _pallas_forward(q, k, v, mask, causal, sm_scale, block_q, block_k,
 
 
 def _blockwise_forward(q, k, v, mask, causal, sm_scale, block_k, window):
-    """Same math as the kernel, in scanned jnp — used on non-TPU backends and
-    as the recompute inside the backward."""
+    """Same math as the kernel, in scanned jnp — the forward on non-TPU
+    backends."""
     B, H, Lq, D = q.shape
     S = k.shape[2]
     k, v = _repeat_kv(k, H), _repeat_kv(v, H)
@@ -605,6 +606,10 @@ def _fa_fwd(q, k, v, mask, causal, sm_scale, block_q, block_k, interpret,
             window):
     o, lse = _fa_impl(q, k, v, mask, causal, sm_scale, block_q, block_k,
                       interpret, window)
+    # a remat whose policy keeps the name (a token stack's layer) saves both,
+    # and its backward does not run the forward again; any other policy
+    # makes them again, as before (the name is then the identity)
+    o, lse = (checkpoint_name(a, scopes.KEPT_ATTN_OUT) for a in (o, lse))
     return o, (q, k, v, mask, o, lse)
 
 

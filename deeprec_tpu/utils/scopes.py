@@ -128,10 +128,16 @@ DENSE_RULES = (ROUTER_BIAS_UPDATE,)
 # What a layer's remat keeps instead of making again (`checkpoint_name`s; no
 # trace shows them): the experts each token chose and the rows they were
 # dispatched to (a few int32 arrays a layer), the delta rule's states at its
-# segments' starts (8 MB a layer at 32 heads of 128 x 128 and four segments).
+# segments' starts (8 MB a layer at 32 heads of 128 x 128 and four segments),
+# and the flash forward's output and log-sum-exp, so that the backward never
+# runs the attention forward again (`[B, H, T, Dv]` in the operands' dtype +
+# `[B, H, T]` f32 a layer: 117.4 + 1.8 MB at 28 heads of 128 and T 16,384,
+# 33.6 + 0.5 MB at 16 of 128 and T 8,192, 67.1 + 0.5 MB at 16 of 256; q, k
+# and v are NOT kept: their projections are made again, docs/attention.md).
 KEPT_MOE_ROUTE = "kept_moe_route"
 KEPT_GDN_STATES = "kept_gdn_states"
-REMAT_KEPT = (KEPT_MOE_ROUTE, KEPT_GDN_STATES)
+KEPT_ATTN_OUT = "kept_attn_out"
+REMAT_KEPT = (KEPT_MOE_ROUTE, KEPT_GDN_STATES, KEPT_ATTN_OUT)
 
 # The sharded exchange's scopes, nested under the phase that issues them.
 # They keep the names they had (`phase_` + what parallel/sharded.py called

@@ -21,6 +21,8 @@ from deeprec_tpu.models import WindowStackLM
 from deeprec_tpu.ops.flash_attention import (_visible_keys, _visible_queries,
                                              _walk, attention_reference,
                                              flash_attention)
+from deeprec_tpu.training.trainer import ModelInputs
+from deeprec_tpu.utils import scopes
 
 CONFIG = {
     "name": "tiny-window", "builder": "smallthinker",
@@ -198,6 +200,44 @@ def test_the_shares_of_four_chips_add_up_to_the_uncut_layer():
     want = reference.layer(dict(lp, moe=dict(lp["moe"], experts=whole)),
                            x[0], config, "highest", True, True)
     close(uncut[0], want, 2e-5)
+
+
+# --------------------------------------------------- what the remat keeps
+
+
+def loss_and_grad(m, p, x, labels):
+    """(the gradient's jaxpr, printed; the loss; its gradients in the
+    weights and the rows) of the model's own loss, the stack under its
+    layer remat."""
+    def f(p, x):
+        inputs = ModelInputs(pooled={}, seq={"tok": (x, None)}, dense={})
+        return m.loss(p, inputs, {"label": labels})[0]
+
+    grad = jax.value_and_grad(f, argnums=(0, 1))
+    return str(jax.make_jaxpr(grad)(p, x)), *jax.jit(grad)(p, x)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_layer_remat_runs_the_attention_forward_once(monkeypatch, dtype):
+    """The remat keeps the flash forward's output and log-sum-exp by name,
+    so the gradient's program holds ONE forward kernel a layer, where the
+    parent's (a remat that keeps nothing of attention) holds two; loss and
+    gradients are the parent's. Interpreted kernels."""
+    m = model(flash_block=8, interpret=True, compute_dtype=dtype)
+    p, layers = params(), CONFIG["num_hidden_layers"]
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 32))
+    labels = jax.random.randint(jax.random.PRNGKey(2), (2, 32), 0, 48)
+    text, loss, grads = loss_and_grad(m, p, x, labels)
+    monkeypatch.setattr(scopes, "REMAT_KEPT", tuple(
+        n for n in scopes.REMAT_KEPT if n != scopes.KEPT_ATTN_OUT))
+    parent, loss0, grads0 = loss_and_grad(m, p, x, labels)
+    assert text.count("name=flash_attention_fwd") == layers
+    assert parent.count("name=flash_attention_fwd") == 2 * layers
+    for kernel in ("flash_attention_bwd_dkdv", "flash_attention_bwd_dq"):
+        assert text.count(f"name={kernel}") == parent.count(
+            f"name={kernel}") == layers
+    assert float(loss) == float(loss0)
+    jax.tree.map(np.testing.assert_array_equal, grads, grads0)
 
 
 # ---------------------------------------------------------- through Trainer
