@@ -22,8 +22,10 @@ gradient, moves, and adds shared experts computed in full
 cut off from the gradient; after every step `after_update` (the trainer's
 hook for a leaf a rule owns) moves it by `b_j += gamma sign(mean(c) - c_j)`
 where `c_j` is how many of the step's choices fell on output `j`, held here
-or not. `rms` is the plain RMS norm (weight at 1). The stack, its remat by
-layer, the loss and the expert layers' counters are models/token_stack.py's.
+or not: the router and the rule are models/token_stack.py's
+`BiasRoutedStackLM`, which models/mamba_stack.py shares. `rms` is the plain
+RMS norm (weight at 1). The stack, its remat by layer, the loss and the
+expert layers' counters are models/token_stack.py's.
 
 The flash kernels take the 192-wide queries and keys whole and the 128-wide
 values beside them (ops/flash_attention.py; docs/attention.md says why not
@@ -43,12 +45,12 @@ from typing import Dict
 import jax.numpy as jnp
 
 from deeprec_tpu import nn
-from deeprec_tpu.models.token_stack import TokenStackLM
+from deeprec_tpu.models.token_stack import BiasRoutedStackLM
 from deeprec_tpu.utils import scopes
 
 
 @dataclasses.dataclass(kw_only=True)
-class LatentStackLM(TokenStackLM):
+class LatentStackLM(BiasRoutedStackLM):
     attn_heads: int
     qk_nope_head_dim: int
     qk_rope_head_dim: int
@@ -56,8 +58,6 @@ class LatentStackLM(TokenStackLM):
     kv_lora_rank: int
     rope_theta: float
     shared_expert_width: int         # all the shared experts, fused
-    routed_scaling_factor: float
-    bias_update_rate: float          # gamma of the selection bias' rule
 
     def _init_mixer(self, ks, i: int) -> Dict:
         d, H, normal = self.hidden, self.attn_heads, self._normal
@@ -110,12 +110,7 @@ class LatentStackLM(TokenStackLM):
         B, T, d = m.shape
         xt = m.reshape(B * T, d)
         with scopes.scope(scopes.BLOCK_MOE):
-            w, e = self.route(p["router"], xt, scoring="sigmoid",
-                              bias=p["bias"], scale=self.routed_scaling_factor)
-            with scopes.scope(scopes.MOE_DISPATCH):
-                load = jnp.sum(
-                    e.reshape(-1, 1) == jnp.arange(self.num_experts),
-                    axis=0, dtype=jnp.int32)
+            w, e, load = self.biased_route(p, xt)
             y, counters = self.held(p["experts"], xt, w, e)
             with scopes.scope(scopes.MOE_SHARED):
                 s = p["shared"]
@@ -132,32 +127,3 @@ class LatentStackLM(TokenStackLM):
             return h + self.mlp_block(p["mlp"], m), {}
         y, counters = self.expert_block(p["moe"], m)
         return h + y, counters
-
-    def _total(self, counters) -> Dict:
-        """`load` [expert layers, router outputs] stays layer by layer (the
-        rule moves each layer's bias by its own loads); `all_max_load` is
-        the fullest output's count, summed over the layers."""
-        load = jnp.stack([c["load"] for c in counters])
-        total = super()._total([{k: v for k, v in c.items() if k != "load"}
-                                for c in counters])
-        return {**total, "load": load,
-                "all_max_load": jnp.sum(jnp.max(load, axis=-1))}
-
-    def after_update(self, dense: Dict, metrics: Dict) -> Dict:
-        """The rule that owns the routers' selection bias, run by the
-        trainer once a step after the dense optimizer's update (whose
-        update of a leaf without a gradient is exactly 0):
-        `b_j += gamma sign(mean(c) - c_j)`, `c = metrics["moe_load"]`
-        [expert layers, router outputs]. Only the sign of a load's distance
-        from the mean is read, so the micro-batches' sum and the replicas'
-        mean give what one batch on one device gives."""
-        with scopes.scope(scopes.ROUTER_BIAS_UPDATE):
-            load = metrics["moe_load"].astype(jnp.float32)
-            move = self.bias_update_rate * jnp.sign(
-                jnp.mean(load, axis=-1, keepdims=True) - load)
-            layers = list(dense["layers"])
-            for j, i in enumerate(range(self.dense_layers, self.layers)):
-                moe = layers[i]["moe"]
-                layers[i] = {**layers[i],
-                             "moe": {**moe, "bias": moe["bias"] + move[j]}}
-            return {**dense, "layers": layers}

@@ -165,6 +165,11 @@ class TokenStackLM:
     def is_dense(self, i: int) -> bool:
         return i < self.dense_layers
 
+    def has_experts(self, i: int) -> bool:
+        """Whether layer i holds an expert block (whose counters the step
+        sums): every layer past the leading dense ones, here."""
+        return not self.is_dense(i)
+
     def _init_swiglu(self, ks, width: int) -> Dict:
         """A gated feed-forward `width` wide from three keys."""
         d = self.hidden
@@ -258,7 +263,7 @@ class TokenStackLM:
                 policy=jax.checkpoint_policies.save_only_these_names(
                     *scopes.REMAT_KEPT))
             x, c = layer(p, x)
-            if not self.is_dense(i):
+            if self.has_experts(i):
                 counters.append(c)
         return x, self._total(counters)
 
@@ -284,3 +289,63 @@ class TokenStackLM:
             mets = {"accuracy": hits.astype(jnp.float32) / n,
                     **{f"moe_{k}": v for k, v in counters.items()}}
             return nll / n, mets
+
+
+@dataclasses.dataclass(kw_only=True)
+class BiasRoutedStackLM(TokenStackLM):
+    """A token stack whose expert layers route by a sigmoid with a
+    selection bias that a rule, and no gradient, moves (the latent and the
+    Mamba stacks):
+
+        s = sigmoid(n Wr);  chosen = top-k of (s + b)
+        w = s[chosen] / (sum of the chosen s + 1e-20) * scale
+
+    `b` (a leaf `bias` of every expert block, [router outputs], starting at
+    0) is cut off from the gradient; after every step `after_update` (the
+    trainer's hook for a leaf a rule owns) moves it by
+    `b_j += gamma sign(mean(c) - c_j)`, where `c_j` is how many of the
+    step's choices fell on output `j`, held here or not."""
+    routed_scaling_factor: float
+    bias_update_rate: float          # gamma of the selection bias' rule
+
+    def biased_route(self, p: Dict, xt):
+        """(weights, experts) [T, top_k] of the tokens xt [T, d], and
+        `load` [router outputs]: the choices that fell on each of ALL the
+        router's outputs."""
+        w, e = self.route(p["router"], xt, scoring="sigmoid", bias=p["bias"],
+                          scale=self.routed_scaling_factor)
+        with scopes.scope(scopes.MOE_DISPATCH):
+            load = jnp.sum(e.reshape(-1, 1) == jnp.arange(self.num_experts),
+                           axis=0, dtype=jnp.int32)
+        return w, e, load
+
+    def _total(self, counters) -> Dict:
+        """`load` [expert layers, router outputs] stays layer by layer (the
+        rule moves each layer's bias by its own loads); `all_max_load` is
+        the fullest output's count, summed over the layers."""
+        load = jnp.stack([c["load"] for c in counters])
+        total = super()._total([{k: v for k, v in c.items() if k != "load"}
+                                for c in counters])
+        return {**total, "load": load,
+                "all_max_load": jnp.sum(jnp.max(load, axis=-1))}
+
+    def after_update(self, dense: Dict, metrics: Dict) -> Dict:
+        """The rule that owns the routers' selection bias, run by the
+        trainer once a step after the dense optimizer's update (whose
+        update of a leaf without a gradient is exactly 0):
+        `b_j += gamma sign(mean(c) - c_j)`, `c = metrics["moe_load"]`
+        [expert layers, router outputs]. Only the sign of a load's distance
+        from the mean is read, so the micro-batches' sum and the replicas'
+        mean give what one batch on one device gives."""
+        with scopes.scope(scopes.ROUTER_BIAS_UPDATE):
+            load = metrics["moe_load"].astype(jnp.float32)
+            move = self.bias_update_rate * jnp.sign(
+                jnp.mean(load, axis=-1, keepdims=True) - load)
+            layers = list(dense["layers"])
+            expert_layers = [i for i in range(self.layers)
+                             if self.has_experts(i)]
+            for j, i in enumerate(expert_layers):
+                moe = layers[i]["moe"]
+                layers[i] = {**layers[i],
+                             "moe": {**moe, "bias": moe["bias"] + move[j]}}
+            return {**dense, "layers": layers}

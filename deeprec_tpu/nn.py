@@ -356,11 +356,12 @@ def swiglu_apply(x, w_gate, w_up, w_down, compute_dtype=jnp.bfloat16):
     return matmul(h, w_down.astype(compute_dtype))
 
 
-def causal_conv1d(x, w):
-    """Depthwise causal convolution over time without bias: x [B, L, C]
-    (any float dtype; the sum is f32), w [K, C];
-    `y_t = sum_j w[j] x[t - (K - 1) + j]` (left padding K - 1, the
+def causal_conv1d(x, w, b=None):
+    """Depthwise causal convolution over time: x [B, L, C] (any float
+    dtype; the sum is f32), w [K, C], an optional bias b [C];
+    `y_t = sum_j w[j] x[t - (K - 1) + j] (+ b)` (left padding K - 1, the
     cross-correlation a `[C, 1, K]` conv1d weight computes)."""
     K, L = w.shape[0], x.shape[1]
     xp = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
-    return sum(xp[:, j:j + L].astype(jnp.float32) * w[j] for j in range(K))
+    y = sum(xp[:, j:j + L].astype(jnp.float32) * w[j] for j in range(K))
+    return y if b is None else y + b

@@ -285,12 +285,14 @@ def held_experts_apply(p, x, weights, experts, *, held: Tuple[int, int],
                        compute_dtype=jnp.bfloat16, interpret: bool = False,
                        activation=jax.nn.silu, count_live: bool = False):
     """The held experts' part of the expert layer,
-    `w (activation(x Wg) * (x Wu)) Wd` a pair. p: `wg`, `wu` [held, d, f],
-    `wd` [held, f, d]; x [T, d]; weights, experts [T, K] from `route_topk`.
-    Returns (y [T, d] f32, {"pairs", "overflow", "max_load"}), and under
-    `count_live` (for an activation that is 0 below 0) "hidden_live" too:
-    the hidden units of the rows pairs stand in that the gate's activation
-    leaves above 0 (an unused row is zero and so is its gate)."""
+    `w (activation(x Wg) * (x Wu)) Wd` a pair, or, where `p` has no `wg`
+    (an expert without a gate), `w activation(x Wu) Wd`. p: `wg`, `wu`
+    [held, d, f], `wd` [held, f, d]; x [T, d]; weights, experts [T, K] from
+    `route_topk`. Returns (y [T, d] f32, {"pairs", "overflow",
+    "max_load"}), and under `count_live` (for an activation that is 0 below
+    0) "hidden_live" too: the hidden units of the rows pairs stand in that
+    the gate's activation leaves above 0 (an unused row is zero and so is
+    its gate)."""
     with scopes.scope(scopes.MOE_DISPATCH):
         d = Dispatch(*(checkpoint_name(a, scopes.KEPT_MOE_ROUTE) for a in
                        dispatch_held(experts, held, pair_budget, block)))
@@ -302,8 +304,12 @@ def held_experts_apply(p, x, weights, experts, *, held: Tuple[int, int],
                                block=block, interpret=interpret)
         # a pair's weight scales its hidden row, not its [d]-wide output:
         # the same product, a quarter of the elements
-        gate = activation(mm(xs, p["wg"]))
-        h = gate * mm(xs, p["wu"]) * row_w[:, None]
+        if "wg" in p:
+            gate = activation(mm(xs, p["wg"]))
+            h = gate * mm(xs, p["wu"]) * row_w[:, None]
+        else:
+            gate = activation(mm(xs, p["wu"]))
+            h = gate * row_w[:, None]
         ys = mm(h.astype(compute_dtype), p["wd"])
         counters = {"pairs": d.pairs, "overflow": d.overflow,
                     "max_load": d.max_load}

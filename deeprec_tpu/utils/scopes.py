@@ -32,7 +32,11 @@ call inside `block_attn`, which of the two a layer is (`attn_window`,
 names its flash call `attn_latent` there, its shared experts `moe_shared`
 inside `block_moe`, a leading layer's dense feed-forward `block_mlp`, and,
 inside `phase_dense_apply`, the rule that moves its routers' selection bias
-`router_bias_update` (a group of its own).
+`router_bias_update` (a group of its own). A Mamba stack
+(models/mamba_stack.py) names its Mamba-2 mixers `block_mamba`, and inside
+them the scan `ssd_scan` and the convolution `mamba_conv`; inside
+`block_moe` the projections into and out of the routed experts' latent
+space `moe_latent` (all three of the group `block_part`).
 
 jax wraps a scope's name in the transforms it is traced under
 (`vmap(engine_probe)`, `transpose(jvp(phase_dense_fwd_bwd))`); a reader
@@ -108,12 +112,19 @@ BLOCK_ATTN = "block_attn"
 BLOCK_MOE = "block_moe"
 BLOCK_HEAD_LOSS = "block_head_loss"
 BLOCK_MLP = "block_mlp"          # a leading layer's dense feed-forward
-BLOCKS = (BLOCK_GDN, BLOCK_ATTN, BLOCK_MOE, BLOCK_HEAD_LOSS, BLOCK_MLP)
+BLOCK_MAMBA = "block_mamba"      # a Mamba-2 mixer (models/mamba_stack.py)
+BLOCKS = (BLOCK_GDN, BLOCK_ATTN, BLOCK_MOE, BLOCK_HEAD_LOSS, BLOCK_MLP,
+          BLOCK_MAMBA)
 GDN_RULE = "gdn_rule"
 MOE_DISPATCH = "moe_dispatch"
 MOE_EXPERTS = "moe_experts"
 MOE_SHARED = "moe_shared"        # the shared experts, computed in full
-BLOCK_PARTS = (GDN_RULE, MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED)
+SSD_SCAN = "ssd_scan"            # the Mamba-2 scan (ops/ssd.py), tight
+MAMBA_CONV = "mamba_conv"        # the Mamba-2 mixer's causal convolution
+MOE_LATENT = "moe_latent"        # the projections into and out of the
+#                                  routed experts' latent space
+BLOCK_PARTS = (GDN_RULE, MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED, SSD_SCAN,
+               MAMBA_CONV, MOE_LATENT)
 # Tight round the flash call: which of a window stack's two kinds a layer
 # is, or a latent stack's call (keys wider than values).
 ATTN_WINDOW = "attn_window"

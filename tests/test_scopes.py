@@ -198,10 +198,12 @@ def test_the_token_stack_names_its_blocks_and_parts():
     names = op_names(tr._train_step.lower(state, batch, None).compile())
     assert_every_instruction_has_a_phase(names)
     got = [phase_reduce.scope_of(n, VOCAB) for _, n in names]
-    blocks = set(scopes.BLOCKS) - {scopes.BLOCK_MLP}   # no dense layer
+    # no dense layer, no Mamba-2 mixer
+    blocks = set(scopes.BLOCKS) - {scopes.BLOCK_MLP, scopes.BLOCK_MAMBA}
     assert {s.block for s in got} - {""} == blocks
     assert {s.block_part for s in got} - {""} == set(scopes.BLOCK_PARTS) - {
-        scopes.MOE_SHARED}
+        scopes.MOE_SHARED, scopes.SSD_SCAN, scopes.MAMBA_CONV,
+        scopes.MOE_LATENT}
     # a part stands inside its block
     inside = {"gdn_rule": "block_gdn", "moe_dispatch": "block_moe",
               "moe_experts": "block_moe"}
@@ -236,7 +238,7 @@ def test_the_window_stack_names_its_attention_parts():
     assert_every_instruction_has_a_phase(names)
     got = [phase_reduce.scope_of(n, VOCAB) for _, n in names]
     assert {s.block for s in got} - {""} == set(scopes.BLOCKS) - {
-        scopes.BLOCK_GDN, scopes.BLOCK_MLP}
+        scopes.BLOCK_GDN, scopes.BLOCK_MLP, scopes.BLOCK_MAMBA}
     assert {s.block_part for s in got} - {""} == {scopes.MOE_DISPATCH,
                                                   scopes.MOE_EXPERTS}
     parts = (scopes.ATTN_WINDOW, scopes.ATTN_GLOBAL)
@@ -284,7 +286,7 @@ def test_the_latent_stack_names_its_parts_and_its_rule():
     assert_every_instruction_has_a_phase(names)
     got = [phase_reduce.scope_of(n, VOCAB) for _, n in names]
     assert {s.block for s in got} - {""} == set(scopes.BLOCKS) - {
-        scopes.BLOCK_GDN}
+        scopes.BLOCK_GDN, scopes.BLOCK_MAMBA}
     assert {s.block_part for s in got} - {""} == {
         scopes.MOE_DISPATCH, scopes.MOE_EXPERTS, scopes.MOE_SHARED}
     assert {s.attn_part for s in got} - {""} == {scopes.ATTN_LATENT}
