@@ -11,5 +11,6 @@ from deeprec_tpu.models.hybrid_stack import HybridStackLM
 from deeprec_tpu.models.window_stack import WindowStackLM
 from deeprec_tpu.models.latent_stack import LatentStackLM
 from deeprec_tpu.models.mamba_stack import MambaStackLM
+from deeprec_tpu.models.conv_stack import ConvStackLM
 from deeprec_tpu.models.multitask import DBMTL, ESMM, MMoE, PLE, SimpleMultiTask
 from deeprec_tpu.models.registry import REGISTRY, build_model

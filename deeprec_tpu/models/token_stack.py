@@ -294,11 +294,11 @@ class TokenStackLM:
 @dataclasses.dataclass(kw_only=True)
 class BiasRoutedStackLM(TokenStackLM):
     """A token stack whose expert layers route by a sigmoid with a
-    selection bias that a rule, and no gradient, moves (the latent and the
-    Mamba stacks):
+    selection bias that a rule, and no gradient, moves (the latent, the
+    Mamba and the short-convolution stacks):
 
         s = sigmoid(n Wr);  chosen = top-k of (s + b)
-        w = s[chosen] / (sum of the chosen s + 1e-20) * scale
+        w = s[chosen] / (sum of the chosen s + renorm_eps) * scale
 
     `b` (a leaf `bias` of every expert block, [router outputs], starting at
     0) is cut off from the gradient; after every step `after_update` (the
@@ -307,13 +307,15 @@ class BiasRoutedStackLM(TokenStackLM):
     step's choices fell on output `j`, held here or not."""
     routed_scaling_factor: float
     bias_update_rate: float          # gamma of the selection bias' rule
+    renorm_eps: float = 1e-20        # added to the chosen scores' sum
 
     def biased_route(self, p: Dict, xt):
         """(weights, experts) [T, top_k] of the tokens xt [T, d], and
         `load` [router outputs]: the choices that fell on each of ALL the
         router's outputs."""
         w, e = self.route(p["router"], xt, scoring="sigmoid", bias=p["bias"],
-                          scale=self.routed_scaling_factor)
+                          scale=self.routed_scaling_factor,
+                          renorm_eps=self.renorm_eps)
         with scopes.scope(scopes.MOE_DISPATCH):
             load = jnp.sum(e.reshape(-1, 1) == jnp.arange(self.num_experts),
                            axis=0, dtype=jnp.int32)

@@ -61,7 +61,8 @@ _VMEM_LIMIT = 64 * 1024 * 1024
 
 
 def route_topk(x, w_router, top_k: int, renormalise: bool = True, *,
-               scoring: str = "softmax", bias=None, scale: float = 1.0):
+               scoring: str = "softmax", bias=None, scale: float = 1.0,
+               renorm_eps: float = 1e-20):
     """(weights [T, top_k] f32, experts [T, top_k] int32), router product
     and scores in f32. `scoring="softmax"`: a softmax over all the router's
     outputs, the `top_k` largest, renormalised over the chosen when asked.
@@ -69,7 +70,8 @@ def route_topk(x, w_router, top_k: int, renormalise: bool = True, *,
     `score + bias` are CHOSEN (`bias` [E], a selection bias that receives no
     gradient: it decides who is chosen and never what a chosen one
     weighs), each WEIGHS by its score without the bias, renormalised over
-    the chosen when asked, times `scale`."""
+    the chosen when asked (`w / (sum of the chosen + renorm_eps)`), times
+    `scale`."""
     logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
                      precision=HIGHEST)
     if scoring == "softmax":
@@ -83,7 +85,7 @@ def route_topk(x, w_router, top_k: int, renormalise: bool = True, *,
             scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
         w = jnp.take_along_axis(scores, e, axis=-1)
         if renormalise:
-            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + renorm_eps)
         w = w * scale
     else:
         raise ValueError(f"scoring is softmax or sigmoid; got {scoring!r}")

@@ -36,7 +36,11 @@ inside `phase_dense_apply`, the rule that moves its routers' selection bias
 (models/mamba_stack.py) names its Mamba-2 mixers `block_mamba`, and inside
 them the scan `ssd_scan` and the convolution `mamba_conv`; inside
 `block_moe` the projections into and out of the routed experts' latent
-space `moe_latent` (all three of the group `block_part`).
+space `moe_latent` (all three of the group `block_part`). A
+short-convolution stack (models/conv_stack.py) names its gated
+short-convolution mixers `block_shortconv` (the group `block`), and inside
+them the two gates and the taps between them `shortconv_gate` (the group
+`block_part`).
 
 jax wraps a scope's name in the transforms it is traced under
 (`vmap(engine_probe)`, `transpose(jvp(phase_dense_fwd_bwd))`); a reader
@@ -113,8 +117,11 @@ BLOCK_MOE = "block_moe"
 BLOCK_HEAD_LOSS = "block_head_loss"
 BLOCK_MLP = "block_mlp"          # a leading layer's dense feed-forward
 BLOCK_MAMBA = "block_mamba"      # a Mamba-2 mixer (models/mamba_stack.py)
+BLOCK_SHORTCONV = "block_shortconv"  # a gated short convolution, its two
+#                                      projections included
+#                                      (models/conv_stack.py)
 BLOCKS = (BLOCK_GDN, BLOCK_ATTN, BLOCK_MOE, BLOCK_HEAD_LOSS, BLOCK_MLP,
-          BLOCK_MAMBA)
+          BLOCK_MAMBA, BLOCK_SHORTCONV)
 GDN_RULE = "gdn_rule"
 MOE_DISPATCH = "moe_dispatch"
 MOE_EXPERTS = "moe_experts"
@@ -123,8 +130,10 @@ SSD_SCAN = "ssd_scan"            # the Mamba-2 scan (ops/ssd.py), tight
 MAMBA_CONV = "mamba_conv"        # the Mamba-2 mixer's causal convolution
 MOE_LATENT = "moe_latent"        # the projections into and out of the
 #                                  routed experts' latent space
+SHORTCONV_GATE = "shortconv_gate"  # the two gates and the causal taps
+#                                    between a short convolution's products
 BLOCK_PARTS = (GDN_RULE, MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED, SSD_SCAN,
-               MAMBA_CONV, MOE_LATENT)
+               MAMBA_CONV, MOE_LATENT, SHORTCONV_GATE)
 # Tight round the flash call: which of a window stack's two kinds a layer
 # is, or a latent stack's call (keys wider than values).
 ATTN_WINDOW = "attn_window"

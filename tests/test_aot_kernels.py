@@ -195,6 +195,36 @@ def test_flash_kernels_compile_at_seven_query_heads_a_group_and_a_window(
         == (32 if window is None else 9)
 
 
+def test_flash_kernels_compile_at_head_dim_64_over_eight_kv_heads(
+        one_chip, monkeypatch):
+    """The short-convolution cell's attention: 2 sequences of 8,192, 32
+    query heads over 8 key/value heads of 64 (half a lane tile, taken
+    whole), causal, bf16 operands, blocks of 512: forward and both backward
+    kernels, and no copy of k or v repeated to the query heads."""
+    from deeprec_tpu.ops.flash_attention import flash_attention
+    from deeprec_tpu.utils import scopes
+
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    sd = _sd(one_chip)
+
+    def step(q, k, v, mask):
+        o, vjp = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, mask, True, 64 ** -0.5, 512, 512), q, k, v)
+        return o, vjp(o)
+
+    hlo = jax.jit(step).lower(
+        sd((2, 32, 8192, 64), jnp.bfloat16),
+        sd((2, 8, 8192, 64), jnp.bfloat16),
+        sd((2, 8, 8192, 64), jnp.bfloat16), sd((2, 8192), jnp.bool_)
+    ).compile().as_text()
+    for name in (scopes.KERNEL_FLASH_FWD, scopes.KERNEL_FLASH_BWD_DKDV,
+                 scopes.KERNEL_FLASH_BWD_DQ):
+        assert name in hlo, name
+    assert "bf16[2,32,8192,64]" in hlo
+    assert not [line for line in hlo.splitlines()
+                if "bf16[2,32,8192,64]" in line and " broadcast(" in line]
+
+
 def test_flash_kernels_compile_at_keys_wider_than_values(one_chip,
                                                          monkeypatch):
     """The latent cell's shape: 16 heads, queries and keys 192 wide (one

@@ -198,12 +198,13 @@ def test_the_token_stack_names_its_blocks_and_parts():
     names = op_names(tr._train_step.lower(state, batch, None).compile())
     assert_every_instruction_has_a_phase(names)
     got = [phase_reduce.scope_of(n, VOCAB) for _, n in names]
-    # no dense layer, no Mamba-2 mixer
-    blocks = set(scopes.BLOCKS) - {scopes.BLOCK_MLP, scopes.BLOCK_MAMBA}
+    # no dense layer, no Mamba-2 mixer, no short convolution
+    blocks = set(scopes.BLOCKS) - {scopes.BLOCK_MLP, scopes.BLOCK_MAMBA,
+                                   scopes.BLOCK_SHORTCONV}
     assert {s.block for s in got} - {""} == blocks
     assert {s.block_part for s in got} - {""} == set(scopes.BLOCK_PARTS) - {
         scopes.MOE_SHARED, scopes.SSD_SCAN, scopes.MAMBA_CONV,
-        scopes.MOE_LATENT}
+        scopes.MOE_LATENT, scopes.SHORTCONV_GATE}
     # a part stands inside its block
     inside = {"gdn_rule": "block_gdn", "moe_dispatch": "block_moe",
               "moe_experts": "block_moe"}
@@ -238,7 +239,8 @@ def test_the_window_stack_names_its_attention_parts():
     assert_every_instruction_has_a_phase(names)
     got = [phase_reduce.scope_of(n, VOCAB) for _, n in names]
     assert {s.block for s in got} - {""} == set(scopes.BLOCKS) - {
-        scopes.BLOCK_GDN, scopes.BLOCK_MLP, scopes.BLOCK_MAMBA}
+        scopes.BLOCK_GDN, scopes.BLOCK_MLP, scopes.BLOCK_MAMBA,
+        scopes.BLOCK_SHORTCONV}
     assert {s.block_part for s in got} - {""} == {scopes.MOE_DISPATCH,
                                                   scopes.MOE_EXPERTS}
     parts = (scopes.ATTN_WINDOW, scopes.ATTN_GLOBAL)
@@ -286,7 +288,7 @@ def test_the_latent_stack_names_its_parts_and_its_rule():
     assert_every_instruction_has_a_phase(names)
     got = [phase_reduce.scope_of(n, VOCAB) for _, n in names]
     assert {s.block for s in got} - {""} == set(scopes.BLOCKS) - {
-        scopes.BLOCK_GDN, scopes.BLOCK_MAMBA}
+        scopes.BLOCK_GDN, scopes.BLOCK_MAMBA, scopes.BLOCK_SHORTCONV}
     assert {s.block_part for s in got} - {""} == {
         scopes.MOE_DISPATCH, scopes.MOE_EXPERTS, scopes.MOE_SHARED}
     assert {s.attn_part for s in got} - {""} == {scopes.ATTN_LATENT}
@@ -314,6 +316,55 @@ def test_the_latent_stack_names_its_parts_and_its_rule():
              if s.phase == scopes.PHASE_DENSE_FWD_BWD and op not in FREE]
     bare = [op for op, s in dense if not s.block]
     assert len(bare) < 0.15 * len(dense), (len(bare), len(dense))
+
+
+def test_the_conv_stack_names_its_mixers_and_gates():
+    """The short-convolution stack: every conv mixer stands under
+    `block_shortconv` and its gates and taps under `shortconv_gate` inside
+    it, forward and backward; attention under `block_attn` with no
+    attention part of its own, the leading layer's feed-forward under
+    `block_mlp`; and the names are the ones the benchmark's phase file
+    holds."""
+    from deeprec_tpu.models import ConvStackLM
+
+    m = ConvStackLM(
+        vocab=48, seq_len=32, capacity=128, pair_budget=128, hidden=32,
+        layers=3, layer_types=("conv", "full_attention", "conv"),
+        dense_layers=1, dense_width=48, conv_kernel=3, attn_heads=4,
+        attn_kv_heads=2, head_dim=8, rope_theta=1e6, num_experts=8,
+        experts_per_token=4, expert_width=16, routed_scaling_factor=1.0,
+        renorm_eps=1e-6, bias_update_rate=1e-3, held_experts=(2, 2),
+        flash_block=16, moe_block=8, loss_block=16)
+    tr = Trainer(m, Adagrad(lr=0.05), optax.adam(1e-3), unique_budget=40)
+    tok = jnp.arange(2 * 33, dtype=jnp.int32).reshape(2, 33) % 48
+    batch = {"tok": tok[:, :-1], "label": tok[:, 1:]}
+    names = op_names(tr._train_step.lower(tr.init(0), batch, None).compile())
+    assert_every_instruction_has_a_phase(names)
+    got = [phase_reduce.scope_of(n, VOCAB) for _, n in names]
+    assert {s.block for s in got} - {""} == set(scopes.BLOCKS) - {
+        scopes.BLOCK_GDN, scopes.BLOCK_MAMBA}
+    assert {s.block_part for s in got} - {""} == {
+        scopes.MOE_DISPATCH, scopes.MOE_EXPERTS, scopes.SHORTCONV_GATE}
+    assert {s.attn_part for s in got} - {""} == set()
+    assert all(s.block == scopes.BLOCK_SHORTCONV for s in got
+               if s.block_part == scopes.SHORTCONV_GATE)
+    for field, name in (("block", scopes.BLOCK_SHORTCONV),
+                        ("block_part", scopes.SHORTCONV_GATE)):
+        assert any(getattr(s, field) == name and "transpose(" in n
+                   for (_, n), s in zip(names, got)), name
+    with open(os.path.join(ROOT, "benchmark", "phases",
+                           "59-conv-stack.json")) as f:
+        ours = json.load(f)["groups"]
+    assert {g: spec["names"] for g, spec in ours.items()} == {
+        "block": [scopes.BLOCK_SHORTCONV],
+        "block_part": [scopes.SHORTCONV_GATE]}
+    dense = [(op, s) for (op, _), s in zip(names, got)
+             if s.phase == scopes.PHASE_DENSE_FWD_BWD and op not in FREE]
+    # what is left is the two norms of a layer and the residual adds, as in
+    # the latent stack; a short convolution is fewer operations than a
+    # latent attention, so they are a larger share (15.2 % at this size)
+    bare = [op for op, s in dense if not s.block]
+    assert len(bare) < 0.2 * len(dense), (len(bare), len(dense))
 
 
 def test_a_read_only_lookup_shows_no_insert():
